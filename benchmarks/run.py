@@ -70,6 +70,7 @@ import jax
 from benchmarks import bench_api, bench_center, bench_dist, bench_mantel, \
     bench_pcoa, bench_serve, bench_stats, bench_tune, bench_validation, \
     trajectory
+from repro.launch.cache import enable_compile_cache
 
 
 def _smoke_report(path: str) -> None:
@@ -145,6 +146,7 @@ def main() -> None:
                          "the repro.tune solved-vs-default tile pricing, "
                          "or the repro.serve coalescing gates")
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
 
     print(f"# repro benchmarks — {platform.processor() or 'cpu'} · "
           f"jax {jax.__version__} · devices={jax.device_count()}")

@@ -32,8 +32,8 @@ changes is how often D is read.
 
 ``Workspace.from_features`` extends the session one step upstream: the
 distance matrix itself is produced by the tiled ``repro.dist`` driver in
-CONDENSED layout, with the operator means and Mantel moments accumulated
-during the same sweep — and since the Mantel family and ANOSIM now run
+CONDENSED layout, with the operator means accumulated during the same
+sweep — and since the Mantel family and ANOSIM now run
 their permutation loops over condensed storage too
 (``kernels.permute_reduce`` closed-form triangle gathers), a
 feature-backed session completes the ENTIRE analysis battery — PCoA,
@@ -65,6 +65,7 @@ from repro.core.pcoa import pcoa as _pcoa
 from repro.core.pcoa import resolve_dimensions
 from repro.core.validation import ensure_finite
 from repro.dist import get_metric, pairwise_condensed
+from repro.kernels.dispatch import HIGHEST
 from repro.obs.ledger import FEATURE_HOIST_PASSES, HOIST_PASSES
 from repro.obs.report import ObsSession, RunReport, build_report
 from repro.obs.trace import NULL_OBS
@@ -199,13 +200,6 @@ def _resident_nbytes(value, seen: set) -> int:
     return 0
 
 
-@jax.jit
-def _centered_normalized(flat, mean, norm):
-    """One fused O(m) pass: the hat vector from the production's fused
-    mean/norm scalars."""
-    return (flat - mean) / norm
-
-
 def _key_fingerprint(key) -> tuple:
     """Hashable identity of a PRNG key, for cache keys."""
     try:
@@ -263,8 +257,8 @@ class Workspace:
         ``repro.dist`` path.
 
         The distances are produced tile-by-tile in CONDENSED layout on
-        first use, and the operator means (and the Mantel-side condensed
-        moments) are accumulated during that same sweep — so the whole
+        first use, and the operator means are accumulated during that
+        same sweep — so the whole
         analysis battery (``pcoa(method="fsvd")``, ``permanova``,
         ``permdisp``, ``anosim``, ``mantel``, ``partial_mantel``) runs
         without an n×n matrix of any kind ever existing: the permutation
@@ -477,8 +471,8 @@ class Workspace:
         """Run the tiled ``repro.dist`` production (feature-backed sessions
         only): ONE sweep over the feature table builds BOTH cache entries —
         ``"condensed"`` (the pdist-layout distances) and ``"dist_means"``
-        (the operator row/global means + the Mantel moments, accumulated
-        while each tile was resident). The two keys miss together, by
+        (the operator row/global means, accumulated while each tile was
+        resident). The two keys miss together, by
         construction."""
         if "condensed" in self.cache and "dist_means" in self.cache:
             return
@@ -493,8 +487,7 @@ class Workspace:
                 interpret=self.config.interpret)
         self.cache.get("condensed", lambda: prod["condensed"])
         self.cache.get("dist_means", lambda: {
-            k: prod[k] for k in ("row_means", "global_mean", "mean",
-                                 "norm")})
+            k: prod[k] for k in ("row_means", "global_mean")})
 
     def condensed(self) -> jax.Array:
         """The condensed (scipy ``pdist`` layout) distances. Feature-backed
@@ -549,22 +542,10 @@ class Workspace:
         Mantel-family sides: the permuted side consumes ``norm``, a fixed
         side contributes its ``hat`` vector directly (condensed — since
         the batched loop gathers condensed storage, no square hat form
-        exists anymore). Feature-backed sessions CONSUME the production
-        sweep's fused mean/norm scalars (accumulated while the tiles were
-        resident — no extra reduction passes; the Σd²−m·mean² form
-        differs from ``condensed_moments`` at ~1e-4 relative, which the
-        Mantel statistics absorb: observed and null draws share the
-        scale) and only pay the one O(m) center-and-divide for the hat
-        vector itself."""
-        if self._features is not None:
-            def build():
-                self._produce_distances()
-                means = self.cache.get("dist_means", lambda: None)
-                return {"norm": means["norm"],
-                        "hat": _centered_normalized(
-                            self.cache.get("condensed", lambda: None),
-                            means["mean"], means["norm"])}
-            return self.cache.get("moments", build)
+        exists anymore). Both backings take them from the shared
+        ``"condensed"`` artifact in two passes (mean, then the centered
+        norm): the one-pass Σd² − m·mean² form cancels in fp32 and moved
+        the observed Mantel r by 2.2e-4 at n=4096."""
         return self.cache.get("moments", lambda: condensed_moments_vec(
             self.condensed()))
 
@@ -683,21 +664,24 @@ class Workspace:
             y, z = self._coerce(other), self._coerce(control)
             if not (self.n == y.n == z.n):
                 raise ValueError("x, y and z must have the same shape")
-            ym, zm = y.moments(), z.moments()
-            r_yz = jnp.dot(ym["hat"], zm["hat"])
-            # eager degeneracy check (can't raise inside the jitted
+            xm, ym, zm = self.moments(), y.moments(), z.moments()
+            r_yz = jnp.dot(ym["hat"], zm["hat"], precision=HIGHEST)
+            r_xz = jnp.dot(xm["hat"], zm["hat"], precision=HIGHEST)
+            # eager degeneracy checks (can't raise inside the jitted
             # engine): |r_yz|→1 makes the residualization 0/0, NaN-ing
-            # the whole null. 1e-5, not 1e-6: an fp32 self-correlation
-            # rounds to 1-r² as large as ~1e-6, and any genuine r_yz this
-            # close is numerically useless
-            r = float(r_yz)
-            if 1.0 - r * r < 1e-5:
-                raise ValueError(
-                    f"y and z are (nearly) collinear (r_yz={r:.6f}); the "
-                    f"partial correlation is undefined — use the plain "
-                    f"Mantel test")
+            # the whole null; |r_xz|→1 makes the observed statistic 0/0.
+            # 1e-5, not 1e-6: an fp32 self-correlation rounds to 1-r² as
+            # large as ~1e-6, and any genuine r this close is numerically
+            # useless
+            for pair, r in (("y and z", float(r_yz)),
+                            ("x and z", float(r_xz))):
+                if 1.0 - r * r < 1e-5:
+                    raise ValueError(
+                        f"{pair} are (nearly) collinear (r={r:.6f}); the "
+                        f"partial correlation is undefined — use the "
+                        f"plain Mantel test")
             denom = jnp.sqrt(1.0 - r_yz * r_yz)
-            pre = {"normxm": self.moments()["norm"], "r_yz": r_yz,
+            pre = {"normxm": xm["norm"], "r_yz": r_yz,
                    "y_res": (ym["hat"] - r_yz * zm["hat"]) / denom,
                    "z": zm["hat"]}
             # fixed sides ride in via pre only (their y/z fields are

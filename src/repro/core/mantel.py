@@ -12,12 +12,13 @@ Monte-Carlo null distribution over K row/column permutations (default 999).
   partial Mantel test in ``repro.stats``):
     1. the second argument never changes ⇒ normalize ``y`` once;
     2. mean and norm are permutation-invariant ⇒ compute ``x̄``, ``‖x−x̄‖`` once.
-  One further algebraic step (DESIGN §2): ``ŷ`` is centered ⇒ ``Σŷ = 0`` ⇒ the
-  ``−x̄`` term vanishes from the inner product — and the whole loop is
+  Centering is permutation-invariant too, so the hoist stores x − x̄ (in
+  exact arithmetic Σŷ = 0 would make that unnecessary; in fp32 it is
+  not) — and the whole loop is
   SQUARE-FREE: the condensed form of the permuted matrix is an index
   transform of the condensed original,
       ``condensed(X_p)[k] = xc[tri(order[i_k], order[j_k])]``,
-  so      ``r_p = ⟨condensed(X_p), ŷ_c⟩ / ‖x−x̄‖``
+  so      ``r_p = ⟨condensed(X_p) − x̄, ŷ_c⟩ / ‖x−x̄‖``
   is one closed-form gather + one fused multiply-reduce over the
   m = n(n−1)/2 condensed entries — never the n×n gather buffer the PR-4
   loop materialized. Permutations run in batches of B through
@@ -44,6 +45,7 @@ import numpy as np
 
 from repro.core.distance_matrix import (DistanceMatrix, condensed_index,
                                         condensed_to_square, triangle_coords)
+from repro.kernels.dispatch import HIGHEST
 from repro.kernels.permute_reduce_ops import permute_reduce
 from repro.stats import engine
 
@@ -59,7 +61,7 @@ def pearsonr_ref(x_flat: jax.Array, y_flat: jax.Array) -> jax.Array:
     normym = jnp.linalg.norm(ym)
     xnorm = xm / normxm
     ynorm = ym / normym
-    return jnp.dot(xnorm, ynorm)
+    return jnp.dot(xnorm, ynorm, precision=HIGHEST)
 
 
 # --------------------------------------------------------------------------
@@ -121,6 +123,14 @@ def hat_square(moments: dict, n: int) -> jax.Array:
     return condensed_to_square(moments["hat"], n)
 
 
+def _centered(xc: jax.Array) -> jax.Array:
+    """x − x̄, the permuted side as the statistics gather it. In exact
+    arithmetic Σŷ = 0 makes centering x unnecessary, but an fp32 ŷ sums
+    to about m·ulp(ȳ)/‖y−ȳ‖, and x̄ multiplies that into every draw: at
+    n=512 it shifted each partial-Mantel draw by 1.2e-4."""
+    return xc - xc.mean()
+
+
 def _as_condensed(mat: jax.Array, n: int) -> jax.Array:
     """Condensed view of a square matrix; condensed input passes through.
     The statistics accept both so legacy square-matrix callers keep
@@ -158,15 +168,14 @@ class MantelStatistic:
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
 
     def hoist(self):
-        # the permuted side's condensed view and the triangle coordinate
-        # map are permutation-invariant too — extracted once, outside the
-        # Monte-Carlo loop
-        inv = {"xc": _as_condensed(self.x, self.n)}
+        # the permuted side's centered condensed view and the triangle
+        # coordinate map are permutation-invariant too — built once,
+        # outside the Monte-Carlo loop
+        inv = {"xc": _centered(_as_condensed(self.x, self.n))}
         if self.pre is not None:
             inv.update(self.pre)
         else:
-            xm = inv["xc"] - inv["xc"].mean()
-            inv["normxm"] = jnp.linalg.norm(xm)        # computed exactly once
+            inv["normxm"] = jnp.linalg.norm(inv["xc"])  # computed once
             y_flat = _as_condensed(self.y, self.n)
             ym = y_flat - y_flat.mean()
             inv["ynorm"] = ym / jnp.linalg.norm(ym)    # computed exactly once
@@ -179,7 +188,8 @@ class MantelStatistic:
         # scaling collapses to 1/‖x−x̄‖ on condensed entries)
         o = order.astype(jnp.int32)
         k = condensed_index(o[inv["ii"]], o[inv["jj"]], self.n)
-        return jnp.dot(inv["xc"][k], inv["ynorm"]) / inv["normxm"]
+        return jnp.dot(inv["xc"][k], inv["ynorm"],
+                       precision=HIGHEST) / inv["normxm"]
 
     def per_batch(self, inv, orders):
         # the engine's primary path: all B reductions of one order tile
@@ -221,21 +231,20 @@ def mantel(x: DistanceMatrix, y: DistanceMatrix, permutations: int = 999,
 # --------------------------------------------------------------------------
 # Distributed mantel — permutations over ('pod','data'), columns over 'model'
 # --------------------------------------------------------------------------
-def mantel_distributed(x: DistanceMatrix, y: DistanceMatrix, mesh,
-                       permutations: int = 1024,
-                       key: Optional[jax.Array] = None,
-                       alternative: str = "two-sided",
-                       perm_axes=("data",), col_axis: str = "model"):
-    """Permutation-parallel Mantel.
+def mantel_null_distributed(x: DistanceMatrix, y: DistanceMatrix, mesh,
+                            permutations: int = 1024,
+                            key: Optional[jax.Array] = None,
+                            perm_axes=("data",), col_axis: str = "model"):
+    """``(observed, null)`` of the permutation-parallel Mantel test.
 
     Each device owns K/|perm_axes| permutations and the full matrix column
     block assigned to its 'model' coordinate; the per-permutation reduction
     is block-local followed by one scalar psum over 'model'. Permutation
     draws use a per-device fold_in so the global null distribution is
-    identical regardless of mesh shape (elastic-safe).
+    identical regardless of mesh shape (elastic-safe): the draws of
+    permutation-device ``d`` come from ``fold_in(key, d)``.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.stats.engine import _shard_map
 
     key = engine.as_key(key)
     n = len(x)
@@ -271,15 +280,31 @@ def mantel_distributed(x: DistanceMatrix, y: DistanceMatrix, mesh,
         def one(order):
             col_order = jax.lax.dynamic_slice(order, (j * c,), (c,))
             xp = x_local[order][:, col_order]          # only our column block
-            part = jnp.vdot(xp, y_cols)
+            part = jnp.vdot(xp, y_cols, precision=HIGHEST)
             return jax.lax.psum(part, axis_name=col_axis) / (2.0 * normxm_s)
 
         return jax.lax.map(one, orders)
 
-    f = _shard_map(
+    f = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(), P(None, col_axis), P()),
         out_specs=P(perm_axes[0] if len(perm_axes) == 1 else perm_axes),
     )
-    permuted_stats = f(x_data, y_full, normxm)
-    return _finish(orig_stat, permuted_stats, permutations, alternative, n)
+    # centered like the statistic's condensed x (the hollow diagonal meets
+    # y_full's zero diagonal, so it adds nothing either way)
+    x_centered = x_data - jnp.sum(x_data) / (n * (n - 1))
+    return orig_stat, f(x_centered, y_full, normxm)
+
+
+def mantel_distributed(x: DistanceMatrix, y: DistanceMatrix, mesh,
+                       permutations: int = 1024,
+                       key: Optional[jax.Array] = None,
+                       alternative: str = "two-sided",
+                       perm_axes=("data",), col_axis: str = "model"):
+    """Permutation-parallel Mantel (``mantel_null_distributed``), finished
+    like ``mantel``: ``(statistic, p_value, n)``."""
+    orig_stat, permuted_stats = mantel_null_distributed(
+        x, y, mesh, permutations, key, perm_axes=perm_axes,
+        col_axis=col_axis)
+    return _finish(orig_stat, permuted_stats, permutations, alternative,
+                   len(x))

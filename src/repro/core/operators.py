@@ -39,11 +39,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.distance_matrix import MAX_TRIANGLE_N, condensed_index
-
-try:                                    # jax >= 0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                  # this container's 0.4.x lineage
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.kernels.dispatch import HIGHEST
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -105,13 +101,14 @@ class CenteredGramOperator:
                 interpret=self.interpret)
         else:
             colsum = jnp.sum(x, axis=0)                  # 1ᵀX   (k,)
-            corr = self.global_mean * colsum - self.row_means @ x  # m·1ᵀX − rᵀX
+            corr = self.global_mean * colsum - jnp.matmul(
+                self.row_means, x, precision=HIGHEST)    # m·1ᵀX − rᵀX
             b = max(min(self.block, self.n), 1)
             parts = []
             for i0 in range(0, self.n, b):               # static row strips
                 rows = self.d[i0:i0 + b]
                 e_rows = -0.5 * rows * rows              # fused into the dot
-                parts.append(e_rows @ x
+                parts.append(jnp.matmul(e_rows, x, precision=HIGHEST)
                              - self.row_means[i0:i0 + b, None] * colsum[None, :]
                              + corr[None, :])
             out = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
@@ -216,14 +213,15 @@ class CondensedCenteredGramOperator:
         if squeeze:
             x = x[:, None]
         colsum = jnp.sum(x, axis=0)                  # 1ᵀX   (k,)
-        corr = self.global_mean * colsum - self.row_means @ x  # m·1ᵀX − rᵀX
+        corr = self.global_mean * colsum - jnp.matmul(
+            self.row_means, x, precision=HIGHEST)        # m·1ᵀX − rᵀX
         b = max(min(self.block, self.n), 1)
         parts = []
         for i0 in range(0, self.n, b):               # static row strips
             bi = min(b, self.n - i0)
             rows = self.row_panel(i0, bi)
             e_rows = -0.5 * rows * rows              # fused into the dot
-            parts.append(e_rows @ x
+            parts.append(jnp.matmul(e_rows, x, precision=HIGHEST)
                          - self.row_means[i0:i0 + bi, None] * colsum[None, :]
                          + corr[None, :])
         out = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
@@ -268,17 +266,19 @@ def centered_gram_matvec_distributed(d: jax.Array, x: jax.Array, mesh,
 
     def _local(d_blk, x_col, x_row):
         e = -0.5 * d_blk * d_blk
-        part = jax.lax.psum(e @ x_col, axis_name=col_axis)    # (n/Pr, k)
+        part = jax.lax.psum(jnp.matmul(e, x_col, precision=HIGHEST),
+                            axis_name=col_axis)           # (n/Pr, k)
         local_row_sums = jnp.sum(e, axis=1)
         rm = jax.lax.psum(local_row_sums, axis_name=col_axis) / n
         gm = jax.lax.psum(jnp.sum(local_row_sums),
                           axis_name=(row_axis, col_axis)) / (n * n)
         colsum = jax.lax.psum(jnp.sum(x_row, axis=0), axis_name=row_axis)
-        rmx = jax.lax.psum(rm @ x_row, axis_name=row_axis)
+        rmx = jax.lax.psum(jnp.matmul(rm, x_row, precision=HIGHEST),
+                           axis_name=row_axis)
         return part - rm[:, None] * colsum[None, :] \
             + (gm * colsum - rmx)[None, :]
 
-    f = _shard_map(
+    f = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(col_axis, None), P(row_axis, None)),
         out_specs=P(row_axis, None),

@@ -52,6 +52,7 @@ from repro.core import centering
 from repro.core.distance_matrix import DistanceMatrix
 from repro.core.operators import (CenteredGramOperator,
                                   centered_gram_matvec_distributed)
+from repro.kernels.dispatch import HIGHEST
 from repro.obs.compile import note_trace
 from repro.obs.trace import current_obs
 
@@ -97,12 +98,12 @@ def _subspace_iteration(matvec, n: int, dtype, key, k: int, oversample: int,
     q, _ = jnp.linalg.qr(matvec(omega))
     for _ in range(power_iters):
         q, _ = jnp.linalg.qr(matvec(q))
-    t = q.T @ matvec(q)                    # (p, p) — tiny, host-side cost
+    t = jnp.matmul(q.T, matvec(q), precision=HIGHEST)   # (p, p), tiny
     t = 0.5 * (t + t.T)
     evals, evecs = jnp.linalg.eigh(t)
     # eigh returns ascending; take top-k by value (descending)
     order = jnp.argsort(-evals)[:k]
-    return evals[order], (q @ evecs)[:, order]
+    return evals[order], jnp.matmul(q, evecs, precision=HIGHEST)[:, order]
 
 
 @partial(jax.jit, static_argnames=("k", "oversample", "power_iters"))
@@ -122,7 +123,8 @@ def _randomized_eigh(a: jax.Array, key, k: int, oversample: int = 10,
     """Materialized fsvd — the baseline the benchmarks race against."""
     note_trace("pcoa.fsvd_materialized",
                (a.shape[0], k, oversample, power_iters))
-    return _subspace_iteration(lambda x: a @ x, a.shape[0], a.dtype, key, k,
+    return _subspace_iteration(lambda x: jnp.matmul(a, x, precision=HIGHEST),
+                               a.shape[0], a.dtype, key, k,
                                oversample, power_iters)
 
 

@@ -12,10 +12,7 @@ argument applied one level upstream of the analyses:
 * the **operator means** — row/global means of E = −½ D∘D, exactly what
   ``CenteredGramOperator.from_distance`` hoists from a square D — come
   from each strip's row sums of D², so ``Workspace.from_features`` can
-  run matrix-free PCoA/PERMANOVA without a square n×n ever existing;
-* the **condensed moments** — the mean and centered norm of the condensed
-  vector, the permuted-side hoist of the Mantel family — come from the
-  same row sums (Σ over the full hollow matrix is twice the condensed Σ).
+  run matrix-free PCoA/PERMANOVA without a square n×n ever existing.
 
 Peak memory is one (block, n) strip plus the (m,) condensed output,
 m = n(n−1)/2 — the square matrix is never allocated. Panel compute
@@ -88,10 +85,10 @@ def _panel_xla(xi: jax.Array, x: jax.Array, metric: Metric,
 def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
                  feature_block: int, impl: str, interpret: Optional[bool],
                  block: int):
-    """One row strip + its fused running sums: (strip, Σ_j d, Σ_j d²).
+    """One row strip + its fused row sums: (strip, Σ_j d²).
 
     The row sums ride the same jit region as the strip compute, so XLA
-    fuses them into the panel sweep — the hoists cost no extra pass."""
+    fuses them into the panel sweep — the hoist costs no extra pass."""
     note_trace("dist.panel_stats",
                (xi.shape, x.shape, metric.name, feature_block, impl, block))
     if impl == "pallas":
@@ -101,7 +98,7 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
                                       interpret=interpret)
     else:
         strip = _panel_xla(xi, x, metric, feature_block)
-    return strip, jnp.sum(strip, axis=1), jnp.sum(strip * strip, axis=1)
+    return strip, jnp.sum(strip * strip, axis=1)
 
 
 def pairwise_condensed(x, metric="braycurtis", *,
@@ -117,8 +114,6 @@ def pairwise_condensed(x, metric="braycurtis", *,
     * ``row_means``   — (n,) row means of E = −½ D∘D (the
       ``CenteredGramOperator`` hoist, accumulated tile-by-tile);
     * ``global_mean`` — () global mean of E;
-    * ``mean`` / ``norm`` — condensed mean and centered condensed norm
-      (the Mantel family's permuted-side moments);
     * ``n`` / ``metric`` — provenance.
 
     The square n×n matrix is never allocated; peak memory is one
@@ -137,7 +132,7 @@ def pairwise_condensed(x, metric="braycurtis", *,
     b = clamp_block(n, block)
     obs = current_obs()          # the ambient session (NULL_OBS when none)
 
-    cond_parts, rs1_parts, rs2_parts = [], [], []
+    cond_parts, rs2_parts = [], []
     with obs.span("dist.pairwise_condensed", phase="production", n=n, d=d,
                   block=b, impl=impl, metric=metric.name,
                   panels=-(-n // b)):
@@ -146,33 +141,23 @@ def pairwise_condensed(x, metric="braycurtis", *,
             xi = x[i0:i1]
             if i1 - i0 < b:                 # pad the short tail panel so
                 xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))  # one trace fits all
-            strip, rs1, rs2 = _panel_stats(xi, x, metric=metric,
+            strip, rs2 = _panel_stats(xi, x, metric=metric,
                                            feature_block=feature_block,
                                            impl=impl, interpret=interpret,
                                            block=b)
-            rs1_parts.append(rs1[:i1 - i0])
             rs2_parts.append(rs2[:i1 - i0])
             idx = _panel_condensed_indices(n, i0, i1)
             if idx.size:
                 cond_parts.append(strip.reshape(-1)[jnp.asarray(idx)])
     obs.charge_production(n, d, b, metric=metric.name, impl=impl)
 
-    rowsum_d = jnp.concatenate(rs1_parts)
     rowsum_d2 = jnp.concatenate(rs2_parts)
     condensed = (jnp.concatenate(cond_parts) if cond_parts
                  else jnp.zeros((0,), dtype=x.dtype))
-
-    m = condensed_size(n)
     row_means = -0.5 * rowsum_d2 / n
-    global_mean = jnp.mean(row_means)
-    # Σ over the full hollow matrix is exactly twice the condensed Σ
-    sum_c = 0.5 * jnp.sum(rowsum_d)
-    sumsq_c = 0.5 * jnp.sum(rowsum_d2)
-    mean_c = sum_c / max(m, 1)
-    norm = jnp.sqrt(jnp.maximum(sumsq_c - m * mean_c * mean_c, 0.0))
     return {"condensed": condensed, "row_means": row_means,
-            "global_mean": global_mean, "mean": mean_c, "norm": norm,
-            "n": n, "metric": metric.name}
+            "global_mean": jnp.mean(row_means), "n": n,
+            "metric": metric.name}
 
 
 def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
@@ -209,7 +194,7 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
         xi = x[i0:i1]
         if i1 - i0 < b:
             xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))
-        strip, _, _ = _panel_stats(xi, x, metric=metric,
+        strip, _ = _panel_stats(xi, x, metric=metric,
                                    feature_block=feature_block, impl=impl,
                                    interpret=interpret, block=b)
         parts.append(strip[:i1 - i0])

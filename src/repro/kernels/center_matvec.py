@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import HIGHEST
+
 
 def _center_matvec_kernel(d_ref, x_ref, rm_ref, colsum_ref, corr_ref,
                           out_ref):
@@ -36,13 +38,12 @@ def _center_matvec_kernel(d_ref, x_ref, rm_ref, colsum_ref, corr_ref,
 
     d = d_ref[...]
     e = -0.5 * d * d                     # E tile formed in-register
-    out_ref[...] += jnp.dot(e, x_ref[...],
+    out_ref[...] += jnp.dot(e, x_ref[...], precision=HIGHEST,
                             preferred_element_type=out_ref.dtype)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():                       # rank-1 corrections, in-register
-        out_ref[...] += corr_ref[...][None, :] \
-            - rm_ref[...][:, None] * colsum_ref[...][None, :]
+        out_ref[...] += corr_ref[...] - rm_ref[...] * colsum_ref[...]
 
 
 def center_matvec(d: jax.Array, x: jax.Array, row_means: jax.Array,
@@ -51,7 +52,9 @@ def center_matvec(d: jax.Array, x: jax.Array, row_means: jax.Array,
     """Tiled ``F @ X``. All operands pre-padded to block multiples.
 
     d: (n, n); x: (n, k); row_means: (n,) row means of E;
-    colsum: (k,) ``1ᵀX``; corr: (k,) ``m·1ᵀX − rᵀX``.
+    colsum: (k,) ``1ᵀX``; corr: (k,) ``m·1ᵀX − rᵀX``. The vectors enter
+    the kernel as (n, 1) / (1, k) blocks: Mosaic tiles 1-D blocks to a
+    layout XLA does not share, and refuses them.
     """
     n = d.shape[0]
     k = x.shape[1]
@@ -62,11 +65,11 @@ def center_matvec(d: jax.Array, x: jax.Array, row_means: jax.Array,
         in_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
             pl.BlockSpec((block_n, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-            pl.BlockSpec((k,), lambda i, j: (0,)),
-            pl.BlockSpec((k,), lambda i, j: (0,)),
+            pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), d.dtype),
         interpret=interpret,
-    )(d, x, row_means, colsum, corr)
+    )(d, x, row_means[:, None], colsum[None, :], corr[None, :])

@@ -29,6 +29,12 @@ from typing import Optional, Tuple
 
 import jax
 
+#: the precision of every fp32 matmul and dot on the analysis path. A
+#: TPU's default runs fp32 operands as one bf16 pass, which moved the
+#: v5e's observed Mantel r by 2e-4 and PERMDISP's F by 1e-4 of their
+#: float64 values; XLA:CPU computes fp32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 #: fp32 sublane multiple — the snap unit in interpreter mode (and for
 #: row-ish dims everywhere).
 SUBLANE = 8

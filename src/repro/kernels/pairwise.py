@@ -36,6 +36,11 @@ from jax.experimental import pallas as pl
 
 from repro.dist.metrics import Metric, merge_acc
 
+#: rows per grid step. The metric's (rows, bn, feature_block) broadcast
+#: term is the kernel's largest value: 512 KiB at 8 x 128 x 128, where a
+#: whole 256-row panel's 32 MiB did not finish compiling for the v5e.
+_ROWS = 8
+
 
 def _pairwise_kernel(metric: Metric, feature_block: int,
                      xi_ref, xj_ref, out_ref):
@@ -64,17 +69,20 @@ def pairwise_panel(xi: jax.Array, xj: jax.Array, metric: Metric, *,
     """
     bm, d = xi.shape
     n = xj.shape[0]
-    grid = (n // block_n,)
+    rb = _ROWS if bm % _ROWS == 0 else bm
+    # column block outer, row sub-panel inner: the (bn, d) Xⱼ block stays
+    # resident while the panel's rows stream past it
+    grid = (n // block_n, bm // rb)
     kernel = lambda a, b, o: _pairwise_kernel(metric, feature_block,
                                               a, b, o)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, d), lambda j: (0, 0)),
-            pl.BlockSpec((block_n, d), lambda j: (j, 0)),
+            pl.BlockSpec((rb, d), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_n, d), lambda j, i: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, block_n), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((rb, block_n), lambda j, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bm, n), xi.dtype),
         interpret=interpret,
     )(xi, xj)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.dist.metrics import Metric
-from repro.kernels.dispatch import (lane_geometry, pick_block,
+from repro.kernels.dispatch import (TPU_LANE, lane_geometry, pick_block,
                                     resolve_interpret)
 from repro.kernels.pairwise import pairwise_panel
 from repro.obs.compile import note_trace
@@ -46,6 +46,11 @@ def pairwise_panel_pallas(xi: jax.Array, x: jax.Array, *, metric: Metric,
                 interpret))
     # TPU-native tiles need lane-aligned (multiple-of-128) trailing dims
     lane, floor = lane_geometry(interpret)
+    if not interpret:
+        # the (bn, d) Xⱼ block is double-buffered in VMEM beside the
+        # metric's broadcast term: at d=2048 a 256-wide block overflows
+        # the v5e's 16 MiB scoped VMEM, a 128-wide one fits
+        block_n = min(block_n, TPU_LANE)
     bn = pick_block(n, block_n, lane, floor=floor)
     pad_n = (-n) % bn
     fb = min(feature_block, d)

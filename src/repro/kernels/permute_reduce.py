@@ -30,10 +30,10 @@ share of the ys/ii/jj streams) + n (its order row) — vs the square
 loop's ~6n² ≈ 12m. S invariant rows share one gather, so the partial
 Mantel test's two reductions cost one pass.
 
-``interpret=None`` dispatches by backend like every kernel here: native
-Mosaic lowering on a TPU, the Pallas interpreter elsewhere (this
-container's CPU; the production CPU path is the pure-XLA twin in
-``permute_reduce_ops``).
+``interpret=None`` dispatches by backend like every kernel here: the
+Pallas interpreter off the TPU (the production path everywhere is the
+pure-XLA twin in ``permute_reduce_ops``). There is no TPU lowering yet:
+native mode raises ``NotImplementedError`` instead of falling back.
 """
 
 from __future__ import annotations
@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.center_matvec_ops import resolve_interpret
+from repro.kernels.dispatch import resolve_interpret
 
 
 def _permute_reduce_kernel(n, ii_ref, jj_ref, ys_ref, orders_ref, xc_ref,
@@ -83,6 +83,13 @@ def permute_reduce_kernel(xc: jax.Array, ys: jax.Array, ii: jax.Array,
     int32. Returns (S, B) in xc's dtype.
     """
     interpret = resolve_interpret(interpret)
+    if not interpret:
+        raise NotImplementedError(
+            "the Pallas permute_reduce kernel does not compile for TPU: "
+            "its 1-D chunk blocks and (1, n) order block break Mosaic's "
+            "(8, 128) tiling, it gathers in-kernel from a whole-(m,) VMEM "
+            "block of the condensed source, and that block outgrows VMEM "
+            "at real n. Use ExecConfig(kernel='xla'), the default.")
     s, m_pad = ys.shape
     b_perms, n = orders.shape
     m = xc.shape[0]

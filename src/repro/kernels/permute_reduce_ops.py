@@ -31,7 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch import snap_chunk
+from repro.kernels.dispatch import HIGHEST, snap_chunk
 from repro.kernels.permute_reduce import permute_reduce_kernel
 from repro.obs.compile import note_trace
 
@@ -64,7 +64,7 @@ def _reduce_xla(xc, ys, ii, jj, orders, n: int, chunk: int) -> jax.Array:
         hi = jnp.maximum(oi, oj)
         k = lo * (2 * n - lo - 1) // 2 + (hi - lo - 1)
         xg = jnp.take(xc, k)                       # (B, chunk) xc gather
-        return acc + yc @ xg.T, None               # (S, B) accumulate
+        return acc + jnp.matmul(yc, xg.T, precision=HIGHEST), None
 
     acc0 = jnp.zeros((s, orders.shape[0]), dtype=xc.dtype)
     out, _ = jax.lax.scan(body, acc0, (ii_c, jj_c, ys_c))

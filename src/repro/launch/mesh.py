@@ -7,19 +7,33 @@ before the first jax import; smoke tests and benchmarks see the real
 single device. (The retired ``launch.dryrun`` was the last such caller;
 nothing in-tree sets the override today.)
 
-Hardware model (TPU v5e targets; ``HBM_BW`` is also the bandwidth
-column of ``tune.budget``'s static TPU budget):
-    197 TFLOP/s bf16 / chip · 819 GB/s HBM · ~50 GB/s/link ICI.
+Hardware model: ``CHIP_PEAKS``, the published per-chip peaks keyed by
+``jax.Device.device_kind``. ``chip_peaks`` refuses a kind that is not in
+the table instead of guessing: a roofline priced against the wrong chip
+is worse than none. ``tune.budget`` takes its TPU bandwidth from here.
 """
 
 from __future__ import annotations
 
 import jax
 
-# v5e constants (per chip)
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s per link
+#: per-chip peaks by ``device_kind`` (Google Cloud documentation, "TPU
+#: v5e"): bf16 FLOP/s, HBM bytes/s, HBM bytes, and interconnect bytes/s
+#: per link (1,600 Gbit/s over four links)
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "hbm_bytes": 16e9, "ici_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The ``CHIP_PEAKS`` row for ``device_kind``; unknown kinds raise."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)}") from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

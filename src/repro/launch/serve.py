@@ -23,13 +23,17 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.serve import AnalysisService, ServeConfig
 
 
 def run(args) -> dict:
+    """Drive the service; returns ``serve_report()`` plus ``statuses``,
+    each request's final status in submission order."""
     rng = np.random.default_rng(args.seed)
     svc = AnalysisService(ServeConfig(batch_size=args.batch,
                                       timeout_s=None,
@@ -83,10 +87,12 @@ def run(args) -> dict:
         print(json.dumps({"gauges": g, "pool": report["pool"],
                           "scheduler": report["scheduler"]}, indent=2,
                          default=str))
+    report["statuses"] = [h.status for h in handles]
     return report
 
 
-def main():
+def main(argv=None) -> int:
+    """Returns 0 only when every request ended ``done``."""
     ap = argparse.ArgumentParser(
         description="drive the repro.serve analysis front door")
     ap.add_argument("--smoke", action="store_true",
@@ -101,12 +107,19 @@ def main():
                     help="per-request lines to print")
     ap.add_argument("--json", action="store_true",
                     help="dump the gauge/pool/scheduler sections as JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.smoke:
         args.n = min(args.n, 32)
         args.permutations = min(args.permutations, 99)
-    run(args)
+    enable_compile_cache()
+    statuses = run(args)["statuses"]
+    failed = [s for s in statuses if s != "done"]
+    if failed:
+        print(f"[serve] {len(failed)} of {len(statuses)} requests did not "
+              f"finish: {failed}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -636,11 +636,10 @@ class TileScheduler:
             exceedances=active.count, done=done, **bounds)
         active.handle.push_update(update)
         if done:
-            # identical finishing rule to engine.finish, down to the
-            # fp32 division: +1 correction, NaN observed -> NaN p
-            p = np.float32(active.count + 1) / np.float32(k + 1)
+            # engine.finish's rule: +1 correction, NaN observed -> NaN p
             active.handle.complete(engine.PermutationTestResult(
                 active.observed,
-                float("nan") if np.isnan(active.observed) else float(p),
+                float("nan") if np.isnan(active.observed)
+                else engine.p_value(active.count, k),
                 active.orders.shape[1], k, active.handle.method,
                 active.handle.key))

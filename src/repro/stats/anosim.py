@@ -39,6 +39,7 @@ from jax.scipy.stats import rankdata
 
 from repro.core.distance_matrix import (DistanceMatrix, condensed_index,
                                         triangle_coords)
+from repro.kernels.dispatch import HIGHEST
 from repro.kernels.permute_reduce_ops import permute_reduce
 from repro.stats import engine
 from repro.stats.engine import PermutationTestResult
@@ -129,7 +130,7 @@ class AnosimStatistic:
     def per_perm(self, inv, order):
         o = order.astype(jnp.int32)
         k = condensed_index(o[inv["ii"]], o[inv["jj"]], self.n)
-        w_sum = jnp.dot(inv["ranks"], inv["within"][k])
+        w_sum = jnp.dot(inv["ranks"], inv["within"][k], precision=HIGHEST)
         return self._finish_r(inv, w_sum)
 
     def per_batch(self, inv, orders):

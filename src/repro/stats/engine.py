@@ -49,11 +49,6 @@ from repro.api.config import ExecConfig
 from repro.obs.compile import note_trace
 from repro.obs.trace import current_obs
 
-try:                                    # jax >= 0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                  # this container's 0.4.x lineage
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # --------------------------------------------------------------------------
 # RNG coercion — THE one documented key-handling rule for every entry point
@@ -155,12 +150,19 @@ def finish(orig_stat, permuted_stats, permutations: int, alternative: str,
     statistic propagates to a NaN p-value — NaN comparisons are all False,
     which would otherwise count zero exceedances and report the *most*
     significant p possible for a degenerate input."""
-    c = count_better(orig_stat, permuted_stats, alternative)
-    p_value = (c + 1) / (permutations + 1)
+    c = int(count_better(orig_stat, permuted_stats, alternative))
     orig_stat = float(orig_stat)
     return PermutationTestResult(
-        orig_stat, float("nan") if np.isnan(orig_stat) else float(p_value),
+        orig_stat,
+        float("nan") if np.isnan(orig_stat) else p_value(c, permutations),
         n, permutations, method, key)
+
+
+def p_value(count: int, permutations: int) -> float:
+    """(count + 1) / (K + 1), divided on the host in fp32 — the one rule
+    the engine and the serve scheduler share. A TPU divides through an
+    approximate reciprocal: on the v5e it put 23/1000 one ulp off."""
+    return float(np.float32(count + 1) / np.float32(permutations + 1))
 
 
 # --------------------------------------------------------------------------
@@ -293,25 +295,22 @@ def permutation_test(stat: Statistic, permutations: int = 999,
 # --------------------------------------------------------------------------
 # Distributed engine — permutation axis through shard_map
 # --------------------------------------------------------------------------
-def permutation_test_distributed(stat: Statistic, mesh,
-                                 permutations: int = 1024,
-                                 key=None,
-                                 alternative: str = "two-sided",
-                                 perm_axes=("data",),
-                                 batch_size: Optional[int] = None,
-                                 config: Optional[ExecConfig] = None,
-                                 method: str = "") -> PermutationTestResult:
-    """Permutation-parallel engine: K/|devices| permutations per device.
+def null_distribution_distributed(stat: Statistic, mesh,
+                                  permutations: int = 1024, key=None,
+                                  perm_axes=("data",),
+                                  batch_size: Optional[int] = None,
+                                  config: Optional[ExecConfig] = None):
+    """``(observed, null)`` with K/|devices| permutations per device.
 
     The invariants are hoisted once and replicated; each device draws its
     own permutations via ``fold_in(key, device_index)`` — the same
     elastic-safe construction as ``mantel_distributed``, so the global
-    null distribution does not depend on the mesh shape.
+    null distribution does not depend on the mesh shape. ``null`` is the
+    (K,) array sharded over ``perm_axes``: device ``d`` holds the draws
+    of its ``fold_in(key, d)`` orders, in row-major device order.
     """
     from jax.sharding import PartitionSpec as P
 
-    if alternative not in ("two-sided", "greater", "less"):
-        raise ValueError(f"unknown alternative {alternative!r}")
     key = as_key(key)
     batch_size = (config or ExecConfig()).resolve_batch_size(batch_size, 8)
 
@@ -323,21 +322,41 @@ def permutation_test_distributed(stat: Statistic, mesh,
 
     invariants, observed = hoist_and_observe(stat)
 
-    def _local(inv):
+    def _local(st, inv):
         dev = 0                     # row-major rank over ALL perm axes, so
         for a in perm_axes:         # no two devices fold_in the same index
             dev = dev * mesh.shape[a] + jax.lax.axis_index(a)
         k = jax.random.fold_in(key, dev)
-        orders = permutation_orders(k, per_dev, stat.n)
-        return jax.lax.map(lambda o: stat.per_perm(inv, o), orders,
+        orders = permutation_orders(k, per_dev, st.n)
+        return jax.lax.map(lambda o: st.per_perm(inv, o), orders,
                            batch_size=min(batch_size, per_dev))
 
-    f = _shard_map(
+    # the statistic's arrays enter as (replicated) arguments: arrays closed
+    # over by a shard_map body would stay committed to one device
+    f = jax.shard_map(
         _local, mesh=mesh,
-        in_specs=(P(),),                           # invariants replicated
+        in_specs=(P(), P()),                 # statistic + invariants replicated
         out_specs=P(perm_axes[0] if len(perm_axes) == 1 else perm_axes),
     )
-    permuted = f(invariants)
+    return observed, f(stat, invariants)
+
+
+def permutation_test_distributed(stat: Statistic, mesh,
+                                 permutations: int = 1024,
+                                 key=None,
+                                 alternative: str = "two-sided",
+                                 perm_axes=("data",),
+                                 batch_size: Optional[int] = None,
+                                 config: Optional[ExecConfig] = None,
+                                 method: str = "") -> PermutationTestResult:
+    """Permutation-parallel engine: K/|devices| permutations per device
+    (``null_distribution_distributed``), finished like the host engine."""
+    if alternative not in ("two-sided", "greater", "less"):
+        raise ValueError(f"unknown alternative {alternative!r}")
+    key = as_key(key)
+    observed, permuted = null_distribution_distributed(
+        stat, mesh, permutations, key, perm_axes=perm_axes,
+        batch_size=batch_size, config=config)
     return finish(observed, permuted, permutations, alternative, stat.n,
                   method=method, key=key)
 

@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core.distance_matrix import (DistanceMatrix, condensed_index,
                                         triangle_coords)
+from repro.kernels.dispatch import HIGHEST
 from repro.kernels.permute_reduce_ops import permute_reduce
 from repro.stats import engine
 from repro.stats.engine import PermutationTestResult
@@ -71,13 +72,12 @@ class PartialMantelStatistic:
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
 
     def hoist(self):
-        from repro.core.mantel import _as_condensed
-        inv = {"xc": _as_condensed(self.x, self.n)}
+        from repro.core.mantel import _as_condensed, _centered
+        inv = {"xc": _centered(_as_condensed(self.x, self.n))}
         if self.pre is not None:
             inv.update(self.pre)
         else:
-            xm = inv["xc"] - inv["xc"].mean()
-            inv["normxm"] = jnp.linalg.norm(xm)
+            inv["normxm"] = jnp.linalg.norm(inv["xc"])
 
             def _hat(mat):
                 flat = _as_condensed(mat, self.n)
@@ -85,7 +85,7 @@ class PartialMantelStatistic:
                 return centered / jnp.linalg.norm(centered)
 
             yhat, zhat = _hat(self.y), _hat(self.z)
-            r_yz = jnp.dot(yhat, zhat)               # permutation-invariant
+            r_yz = jnp.dot(yhat, zhat, precision=HIGHEST)  # perm-invariant
             inv["r_yz"] = r_yz
             inv["y_res"] = (yhat - r_yz * zhat) / jnp.sqrt(1.0 - r_yz * r_yz)
             inv["z"] = zhat
@@ -96,8 +96,8 @@ class PartialMantelStatistic:
         o = order.astype(jnp.int32)
         k = condensed_index(o[inv["ii"]], o[inv["jj"]], self.n)
         xg = inv["xc"][k]                            # ONE gather, two dots
-        num = jnp.dot(xg, inv["y_res"]) / inv["normxm"]
-        r_xz = jnp.dot(xg, inv["z"]) / inv["normxm"]
+        num = jnp.dot(xg, inv["y_res"], precision=HIGHEST) / inv["normxm"]
+        r_xz = jnp.dot(xg, inv["z"], precision=HIGHEST) / inv["normxm"]
         return num / jnp.sqrt(1.0 - r_xz * r_xz)
 
     def per_batch(self, inv, orders):

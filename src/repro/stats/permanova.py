@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.centering import center_distance_matrix
 from repro.core.distance_matrix import DistanceMatrix
+from repro.kernels.dispatch import HIGHEST
 from repro.stats import engine
 from repro.stats.engine import PermutationTestResult
 
@@ -64,7 +65,8 @@ class PermanovaStatistic:
 
     def per_perm(self, inv, order):
         z = inv["z"][order]                          # O(n·k) label gather
-        s = jnp.sum(z * (inv["g"] @ z), axis=0)      # (k,) quadratic forms
+        s = jnp.sum(z * jnp.matmul(inv["g"], z, precision=HIGHEST),
+                    axis=0)                          # (k,) quadratic forms
         ss_among = jnp.sum(s / inv["sizes"])
         ss_within = inv["ss_total"] - ss_among
         dof_among = self.num_groups - 1

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.distance_matrix import DistanceMatrix
+from repro.kernels.dispatch import HIGHEST
 from repro.stats import engine
 from repro.stats.engine import PermutationTestResult
 
@@ -60,14 +61,16 @@ class PermdispStatistic:
 
     def per_perm(self, inv, order):
         z = inv["z"][order]                          # O(n·g) label gather
-        centroids = (z.T @ inv["x"]) / inv["sizes"][:, None]
-        dev = inv["x"] - z @ centroids               # x_i − C_{g(i)}
+        centroids = jnp.matmul(z.T, inv["x"],
+                               precision=HIGHEST) / inv["sizes"][:, None]
+        dev = inv["x"] - jnp.matmul(z, centroids,
+                                    precision=HIGHEST)   # x_i − C_{g(i)}
         v = jnp.sqrt(jnp.maximum(jnp.sum(dev * dev, axis=1), 0.0))
         # one-way ANOVA F over the dispersions v
-        group_means = (z.T @ v) / inv["sizes"]
+        group_means = jnp.matmul(z.T, v, precision=HIGHEST) / inv["sizes"]
         grand = jnp.mean(v)
         ss_between = jnp.sum(inv["sizes"] * (group_means - grand) ** 2)
-        resid = v - z @ group_means
+        resid = v - jnp.matmul(z, group_means, precision=HIGHEST)
         ss_within = jnp.sum(resid * resid)
         dof_between = self.num_groups - 1
         dof_within = self.n - self.num_groups

@@ -30,10 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# the TPU roofline constants live in launch.mesh (PEAK_FLOPS / HBM_BW);
-# reusing HBM_BW here keeps the tuner's TPU bandwidth and the roofline
-# model's the same number
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import chip_peaks
 
 __all__ = ["BackendBudget", "detect_budget", "calibrate",
            "save_profile", "load_profile"]
@@ -75,14 +72,18 @@ class BackendBudget:
         return BackendBudget(**d)
 
 
-#: static per-backend defaults. TPU: v5e VMEM (16 MiB) and the
-#: launch.mesh HBM roofline bandwidth. CPU: a conservative 1 MiB L2
-#: working slice (per-core L2 is 0.5–2 MiB across the x86 fleet; the
-#: solver prefers tiles that fit the SMALL end so they fit everywhere)
-#: over a 32 MiB L3. GPU: an 8 MiB L2 slice over HBM.
+#: static per-backend defaults. TPU rows are keyed by ``device_kind``:
+#: the v5e's default scoped VMEM (16 MiB) over its 16 GiB HBM, streamed
+#: at the published HBM bandwidth (``launch.mesh.CHIP_PEAKS``). CPU: a
+#: conservative 1 MiB L2 working slice (per-core L2 is 0.5–2 MiB across
+#: the x86 fleet; the solver prefers tiles that fit the SMALL end so they
+#: fit everywhere) over a 32 MiB L3. GPU: an 8 MiB L2 slice over HBM.
+_TPU_DEFAULTS = {
+    "TPU v5 lite": dict(working_bytes=16 * 2**20, capacity_bytes=16 * 2**30,
+                        bandwidth=chip_peaks("TPU v5 lite")["hbm_bw"],
+                        latency=3e-6),
+}
 _DEFAULTS = {
-    "tpu": dict(working_bytes=16 * 2**20, capacity_bytes=16 * 2**30,
-                bandwidth=HBM_BW, latency=3e-6),
     "cpu": dict(working_bytes=1 * 2**20, capacity_bytes=32 * 2**20,
                 bandwidth=3e10, latency=30e-6),
     "gpu": dict(working_bytes=8 * 2**20, capacity_bytes=2 * 2**30,
@@ -90,12 +91,26 @@ _DEFAULTS = {
 }
 
 
-def detect_budget(backend: Optional[str] = None) -> BackendBudget:
+def detect_budget(backend: Optional[str] = None,
+                  device_kind: Optional[str] = None) -> BackendBudget:
     """The static budget for ``backend`` (default: the live
-    ``jax.default_backend()``); unknown backends get the CPU column."""
+    ``jax.default_backend()``). A TPU is looked up by ``device_kind``
+    (default: the first live device's). An unknown backend or TPU kind
+    raises: tiles sized for another chip's memory would be silently
+    wrong."""
     be = backend or jax.default_backend()
-    d = _DEFAULTS.get(be, _DEFAULTS["cpu"])
-    return BackendBudget(backend=be, source="default", **d)
+    if be == "tpu":
+        kind = device_kind or jax.devices()[0].device_kind
+        row = _TPU_DEFAULTS.get(kind)
+        if row is None:
+            raise ValueError(f"no budget for TPU device kind {kind!r}; "
+                             f"known: {sorted(_TPU_DEFAULTS)}")
+    else:
+        row = _DEFAULTS.get(be)
+        if row is None:
+            raise ValueError(f"no budget for backend {be!r}; known: "
+                             f"{sorted(_DEFAULTS) + ['tpu']}")
+    return BackendBudget(backend=be, source="default", **row)
 
 
 def _time_pass(x: jax.Array, reps: int = 5) -> float:

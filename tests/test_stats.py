@@ -296,15 +296,16 @@ def test_partial_mantel_rejects_collinear_control():
 
 
 def test_partial_mantel_controls_for_confounder():
-    """y == x ⇒ partial r stays ~1 whatever z; controlling x's own driver
-    z == x must *not* report spurious correlation against independent y."""
+    """y == x ⇒ partial r stays ~1 whatever z; controlling for x itself
+    (z == x) leaves no partial correlation to report (0/0), so it is
+    refused, never reported as a spurious correlation against an
+    independent y."""
     x, z = _dm(16), _dm(17)
     r_same = partial_mantel(x, x, z, permutations=32, key=KEY)
     assert r_same.statistic > 0.99
     y_indep = _dm(18)
-    r_ctl = partial_mantel(x, y_indep, x, permutations=99, key=KEY)
-    assert abs(r_ctl.statistic) < 0.2
-    assert r_ctl.p_value > 0.01
+    with pytest.raises(ValueError, match="x and z are .*collinear"):
+        partial_mantel(x, y_indep, x, permutations=99, key=KEY)
 
 
 # --------------------------------------------------------------------------

@@ -52,12 +52,23 @@ def test_train_restart_is_seamless(tmp_path):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_serve_launcher_continuous_batching():
+def test_serve_launcher_continuous_batching(monkeypatch):
+    """The analysis-service launcher: every request of a mixed battery
+    ends ``done`` over coalesced tiles, and a request that does not end
+    ``done`` makes ``main`` exit non-zero."""
     res = serve_launch.run(argparse.Namespace(
-        arch="llama3.2-3b", smoke=True, batch=2, requests=4,
-        prompt_len=16, gen_len=8))
-    assert res["requests"] == 4
-    assert res["tokens"] == 4 * 8
+        studies=3, requests=6, n=24, batch=8, permutations=49, seed=0,
+        show=0, json=False))
+    assert res["statuses"] == ["done"] * 6
+    assert res["scheduler"]["tiles_run"] > 0
+    # the environment names a cache directory, so main leaves JAX's
+    # cache configuration alone (JAX read the variable at import)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "unused")
+    assert serve_launch.main(["--smoke", "--requests", "4"]) == 0
+    # one study: request 5's partial Mantel controls for the very study
+    # it correlates with, which is refused as collinear
+    assert serve_launch.main(["--smoke", "--studies", "1",
+                              "--requests", "5"]) == 1
 
 
 def test_microbiome_pipeline_end_to_end():

@@ -172,10 +172,15 @@ def test_solved_defaults_match_constants():
 # --------------------------------------------------------------------------
 def test_detect_budget_backends():
     for be in ("cpu", "tpu", "gpu"):
-        b = detect_budget(be)
+        b = detect_budget(be, device_kind="TPU v5 lite")
         assert b.backend == be and b.working_bytes > 0
         assert b.working_bytes <= b.capacity_bytes
     assert detect_budget().backend == jax.default_backend()
+    # no guessed defaults: an unknown TPU kind or backend is refused
+    with pytest.raises(ValueError, match="TPU v4"):
+        detect_budget("tpu", device_kind="TPU v4")
+    with pytest.raises(ValueError, match="metal"):
+        detect_budget("metal")
 
 
 def test_calibration_profile_roundtrip(tmp_path):
