@@ -113,8 +113,9 @@ def _smoke_report(path: str) -> None:
     print(f"#   hoist passes {led['hoist_passes']:.1f}  "
           f"total {led['total_bytes'] / 1e6:.2f} MB analytic  "
           f"ops {sorted(led['by_op'])}")
-    print(f"#   compile window: "
-          f"{ {k: v['programs'] for k, v in report.compile.items()} }")
+    programs = {k: v["programs"] for k, v in report.compile.items()}
+    print(f"#   compile window: {programs}  program prep "
+          f"{report.prep.get('seconds', 0.0):.2f} s")
 
 
 def main() -> None:

@@ -309,15 +309,20 @@ class Workspace:
         self.n = len(self._dm)
 
     def _admit_features(self, features, metric) -> None:
-        x = jnp.asarray(features)
-        if x.ndim != 2:
-            raise ValueError(f"expected an (n, d) feature table, "
-                             f"got shape {x.shape}")
-        ensure_finite(x, what="feature table")
-        if x.dtype != jnp.float32:
-            x = x.astype(jnp.float32)
-        if self.config.device is not None:
-            x = jax.device_put(x, self.config.device)
+        with self._obs.span("ws.from_features") as span:
+            with self._obs.span("ws.upload"):
+                x = jnp.asarray(features)
+            if x.ndim != 2:
+                raise ValueError(f"expected an (n, d) feature table, "
+                                 f"got shape {x.shape}")
+            span.add(n=int(x.shape[0]), d=int(x.shape[1]))
+            with self._obs.span("ws.validate"):
+                # the finiteness check waits for the upload to land
+                ensure_finite(x, what="feature table")
+                if x.dtype != jnp.float32:
+                    x = x.astype(jnp.float32)
+                if self.config.device is not None:
+                    x = jax.device_put(x, self.config.device)
         self._features = x
         self._metric = get_metric(metric if metric is not None
                                   else self.config.metric)

@@ -88,17 +88,25 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
     """One row strip + its fused row sums: (strip, Σ_j d²).
 
     The row sums ride the same jit region as the strip compute, so XLA
-    fuses them into the panel sweep — the hoist costs no extra pass."""
+    fuses them into the panel sweep — the hoist costs no extra pass.
+    Profiler scopes: ``dist.accumulate`` (the strip) and
+    ``dist.rowsums``."""
     note_trace("dist.panel_stats",
-               (xi.shape, x.shape, metric.name, feature_block, impl, block))
-    if impl == "pallas":
-        from repro.kernels.pairwise_ops import pairwise_panel_pallas
-        strip = pairwise_panel_pallas(xi, x, metric=metric, block_n=block,
-                                      feature_block=feature_block,
-                                      interpret=interpret)
-    else:
-        strip = _panel_xla(xi, x, metric, feature_block)
-    return strip, jnp.sum(strip * strip, axis=1)
+               (xi.shape, x.shape, metric.name, feature_block, impl, block),
+               _panel_stats, (xi, x),
+               {"metric": metric, "feature_block": feature_block,
+                "impl": impl, "interpret": interpret, "block": block})
+    with jax.named_scope("dist.accumulate"):
+        if impl == "pallas":
+            from repro.kernels.pairwise_ops import pairwise_panel_pallas
+            strip = pairwise_panel_pallas(xi, x, metric=metric,
+                                          block_n=block,
+                                          feature_block=feature_block,
+                                          interpret=interpret)
+        else:
+            strip = _panel_xla(xi, x, metric, feature_block)
+    with jax.named_scope("dist.rowsums"):
+        return strip, jnp.sum(strip * strip, axis=1)
 
 
 def pairwise_condensed(x, metric="braycurtis", *,

@@ -49,7 +49,10 @@ _chunk_geometry = snap_chunk
 
 
 def _reduce_xla(xc, ys, ii, jj, orders, n: int, chunk: int) -> jax.Array:
-    """The lax.scan twin: same chunking, same math, pure XLA."""
+    """The lax.scan twin: same chunking, same math, pure XLA. Its steps
+    carry the profiler scopes ``index`` (order gathers and triangle
+    arithmetic), ``gather`` (the take from ``xc``) and ``reduce`` (the
+    matmul)."""
     s, m_pad = ys.shape
     num_chunks = m_pad // chunk
     ii_c = ii.reshape(num_chunks, chunk)
@@ -58,13 +61,16 @@ def _reduce_xla(xc, ys, ii, jj, orders, n: int, chunk: int) -> jax.Array:
 
     def body(acc, operands):
         ic, jc, yc = operands                      # (chunk,), (S, chunk)
-        oi = jnp.take(orders, ic, axis=1)          # (B, chunk) order gather
-        oj = jnp.take(orders, jc, axis=1)
-        lo = jnp.minimum(oi, oj)
-        hi = jnp.maximum(oi, oj)
-        k = lo * (2 * n - lo - 1) // 2 + (hi - lo - 1)
-        xg = jnp.take(xc, k)                       # (B, chunk) xc gather
-        return acc + jnp.matmul(yc, xg.T, precision=HIGHEST), None
+        with jax.named_scope("index"):
+            oi = jnp.take(orders, ic, axis=1)      # (B, chunk) order gather
+            oj = jnp.take(orders, jc, axis=1)
+            lo = jnp.minimum(oi, oj)
+            hi = jnp.maximum(oi, oj)
+            k = lo * (2 * n - lo - 1) // 2 + (hi - lo - 1)
+        with jax.named_scope("gather"):
+            xg = jnp.take(xc, k)                   # (B, chunk) xc gather
+        with jax.named_scope("reduce"):
+            return acc + jnp.matmul(yc, xg.T, precision=HIGHEST), None
 
     acc0 = jnp.zeros((s, orders.shape[0]), dtype=xc.dtype)
     out, _ = jax.lax.scan(body, acc0, (ii_c, jj_c, ys_c))
@@ -135,8 +141,11 @@ def _permute_reduce_jit(xc: jax.Array, ys: jax.Array, orders: jax.Array,
         jj = jnp.pad(jj, (0, pad), constant_values=1)
 
     if impl == "pallas":
-        return permute_reduce_kernel(xc, ys, ii, jj, orders, chunk=chunk,
-                                     interpret=interpret)
+        # one kernel does the index arithmetic, gather and reduce; the
+        # gather is what it is for
+        with jax.named_scope("gather"):
+            return permute_reduce_kernel(xc, ys, ii, jj, orders,
+                                         chunk=chunk, interpret=interpret)
     return _reduce_xla(xc, ys, ii, jj, orders, n, chunk)
 
 

@@ -5,14 +5,18 @@ the SSD-profiling study's rule, applied to our own runtime):
 
 * ``obs.trace``   — nested span tracer with phase tags
   (hoist | per_perm | production | solve | step), JSON + Chrome
-  ``trace_event`` export, optional ``jax.profiler.TraceAnnotation``
-  bridge, and a zero-overhead no-op fast path when disabled;
+  ``trace_event`` export; every span, with or without a session, is a
+  ``jax.profiler.TraceAnnotation`` too, so any profiler capture holds
+  the program's spans;
 * ``obs.ledger``  — THE audited analytic-traffic registry (hoist pass
   tables, Mantel per-permutation models, production feature reads),
   shared by the benchmarks and charged live by the instrumented stack;
 * ``obs.compile`` — the recompile sentinel: jit trace/program counts
   per wrapped entry point, with a runtime guard for the "one trace
-  serves any K" invariant;
+  serves any K" invariant; the program-preparation counter (trace,
+  lowering, compile and cache-load seconds); and ``hlo_texts``, the
+  compiled HLO whose metadata holds each instruction's
+  ``jax.named_scope`` path;
 * ``obs.report``  — ``ObsSession`` (one run's tracer+ledger+sentinel
   window) and ``RunReport`` (the one-JSON-per-run artifact CI uploads);
 * ``obs.probe``   — the MEASURED half: AOT-compiled flop/byte/peak
@@ -41,7 +45,7 @@ from repro.obs.metrics import (DEFAULT_LATENCY_BUCKETS, NULL_HISTOGRAM,
 from repro.obs.probe import (ProbeRecord, probe_lowered, probe_session,
                              probe_table, scan_corrected_bytes)
 from repro.obs.report import ObsSession, RunReport, build_report
-from repro.obs.trace import (NULL_OBS, NULL_SPAN, PHASES, Span, Tracer,
+from repro.obs.trace import (NULL_OBS, PHASES, ProfilerSpan, Span, Tracer,
                              current_obs)
 
 __all__ = [
@@ -55,5 +59,5 @@ __all__ = [
     "ProbeRecord", "probe_lowered", "probe_session", "probe_table",
     "scan_corrected_bytes",
     "ObsSession", "RunReport", "build_report",
-    "NULL_OBS", "NULL_SPAN", "PHASES", "Span", "Tracer", "current_obs",
+    "NULL_OBS", "PHASES", "ProfilerSpan", "Span", "Tracer", "current_obs",
 ]

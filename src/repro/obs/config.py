@@ -7,12 +7,14 @@ observability (the span list, the ledger entries) lives in
 ``obs.report.ObsSession``, which a ``Workspace`` constructs FROM this
 config; the config only says what to collect.
 
-``enabled=False`` (the default) is the zero-overhead contract: a
+``enabled=False`` (the default) is the near-zero-overhead contract: a
 Workspace built with it never constructs a session — every ``span()``
-call resolves to the shared no-op singleton (``obs.trace.NULL_SPAN``)
-and every ledger charge is a no-op method on ``obs.trace.NULL_OBS``.
-The recompile sentinel (``obs.compile``) is the one always-on piece:
-it only runs at jit-trace time, so it costs nothing per call.
+call resolves to a bare profiler annotation (``obs.trace.ProfilerSpan``:
+no tracer state) and every ledger charge is a no-op method on
+``obs.trace.NULL_OBS``. The annotations and the recompile sentinel
+(``obs.compile``) are the always-on pieces: an annotation costs well
+under a microsecond with no profiler running, and the sentinel runs
+only at jit-trace and compile time.
 
 This module deliberately imports nothing from ``repro`` (and nothing
 heavier than ``dataclasses``) so ``api.config`` can import it without
@@ -32,19 +34,14 @@ class ObsConfig:
     ------
     enabled:
         Master switch. ``False`` (default): no session is created, every
-        span/charge resolves to the no-op fast path — measured session
-        overhead is the cost of one attribute lookup per call site.
+        span is a bare profiler annotation and every charge a no-op.
     spans:
         Collect the nested wall-time span tree (``obs.trace.Tracer``).
+        Spans reach a ``jax.profiler`` capture either way.
     ledger:
         Charge the analytic traffic ledger (``obs.ledger.Ledger``) at the
         instrumented call sites — hoist builds, permutation batches, the
         distance production sweep.
-    annotate_xla:
-        Bridge each span into ``jax.profiler.TraceAnnotation`` so spans
-        line up inside XLA profiles (Perfetto / TensorBoard). Off by
-        default: it adds a profiler call per span even when no profile
-        is being taken.
     probe:
         Measure the session's jitted entry points at report time
         (``obs.probe``: AOT-compiled flop/byte/peak counts) and
@@ -58,11 +55,10 @@ class ObsConfig:
     enabled: bool = False
     spans: bool = True
     ledger: bool = True
-    annotate_xla: bool = False
     probe: bool = True
 
     def __post_init__(self):
-        for f in ("enabled", "spans", "ledger", "annotate_xla", "probe"):
+        for f in ("enabled", "spans", "ledger", "probe"):
             v = getattr(self, f)
             if not isinstance(v, bool):
                 raise ValueError(f"ObsConfig.{f} must be a bool, "

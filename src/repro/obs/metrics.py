@@ -15,9 +15,9 @@ reporting API. These primitives are fixed-footprint by construction:
 * ``Counter`` / ``Gauge`` — named scalars with the same ``to_dict`` /
   Prometheus surface, so breach counts and queue depths export beside
   the distributions.
-* ``NULL_HISTOGRAM`` — the disabled fast path, mirroring
-  ``obs.trace.NULL_SPAN``: a shared singleton whose ``record()`` is a
-  no-op method call, allocation-free, so call sites never branch.
+* ``NULL_HISTOGRAM`` — the disabled fast path: a shared singleton
+  whose ``record()`` is a no-op method call, allocation-free, so call
+  sites never branch.
 
 Export: ``to_dict()`` everywhere (JSON, rides ``serve_report()``), and
 ``prometheus_text()`` renders any mix of the three as Prometheus
@@ -145,7 +145,7 @@ class Histogram:
 
 class _NullHistogram:
     """The disabled fast path — record() is a no-op, allocation-free.
-    A shared singleton (``NULL_HISTOGRAM``), like ``NULL_SPAN``."""
+    A shared singleton (``NULL_HISTOGRAM``)."""
 
     __slots__ = ()
 

@@ -25,7 +25,7 @@ from typing import Optional
 from repro.obs.compile import sentinel
 from repro.obs.config import ObsConfig
 from repro.obs.ledger import Ledger
-from repro.obs.trace import NULL_SPAN, Tracer
+from repro.obs.trace import ProfilerSpan, Tracer
 
 
 class ObsSession:
@@ -36,17 +36,19 @@ class ObsSession:
     def __init__(self, config: Optional[ObsConfig] = None):
         self.config = config if config is not None else ObsConfig(
             enabled=True)
-        self.tracer = Tracer(annotate_xla=self.config.annotate_xla)
+        self.tracer = Tracer()
         self.ledger = Ledger()
         self.sentinel = sentinel
         self.sentinel_base = sentinel.snapshot()
+        self.prep_base = sentinel.prep()
 
     # -- spans -------------------------------------------------------------
     def span(self, name: str, phase: Optional[str] = None, **attrs):
         """A session span: entering it also makes this session ambient
-        (``current_obs()``) for the enclosed call chain."""
+        (``current_obs()``) for the enclosed call chain. With
+        ``config.spans`` off it is the bare profiler annotation."""
         if not self.config.spans:
-            return NULL_SPAN
+            return ProfilerSpan(name)
         return self.tracer.span(name, phase, session=self, **attrs)
 
     # -- ledger charges (gated on config.ledger) ---------------------------
@@ -72,16 +74,23 @@ class ObsSession:
         """Traces/programs noted since this session began."""
         return self.sentinel.since(self.sentinel_base)
 
+    def prep_delta(self) -> dict:
+        """Program preparation counted since this session began."""
+        return self.sentinel.prep_since(self.prep_base)
+
 
 @dataclasses.dataclass
 class RunReport:
-    """One run, one document: spans + ledger + cache + compile counts.
+    """One run, one document: spans + ledger + cache + compile counts
+    + program preparation.
 
     ``meta`` carries provenance (jax version, backend, session shape);
     ``spans`` is the tracer's nested dict tree; ``ledger`` the totals
     plus every entry; ``cache`` the HoistCache hit/miss counters and
     generation; ``compile`` the sentinel's per-entry-point trace and
-    program counts for the run's window.
+    program counts for the run's window; ``prep`` the program
+    preparation counted in it (``sentinel.prep()``: ``seconds``, per
+    kind counts and seconds, cache hits and misses, by entry point).
     """
 
     meta: dict
@@ -91,12 +100,13 @@ class RunReport:
     compile: dict
     measured: dict = dataclasses.field(default_factory=dict)
     drift: dict = dataclasses.field(default_factory=dict)
+    prep: dict = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return {"meta": self.meta, "spans": self.spans,
                 "ledger": self.ledger, "cache": self.cache,
                 "compile": self.compile, "measured": self.measured,
-                "drift": self.drift}
+                "drift": self.drift, "prep": self.prep}
 
     def to_json(self, indent: Optional[int] = 2) -> str:
         return json.dumps(self.to_dict(), indent=indent, default=str)
@@ -143,8 +153,8 @@ def build_report(session: Optional[ObsSession] = None, cache=None,
     """Assemble a ``RunReport`` from a session (tracer + ledger +
     sentinel window) and an optional HoistCache. With ``session=None``
     (observability disabled) the report still carries the cache
-    counters and the sentinel's full process snapshot — the always-on
-    telemetry — with empty spans and ledger.
+    counters and the sentinel's full process snapshot and prep counters
+    — the always-on telemetry — with empty spans and ledger.
 
     ``measured`` is a ``{name: ProbeRecord}`` mapping from
     ``obs.probe.probe_session`` (serialized here); ``drift`` the
@@ -164,9 +174,11 @@ def build_report(session: Optional[ObsSession] = None, cache=None,
                          cache=_cache_section(cache),
                          compile=session.compile_delta(),
                          measured=measured_section,
-                         drift=dict(drift or {}))
+                         drift=dict(drift or {}),
+                         prep=session.prep_delta())
     return RunReport(meta=base_meta, spans=[], ledger={},
                      cache=_cache_section(cache),
                      compile=sentinel.snapshot(),
                      measured=measured_section,
-                     drift=dict(drift or {}))
+                     drift=dict(drift or {}),
+                     prep=sentinel.prep())

@@ -6,9 +6,9 @@ ROADMAP's corollary is that container wall-clock is ±40% noise, so the
 with which analytic cost — is the trustworthy signal and the timing is
 the informational overlay. A ``Span`` records both: host-side wall time
 (``perf_counter``; note jax dispatch is async, so a span bounds the
-host's dispatch+sync work, not device occupancy — use ``annotate_xla``
-to line spans up inside an XLA profile for device truth) plus a phase
-tag from the analysis stack's vocabulary:
+host's dispatch+sync work, not device occupancy — the profiler
+annotation below lines spans up with the device ops) plus a phase tag
+from the analysis stack's vocabulary:
 
 * ``hoist``      — a permutation-invariant O(n²)/O(m) artifact build
   (the HoistCache miss path);
@@ -21,19 +21,25 @@ tag from the analysis stack's vocabulary:
   scheduling, request lifecycle).
 
 Spans nest (a ``ws.permanova`` span contains its ``hoist:gram`` child
-and the engine's ``per_perm`` span), export as plain dicts / JSON and as
-Chrome ``trace_event`` JSON (load in ``chrome://tracing`` / Perfetto),
-and optionally bridge into ``jax.profiler.TraceAnnotation``.
+and the engine's ``per_perm`` span) and export as plain dicts / JSON and
+as Chrome ``trace_event`` JSON (load in ``chrome://tracing`` /
+Perfetto).
 
-The no-op fast path is the contract that lets every hot call site stay
-instrumented unconditionally: with no active session, ``current_obs()``
-returns the shared ``NULL_OBS`` singleton whose ``span()`` returns the
-shared ``NULL_SPAN`` singleton — no allocation, no branching beyond one
-list check. ``tests/test_obs.py`` pins both the identity (no per-call
-allocation) and a generous per-call time bound.
+Every span, with or without a session, is also a
+``jax.profiler.TraceAnnotation`` of its name: any ``jax.profiler``
+capture holds the program's spans on the host plane, on the profiler's
+clock, beside the device ops. With no profiler running an annotation
+costs well under a microsecond.
 
-This module imports nothing from ``repro`` (jax only, lazily, for the
-profiler bridge) so any layer can import it without cycles.
+The session-less path is the contract that lets every hot call site
+stay instrumented unconditionally: with no active session,
+``current_obs()`` returns the shared ``NULL_OBS`` singleton whose
+``span()`` returns a bare ``ProfilerSpan`` — the annotation alone, with
+no tracer state and no ledger entry. ``tests/test_obs.py`` pins that
+contract and a generous per-call time bound.
+
+This module imports nothing from ``repro`` (jax only, for the profiler
+annotation) so any layer can import it without cycles.
 """
 
 from __future__ import annotations
@@ -41,6 +47,8 @@ from __future__ import annotations
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 #: the phase vocabulary — see the module docstring
 PHASES = ("hoist", "per_perm", "production", "solve", "step", "serve")
@@ -80,13 +88,8 @@ class Span:
         self._tracer._open(self)
         if self._session is not None:
             push_obs(self._session)
-        if self._tracer.annotate_xla:
-            try:                         # the profiler bridge is best-effort
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         return self
 
     def end(self) -> "Span":
@@ -134,8 +137,7 @@ class Tracer:
     like the HoistCache it instruments.
     """
 
-    def __init__(self, annotate_xla: bool = False):
-        self.annotate_xla = annotate_xla
+    def __init__(self):
         self.epoch = time.perf_counter()
         self.spans: list[Span] = []
         self._stack: list[Span] = []
@@ -241,43 +243,38 @@ class Tracer:
 
 
 # --------------------------------------------------------------------------
-# The no-op fast path + the ambient session stack
+# The session-less path + the ambient session stack
 # --------------------------------------------------------------------------
-class _NullSpan:
-    """THE no-op span: one process-wide singleton, so the disabled path
-    allocates nothing per call (pinned by tests/test_obs.py)."""
+class ProfilerSpan(TraceAnnotation):
+    """A span with no session: the profiler annotation of its name and
+    nothing else — no tracer state, no ledger entry. Same surface as
+    ``Span``; ``add`` drops its attrs."""
 
     __slots__ = ()
 
-    def __enter__(self):
+    def begin(self) -> "ProfilerSpan":
+        self.__enter__()
         return self
 
-    def __exit__(self, *exc):
-        return False
-
-    def begin(self):
+    def end(self) -> "ProfilerSpan":
+        self.__exit__(None, None, None)
         return self
 
-    def end(self):
+    def add(self, **attrs) -> "ProfilerSpan":
         return self
-
-    def add(self, **attrs):
-        return self
-
-
-NULL_SPAN = _NullSpan()
 
 
 class _NullObs:
-    """THE no-op session: every instrumented call site talks to this when
-    observability is off (or no session is ambient). Same method surface
-    as ``obs.report.ObsSession``, all free."""
+    """THE session-less session: every instrumented call site talks to
+    this when observability is off (or no session is ambient). Same
+    method surface as ``obs.report.ObsSession``: spans are bare profiler
+    annotations, charges are free no-ops."""
 
     __slots__ = ()
     enabled = False
 
     def span(self, name, phase=None, **attrs):
-        return NULL_SPAN
+        return ProfilerSpan(name)
 
     def charge(self, op, floats, **params):
         return None

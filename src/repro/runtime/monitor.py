@@ -36,7 +36,7 @@ import statistics
 import time
 from typing import List, Optional
 
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import ProfilerSpan, Span, Tracer
 
 
 @dataclasses.dataclass
@@ -84,7 +84,10 @@ class StepMonitor:
 
     ``tracer`` defaults to a private ``Tracer``; pass a session's tracer
     (e.g. ``workspace.obs.tracer``) to interleave step spans with the
-    analysis spans in one exported timeline.
+    analysis spans in one exported timeline. ``span`` times work inside
+    or around a step: on a tracer passed in, a span of its own; on the
+    private one, the profiler annotation only, so that tracer keeps the
+    steps alone.
     """
 
     def __init__(self, k: float = 3.0, warmup: int = 3,
@@ -94,6 +97,7 @@ class StepMonitor:
         self.warmup = warmup
         self.deadline_factor = deadline_factor
         self.tracer = tracer if tracer is not None else Tracer()
+        self._shared = tracer is not None
         self._spans: List[Span] = []         # this monitor's step spans
         self._open: Optional[Span] = None
         self.escalations: List[EscalationRecord] = []
@@ -101,6 +105,12 @@ class StepMonitor:
     # -- timing ---------------------------------------------------------
     def start(self):
         self._open = self.tracer.span("step", phase="step").begin()
+
+    def span(self, name: str, phase: Optional[str] = None):
+        """A span of work inside or around a step (see the class)."""
+        if self._shared:
+            return self.tracer.span(name, phase)
+        return ProfilerSpan(name)
 
     def stop(self, step: int) -> StepRecord:
         if self._open is None:

@@ -63,7 +63,14 @@ Fault tolerance (the recovery half of ``repro.faults``):
 Every tile is timed through a ``runtime.monitor.StepMonitor`` span
 (phase="step"), so the straggler/deadline watchdog covers serve loops,
 and charged to the study's ``repro.obs`` ledger with the same
-``charge_perm_batch`` terms the library engine uses.
+``charge_perm_batch`` terms the library engine uses. Inside the step,
+``serve.dispatch`` times enqueueing ``engine.tile_statistics``,
+``serve.fetch`` the blocking device-to-host copy and ``serve.check``
+the finiteness check; ``serve.journal`` (each progress record) follows
+the step, and ``serve.backoff`` times the sleep while every lane backs
+off. They reach any ``jax.profiler`` capture, and are kept as children
+of the step only on a tracer given to the monitor
+(``StepMonitor.span``).
 """
 
 from __future__ import annotations
@@ -367,7 +374,8 @@ class TileScheduler:
             waits = [ln.not_before - now for ln in self.lanes.values()
                      if ln.pending_rows()]
             if waits:                     # all backing off: wait it out
-                time.sleep(min(min(waits), 0.05))
+                with self.monitor.span("serve.backoff", phase="serve"):
+                    time.sleep(min(min(waits), 0.05))
                 return True
             return False
         # round-robin: the lane we serve moves to the back
@@ -441,12 +449,18 @@ class TileScheduler:
                 raise AllocFault("injected allocator OOM on tile")
             elif spec.kind == "nan":
                 poison_rows = spec
-        values = np.asarray(
-            engine.tile_statistics(lane.stat, lane.invariants, tile))
+        span = self.monitor.span         # inside the open step span
+        with span("serve.dispatch", phase="serve"):
+            pending = engine.tile_statistics(lane.stat, lane.invariants,
+                                             tile)
+        with span("serve.fetch", phase="serve"):
+            values = np.asarray(pending)  # blocks on the device
         if poison_rows is not None:
             values = values.copy()
             values[:] = np.nan
-        if not np.all(np.isfinite(values)):
+        with span("serve.check", phase="serve"):
+            finite = bool(np.all(np.isfinite(values)))
+        if not finite:
             raise PoisonError(
                 f"tile returned non-finite statistics "
                 f"({int(np.sum(~np.isfinite(values)))}/{values.size} rows)")
@@ -620,10 +634,11 @@ class TileScheduler:
     # -- streaming / journaling --------------------------------------------
     def _journal_progress(self, active: _Active) -> None:
         if self.journal is not None:
-            self.journal.append({"t": "progress",
-                                 "rid": active.handle.request_id,
-                                 "cursor": int(active.cursor),
-                                 "count": int(active.count)})
+            with self.monitor.span("serve.journal", phase="serve"):
+                self.journal.append({"t": "progress",
+                                     "rid": active.handle.request_id,
+                                     "cursor": int(active.cursor),
+                                     "count": int(active.count)})
 
     def _emit(self, active: _Active) -> None:
         k = int(active.orders.shape[0])

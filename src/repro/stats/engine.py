@@ -183,9 +183,10 @@ def hoist_and_observe(stat):
     then streams tiles through ``tile_statistics``.
     """
     note_trace("stats.engine.hoist_and_observe",
-               (type(stat).__name__, stat.n))
-    inv = stat.hoist()
-    return inv, stat.per_perm(inv, jnp.arange(stat.n))
+               (type(stat).__name__, stat.n), hoist_and_observe, (stat,))
+    with jax.named_scope("perm.hoist"):
+        inv = stat.hoist()
+        return inv, stat.per_perm(inv, jnp.arange(stat.n))
 
 
 @jax.jit
@@ -201,11 +202,13 @@ def tile_statistics(stat, invariants, orders):
     a request's draws do not depend on its tile-mates.
     """
     note_trace("stats.engine.tile",
-               (type(stat).__name__, stat.n, orders.shape[0]))
+               (type(stat).__name__, stat.n, orders.shape[0]),
+               tile_statistics, (stat, invariants, orders))
     per_batch = getattr(stat, "per_batch", None)
-    if per_batch is not None:
-        return per_batch(invariants, orders)
-    return jax.vmap(lambda o: stat.per_perm(invariants, o))(orders)
+    with jax.named_scope("perm.draws"):
+        if per_batch is not None:
+            return per_batch(invariants, orders)
+        return jax.vmap(lambda o: stat.per_perm(invariants, o))(orders)
 
 
 @partial(jax.jit, static_argnames=("permutations", "batch_size"))
@@ -215,15 +218,25 @@ def _null_distribution(stat, key, permutations: int, batch_size: int):
     ``stat`` is a pytree: its arrays are traced, its static metadata (n,
     group count, …) keys the jit cache, so repeated tests of the same
     shape reuse the compiled executable.
+
+    Three ``jax.named_scope``s split the program for the profiler, named
+    after what the test defines rather than how it is computed:
+    ``perm.orders`` (drawing the orders and padding them to whole
+    tiles), ``perm.hoist`` (the invariants and the observed statistic)
+    and ``perm.draws`` (the K null draws).
     """
     # trace-time only (a jitted body runs once per distinct signature):
     # the sentinel's count of engine programs, free at execution time
     note_trace("stats.engine.null_distribution",
-               (type(stat).__name__, stat.n, permutations, batch_size))
-    invariants = stat.hoist()                      # runs exactly once
-    observed = stat.per_perm(invariants, jnp.arange(stat.n))
+               (type(stat).__name__, stat.n, permutations, batch_size),
+               _null_distribution, (stat, key),
+               {"permutations": permutations, "batch_size": batch_size})
+    with jax.named_scope("perm.hoist"):
+        invariants = stat.hoist()                  # runs exactly once
+        observed = stat.per_perm(invariants, jnp.arange(stat.n))
 
-    orders = permutation_orders(key, permutations, stat.n)
+    with jax.named_scope("perm.orders"):
+        orders = permutation_orders(key, permutations, stat.n)
     per_batch = getattr(stat, "per_batch", None)
     if per_batch is not None and permutations:
         # ONE trace serves every K: orders are padded up to full
@@ -241,16 +254,19 @@ def _null_distribution(stat, key, permutations: int, batch_size: int):
                    (type(stat).__name__, stat.n, batch_size))
         num_tiles = -(-permutations // batch_size)
         total = num_tiles * batch_size
-        if total != permutations:
-            orders = orders[jnp.arange(total) % permutations]
-        tiles = orders.reshape(num_tiles, batch_size, stat.n)
-        permuted = jax.lax.map(lambda o: per_batch(invariants, o),
-                               tiles).reshape(total)[:permutations]
+        with jax.named_scope("perm.orders"):
+            if total != permutations:
+                orders = orders[jnp.arange(total) % permutations]
+            tiles = orders.reshape(num_tiles, batch_size, stat.n)
+        with jax.named_scope("perm.draws"):
+            permuted = jax.lax.map(lambda o: per_batch(invariants, o),
+                                   tiles).reshape(total)[:permutations]
     else:
         # lax.map auto-vmaps per_perm over each batch: the batched gathers
         # + one fused reduce, with peak memory of one batch of matrices.
-        permuted = jax.lax.map(lambda o: stat.per_perm(invariants, o),
-                               orders, batch_size=batch_size)
+        with jax.named_scope("perm.draws"):
+            permuted = jax.lax.map(lambda o: stat.per_perm(invariants, o),
+                                   orders, batch_size=batch_size)
     return observed, permuted
 
 
@@ -288,8 +304,10 @@ def permutation_test(stat: Statistic, permutations: int = 999,
         # padded tail rows are real gathers, so they are charged too
         obs.charge_perm_batch(method or type(stat).__name__, stat.n,
                               tiles * bs, bs)
-    return finish(observed, permuted, permutations, alternative, stat.n,
-                  method=method, key=key)
+    with obs.span("engine.finish", n=stat.n, permutations=permutations):
+        # the p-value's count waits for the draws: the blocking fetch
+        return finish(observed, permuted, permutations, alternative,
+                      stat.n, method=method, key=key)
 
 
 # --------------------------------------------------------------------------

@@ -14,8 +14,8 @@ import pytest
 from repro.api import ExecConfig, Workspace
 from repro.core import random_distance_matrix
 from repro.obs import (FEATURE_HOIST_PASSES, HOIST_PASSES, NULL_OBS,
-                       NULL_SPAN, CompileSentinel, Ledger, ObsConfig,
-                       RecompileError, RunReport, Tracer, build_report,
+                       CompileSentinel, Ledger, ObsConfig, ProfilerSpan,
+                       RecompileError, RunReport, Span, Tracer, build_report,
                        current_obs, perm_traffic_floats, production_floats,
                        sentinel)
 
@@ -176,12 +176,14 @@ def test_ambient_session_stack():
 # the disabled path: zero-overhead contract
 # --------------------------------------------------------------------------
 def test_null_singletons_are_process_wide():
-    """The no-op fast path allocates nothing per call: every disabled
-    span/session IS the shared singleton."""
-    assert NULL_OBS.span("anything", phase="hoist", n=10) is NULL_SPAN
-    assert NULL_SPAN.__enter__() is NULL_SPAN
-    assert NULL_SPAN.add(x=1) is NULL_SPAN
-    assert NULL_SPAN.begin().end() is NULL_SPAN
+    """The session-less path keeps no state: a disabled session IS the
+    shared singleton, and its span is the profiler annotation alone —
+    no tracer state, no ledger entry, no ambient session."""
+    span = NULL_OBS.span("anything", phase="hoist", n=10)
+    assert isinstance(span, ProfilerSpan) and not isinstance(span, Span)
+    with span:
+        assert current_obs() is NULL_OBS
+    assert span.add(x=1) is span and span.begin().end() is span
     assert NULL_OBS.charge_hoist("gram", 100) is None
     assert not NULL_OBS.enabled
     # a default Workspace rides the singleton — no session object exists

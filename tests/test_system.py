@@ -101,3 +101,15 @@ def test_quickstart_example_runs():
     out = mod.main(fast=True)
     assert out["pcoa_dims"] >= 2
     assert 0 < out["mantel_p"] <= 1
+
+
+def test_community_analysis_example_runs(capsys):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "community_analysis",
+        os.path.join(os.path.dirname(__file__), "..", "examples",
+                     "community_analysis.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod.main(n=48, permutations=19)
+    assert "== recompile window:" in capsys.readouterr().out
