@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import json
 import os
 import sys
@@ -454,6 +455,8 @@ def multichip_phase(phases, checks, seed, n=MULTI_N, d=MULTI_D,
     for method, operands in (("permanova", {"grouping": groups}),
                              ("mantel", {"other": wy})):
         stat, _ = ws.statistic(method, **operands)
+        if method == "mantel":          # the sharded per_perm path
+            stat = dataclasses.replace(stat, layout="condensed")
         key = jax.random.PRNGKey(seed + 10)
         with phases(f"multichip.{method}"):
             observed, null = engine.null_distribution_distributed(

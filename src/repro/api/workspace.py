@@ -38,9 +38,11 @@ their permutation loops over condensed storage too
 (``kernels.permute_reduce`` closed-form triangle gathers), a
 feature-backed session completes the ENTIRE analysis battery — PCoA,
 PERMANOVA, PERMDISP, ANOSIM, Mantel, partial Mantel — with no n×n
-matrix of any kind ever allocated. The only remaining square builds are
-explicit opt-ins: ``gram`` for eigh/materialized ordination, and the
-``"square"`` key when the caller demands ``ws.dm`` itself. ``refresh()``
+distance matrix ever cached. The square builds are explicit opt-ins —
+``gram`` for eigh/materialized ordination, and the ``"square"`` key
+when the caller demands ``ws.dm`` itself — and, on a TPU, the Mantel
+test's own hoist: its ``"rows"`` draw layout (``draw_layout``) builds
+the centred x and ŷ square for the test. ``refresh()``
 invalidates the whole cache (generation-counted) when the underlying
 data changes.
 """
@@ -58,7 +60,8 @@ import numpy as np
 from repro.api.config import ExecConfig
 from repro.api.results import OrdinationResult
 from repro.core.distance_matrix import DistanceMatrix, condensed_to_square
-from repro.core.mantel import MantelStatistic, condensed_moments_vec
+from repro.core.mantel import (MantelStatistic, condensed_moments_vec,
+                               draw_layout)
 from repro.core.operators import (CenteredGramOperator,
                                   CondensedCenteredGramOperator)
 from repro.core.pcoa import pcoa as _pcoa
@@ -66,6 +69,7 @@ from repro.core.pcoa import resolve_dimensions
 from repro.core.validation import ensure_finite
 from repro.dist import get_metric, pairwise_condensed
 from repro.kernels.dispatch import HIGHEST
+from repro.launch.mesh import chip_peaks
 from repro.obs.ledger import FEATURE_HOIST_PASSES, HOIST_PASSES
 from repro.obs.report import ObsSession, RunReport, build_report
 from repro.obs.trace import NULL_OBS
@@ -417,8 +421,23 @@ class Workspace:
             "chunk_executed": snap_chunk(m, chunk)[0],
             "lane": lane,
             "auto": self.tuned is not None,
+            "draw_layout": self.draw_layout(),
         }
         return tiles
+
+    def draw_layout(self) -> str:
+        """The Mantel draws' layout at this session's n, tile and
+        backend (``core.mantel.draw_layout``): ``"rows"`` on a TPU whose
+        memory holds the squares, else ``"condensed"``."""
+        backend = jax.default_backend()
+        hbm = None
+        if backend == "tpu":
+            try:
+                hbm = chip_peaks(jax.devices()[0].device_kind)["hbm_bytes"]
+            except ValueError:   # no published peaks: keep the condensed loop
+                pass
+        return draw_layout(self.n, self.config.resolve_batch_size(None, 32),
+                           backend, hbm)
 
     def report(self, meta: Optional[dict] = None) -> RunReport:
         """The session's ``RunReport``: span tree, analytic ledger
@@ -664,7 +683,8 @@ class Workspace:
             return MantelStatistic(self.condensed(), None, self.n, pre=pre,
                                    kernel=self.config.kernel,
                                    interpret=self.config.interpret,
-                                   chunk=self.config.chunk), "two-sided"
+                                   chunk=self.config.chunk,
+                                   layout=self.draw_layout()), "two-sided"
         if method == "partial_mantel":
             y, z = self._coerce(other), self._coerce(control)
             if not (self.n == y.n == z.n):
@@ -758,13 +778,15 @@ class Workspace:
                alternative: str = "two-sided",
                batch_size: Optional[int] = None) -> PermutationTestResult:
         """Mantel test of this matrix (permuted side) against ``other``
-        (a Workspace, DistanceMatrix or raw array; held fixed). Fully
-        square-free: the permuted side rides in as the shared condensed
-        artifact (the batched loop's closed-form triangle gathers replace
-        the n×n ``x[order][:, order]`` buffer), the fixed side
-        contributes only its CONDENSED hat vector — neither session ever
-        demands the lazy ``"square"`` key, so feature-backed Workspaces
-        run the whole Mantel family with no n×n distance matrix."""
+        (a Workspace, DistanceMatrix or raw array; held fixed). The
+        permuted side rides in as the shared condensed artifact and the
+        fixed side as its CONDENSED hat vector — neither session ever
+        demands the lazy ``"square"`` key. The draws take the layout of
+        ``draw_layout()``: ``"condensed"`` (closed-form triangle gathers
+        over the condensed entries, nothing square; every backend but the
+        TPU, and any n whose squares do not fit) or ``"rows"`` (a TPU
+        whose memory holds them: the test's hoist builds the centred x
+        and ŷ as two hollow squares, and each draw gathers their rows)."""
         with self._obs.span("ws.mantel", n=self.n,
                             permutations=permutations,
                             kernel=self.config.kernel):
@@ -780,7 +802,7 @@ class Workspace:
                        ) -> PermutationTestResult:
         """Partial Mantel of this matrix against ``other``, controlling
         for ``control``; ŷ is residualized from cached moments — all
-        three operands stay condensed (square-free like ``mantel``).
+        three operands stay condensed (``mantel``'s condensed layout).
         Routes through the Pallas ``permute_reduce`` backend when
         ``config.kernel == "pallas"``."""
         with self._obs.span("ws.partial_mantel", n=self.n,

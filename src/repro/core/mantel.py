@@ -14,18 +14,24 @@ Monte-Carlo null distribution over K row/column permutations (default 999).
     2. mean and norm are permutation-invariant ⇒ compute ``x̄``, ``‖x−x̄‖`` once.
   Centering is permutation-invariant too, so the hoist stores x − x̄ (in
   exact arithmetic Σŷ = 0 would make that unnecessary; in fp32 it is
-  not) — and the whole loop is
-  SQUARE-FREE: the condensed form of the permuted matrix is an index
-  transform of the condensed original,
-      ``condensed(X_p)[k] = xc[tri(order[i_k], order[j_k])]``,
-  so      ``r_p = ⟨condensed(X_p) − x̄, ŷ_c⟩ / ‖x−x̄‖``
-  is one closed-form gather + one fused multiply-reduce over the
-  m = n(n−1)/2 condensed entries — never the n×n gather buffer the PR-4
-  loop materialized. Permutations run in batches of B through
-  ``kernels.permute_reduce``: the hoisted ŷ_c / triangle-map streams are
-  fetched once per tile and reused across all B permutations, leaving
-  ~m(1 + 3/B) floats of traffic per permutation vs the square-gather
-  loop's ~6n² ≈ 12m (the measured accounting lives in BENCH_mantel.json).
+  not). The draws then run in one of two layouts, the statistic's
+  ``layout``, which ``draw_layout`` picks from what the session observes:
+    - ``"condensed"`` (every backend but the TPU, and any n whose squares
+      do not fit): the condensed form of the permuted matrix is an index
+      transform of the condensed original,
+          ``condensed(X_p)[k] = xc[tri(order[i_k], order[j_k])]``,
+      so ``r_p = ⟨condensed(X_p) − x̄, ŷ_c⟩ / ‖x−x̄‖`` is one closed-form
+      element gather + one fused multiply-reduce over the m = n(n−1)/2
+      condensed entries, batched B at a time through
+      ``kernels.permute_reduce`` (~m(1 + 3/B) floats of traffic per
+      permutation; the accounting lives in BENCH_mantel.json). Nothing
+      square is built. XLA:CPU vectorizes this gather;
+    - ``"rows"`` (a TPU, where the squares plus one draw's working set
+      fit in half the chip's memory): the hoist builds X̂ = x − x̄ and Ŷ
+      = ŷ square (hollow), and each draw is ½·sum(X̂[o] ⊙ Ŷ[o⁻¹]ᵀ)
+      through ``kernels.permute_reduce_rows``: two whole-row gathers, one
+      transpose, one multiply-reduce. A TPU serializes the condensed
+      element gather, one index at a time; contiguous rows it does not.
 * ``mantel_distributed`` — permutations sharded over ('pod','data'), matrix
   columns over 'model': each device reduces its column block, one psum.
   (The engine's ``permutation_test_distributed`` shards only the permutation
@@ -46,7 +52,8 @@ import numpy as np
 from repro.core.distance_matrix import (DistanceMatrix, condensed_index,
                                         condensed_to_square, triangle_coords)
 from repro.kernels.dispatch import HIGHEST
-from repro.kernels.permute_reduce_ops import permute_reduce
+from repro.kernels.permute_reduce_ops import (hollow_square, permute_reduce,
+                                              permute_reduce_rows)
 from repro.stats import engine
 
 
@@ -140,14 +147,34 @@ def _as_condensed(mat: jax.Array, n: int) -> jax.Array:
     return mat[np.triu_indices(n, k=1)]
 
 
+LAYOUTS = ("condensed", "rows")
+
+
+def draw_layout(n: int, batch_size: int, backend: str,
+                hbm_bytes: Optional[float]) -> str:
+    """The Mantel draws' layout for a session: ``"rows"`` on a TPU where
+    the row layout's working set fits in half of the chip's memory
+    (``hbm_bytes``; None where unknown), else ``"condensed"``. XLA:CPU
+    keeps the condensed loop, where its element gather vectorizes; an n
+    whose squares do not fit keeps it too. The working set, in 4-byte
+    words: the two squares, one draw's two gathered squares and its
+    transposed operand, and a tile's orders and their inverses."""
+    rows_bytes = 4 * (5 * n * n + 2 * batch_size * n)
+    if backend == "tpu" and hbm_bytes and rows_bytes <= hbm_bytes / 2:
+        return "rows"
+    return "condensed"
+
+
 @partial(jax.tree_util.register_dataclass,
          data_fields=["x", "y", "pre"],
-         meta_fields=["n", "kernel", "interpret", "chunk"])
+         meta_fields=["n", "kernel", "interpret", "chunk", "layout"])
 @dataclasses.dataclass
 class MantelStatistic:
-    """Pearson r between permuted x and fixed y, hoisting split per §4.2 —
-    square-free: every hoist and every per-permutation pass works on the
-    m = n(n−1)/2 condensed entries.
+    """Pearson r between permuted x and fixed y, hoisting split per §4.2.
+    In the ``"condensed"`` layout (the default) every hoist and every
+    per-permutation pass works on the m = n(n−1)/2 condensed entries; in
+    the ``"rows"`` layout the hoist builds the two hollow squares and the
+    draws gather their rows (module docstring; ``draw_layout`` chooses).
 
     ``x``/``y`` may be square (n, n) matrices or condensed (m,) vectors.
     ``pre`` optionally carries the session-level hoist
@@ -166,11 +193,17 @@ class MantelStatistic:
     kernel: str = "xla"
     interpret: Optional[bool] = None
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
+    layout: str = "condensed"    # the draws' layout, one of LAYOUTS
+
+    def __post_init__(self):
+        if self.layout not in LAYOUTS:
+            raise ValueError(f"unknown Mantel layout {self.layout!r}; "
+                             f"expected one of {LAYOUTS}")
 
     def hoist(self):
         # the permuted side's centered condensed view and the triangle
-        # coordinate map are permutation-invariant too — built once,
-        # outside the Monte-Carlo loop
+        # coordinate map (or, in the row layout, the two squares) are
+        # permutation-invariant too — built once, outside the loop
         inv = {"xc": _centered(_as_condensed(self.x, self.n))}
         if self.pre is not None:
             inv.update(self.pre)
@@ -179,10 +212,16 @@ class MantelStatistic:
             y_flat = _as_condensed(self.y, self.n)
             ym = y_flat - y_flat.mean()
             inv["ynorm"] = ym / jnp.linalg.norm(ym)    # computed exactly once
+        if self.layout == "rows":
+            inv["xs"] = hollow_square(inv.pop("xc"), self.n)
+            inv["ys"] = hollow_square(inv.pop("ynorm"), self.n)[None]
+            return inv
         inv["ii"], inv["jj"] = triangle_coords(self.n)
         return inv
 
     def per_perm(self, inv, order):
+        if self.layout == "rows":
+            return self.per_batch(inv, order[None])[0]
         # one closed-form condensed gather + one fused multiply-reduce
         # (Σ_uptri == ½ Σ_full and Σŷ = 0, so the full-matrix 2/(2‖x−x̄‖)
         # scaling collapses to 1/‖x−x̄‖ on condensed entries)
@@ -195,6 +234,9 @@ class MantelStatistic:
         # the engine's primary path: all B reductions of one order tile
         # through the batched kernel — the ŷ/triangle streams are fetched
         # once per tile and reused across the whole batch
+        if self.layout == "rows":
+            return (permute_reduce_rows(inv["xs"], inv["ys"], orders)[0]
+                    / inv["normxm"])
         stats = permute_reduce(inv["xc"], inv["ynorm"][None, :], orders,
                                inv["ii"], inv["jj"], impl=self.kernel,
                                chunk=self.chunk, interpret=self.interpret)
@@ -210,9 +252,10 @@ def _finish(orig_stat, permuted_stats, permutations, alternative, n):
 def mantel(x: DistanceMatrix, y: DistanceMatrix, permutations: int = 999,
            key=None, alternative: str = "two-sided"):
     """Cache-optimized Mantel test (paper Algorithm 5). Same interface and
-    semantics as ``mantel_ref``, with the square-free condensed batch
-    loop: ~11.0x less per-permutation traffic than the square-gather
-    engine loop and ~16.4x less than the eager Algorithm-3 original
+    semantics as ``mantel_ref``. Its condensed batch loop (the layout
+    off the TPU, ``draw_layout``) moves ~11.0x less per-permutation
+    traffic than the square-gather engine loop and ~16.4x less than the
+    eager Algorithm-3 original
     (analytic fp32 bytes at n=2048, B=32, K=999 — the audited accounting
     is the tracked ``BENCH_mantel.json`` artifact, via
     ``benchmarks/run.py --suite mantel``).
@@ -254,7 +297,7 @@ def mantel_null_distributed(x: DistanceMatrix, y: DistanceMatrix, mesh,
     # column-sharded reduction below stays specialized; the shared engine
     # entry point jits hoist + observed together so the identity-order
     # gathers fuse away instead of materializing two full n×n copies
-    stat = MantelStatistic(x_data, y_data, n)
+    stat = MantelStatistic(x_data, y_data, n, layout="condensed")
     inv, orig_stat = engine.hoist_and_observe(stat)
     normxm = inv["normxm"]
     # this path shards the MATRIX columns over 'model', so it is the one

@@ -24,6 +24,9 @@ Kernels (paper hot spots only — DESIGN §3):
                      through VMEM once per chunk and the permuted gather
                      is closed-form triangle indexing — the Mantel/ANOSIM
                      permutation hot loop with no n² buffer anywhere.
+                     ``permute_reduce_rows`` is its square twin for the
+                     TPU: whole-row gathers of two hollow squares, no
+                     element gather (the Mantel draws' ``"rows"`` layout).
 * ``pairwise``     — tiled pairwise-distance row panel: the ``repro.dist``
                      metric reduce fused in-register against VMEM-resident
                      Xᵢ/Xⱼ feature blocks.
@@ -36,7 +39,8 @@ from repro.kernels.center_matvec_ops import center_matvec_pallas
 from repro.kernels.symhollow_ops import is_symmetric_and_hollow_pallas
 from repro.kernels.mantel_corr_ops import mantel_corr_pallas
 from repro.kernels.pairwise_ops import pairwise_panel_pallas
-from repro.kernels.permute_reduce_ops import permute_reduce
+from repro.kernels.permute_reduce_ops import (permute_reduce,
+                                              permute_reduce_rows)
 from repro.kernels.rmsnorm_ops import rmsnorm_pallas
 
 __all__ = [
@@ -46,5 +50,6 @@ __all__ = [
     "mantel_corr_pallas",
     "pairwise_panel_pallas",
     "permute_reduce",
+    "permute_reduce_rows",
     "rmsnorm_pallas",
 ]

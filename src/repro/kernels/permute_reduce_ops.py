@@ -21,6 +21,19 @@ carry zero ``ys``, so they contribute exactly 0) and the int32 bound
 (``n <= MAX_TRIANGLE_N``; beyond it the closed-form index would wrap and
 CLAMP into silently wrong gathers, so we refuse loudly like
 ``CondensedCenteredGramOperator``).
+
+``permute_reduce_rows`` is the same reduction over SQUARE operands, with
+no element gather at all. For symmetric hollow X and Y, an order o and
+its inverse q = o⁻¹,
+
+    Σ_{i<j} Y[i, j] · X[o_i, o_j] = ½ Σ_{i,c} X[o_i, c] · Y[q_c, i]
+                                  = ½ · sum(X[o] ⊙ Y[q]ᵀ),
+
+so a draw is two whole-row gathers (contiguous n-float rows, the pattern
+a TPU moves by DMA), one transpose and one multiply-reduce. On a TPU the
+condensed (B, chunk) element gather is serialized one index at a time;
+the rows are not. ``hollow_square`` builds the square operands from the
+condensed ones once, in the caller's hoist.
 """
 
 from __future__ import annotations
@@ -166,3 +179,70 @@ def permute_reduce(xc: jax.Array, ys: jax.Array, orders: jax.Array,
         xc, ys, orders, ii, jj, impl=impl,
         chunk=DEFAULT_CHUNK if chunk is None else int(chunk),
         interpret=interpret)
+
+
+def hollow_square(xc: jax.Array, n: int) -> jax.Array:
+    """The symmetric (n, n) matrix with zero diagonal whose upper
+    triangle is the condensed ``xc``: the row layout's operand.
+
+    Row i of the upper triangle is the contiguous slice
+    ``xc[S(i) : S(i) + n - 1 - i]``, S(i) = i(2n - i - 1)/2, so the
+    upper triangle U is a gather of n contiguous windows of length n
+    (masked to the columns past the diagonal) and the square is U where
+    c > i, Uᵀ elsewhere. Bitwise ``condensed_to_square``, without its
+    host (n, n) position map, which would become an n²-int constant of
+    the program and an n²-element gather."""
+    if n < 2:                              # empty triangle
+        return jnp.zeros((n, n), dtype=xc.dtype)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    # n zeros on each side keep every window in range: window i starts
+    # at S(i) - i - 1 in xc, which is -1 for the first row
+    padded = jnp.pad(xc, (n, n))
+    starts = n + rows * (2 * n - rows - 1) // 2 - rows - 1
+    windows = jax.vmap(
+        lambda s: jax.lax.dynamic_slice(padded, (s,), (n,)))(starts)
+    upper = rows[None, :] > rows[:, None]
+    u = jnp.where(upper, windows, 0)
+    return jnp.where(upper, u, u.T)
+
+
+@jax.jit
+def permute_reduce_rows(xs: jax.Array, ys: jax.Array,
+                        orders: jax.Array) -> jax.Array:
+    """All B permuted multiply-reduces of one invariant stack, from
+    square operands by whole-row gathers.
+
+    out[s, b] = ½ Σ_{i,c} xs[o_b[i], c] · ys[s][q_b[c], i]
+              = <condensed(xs[o_b][:, o_b]), condensed(ys[s])>
+
+    xs: (n, n) symmetric with a zero diagonal (``hollow_square``). ys:
+    (S, n, n), each the same. orders: (B, n) int permutation tile; each
+    inverse q_b is built here. Returns (S, B) in xs's dtype, summed in
+    fp32 on the vector unit. The B draws run one after another, so the
+    working set is one draw's two gathered squares, never (B, n, n).
+    """
+    n = xs.shape[0]
+    b_perms = orders.shape[0]
+    if xs.shape != (n, n) or orders.shape[1] != n:
+        raise ValueError(f"xs must be (n, n) for orders (B, n); got "
+                         f"{xs.shape} and {orders.shape}")
+    if ys.ndim != 3 or ys.shape[1:] != (n, n):
+        raise ValueError(f"ys must be (S, {n}, {n}), got {ys.shape}")
+    # trace-time only: the row layout's programs, one per (n, B, S)
+    note_trace("kernels.permute_reduce_rows", (n, b_perms, ys.shape[0]))
+    orders = orders.astype(jnp.int32)
+    with jax.named_scope("index"):
+        inverses = jnp.argsort(orders, axis=-1).astype(jnp.int32)
+
+    def draw(order_inverse):
+        o, q = order_inverse
+        with jax.named_scope("gather"):
+            xo = jnp.take(xs, o, axis=0, mode="clip",
+                          unique_indices=True)           # row o_i of xs
+            yq = jax.vmap(lambda y: jnp.take(
+                y, q, axis=0, mode="clip", unique_indices=True))(ys)
+        with jax.named_scope("reduce"):
+            return 0.5 * jnp.sum(xo[None] * jnp.swapaxes(yq, 1, 2),
+                                 axis=(1, 2))
+
+    return jax.lax.map(draw, (orders, inverses)).T

@@ -297,7 +297,8 @@ def permutation_test(stat: Statistic, permutations: int = 999,
     tiles = -(-permutations // bs) if permutations else 0
     with obs.span(f"engine.{method or type(stat).__name__}",
                   phase="per_perm", n=stat.n, permutations=permutations,
-                  batch_size=bs, tiles=tiles, batched=batched):
+                  batch_size=bs, tiles=tiles, batched=batched,
+                  layout=getattr(stat, "layout", None)):
         observed, permuted = _null_distribution(stat, key, permutations, bs)
     if batched and permutations:
         # the batched loop IS the condensed_fused traffic model — the

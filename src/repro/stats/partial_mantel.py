@@ -51,7 +51,7 @@ from repro.stats.engine import PermutationTestResult
 @dataclasses.dataclass
 class PartialMantelStatistic:
     """r_xy·z with ŷ residualized against ẑ once, outside the loop —
-    square-free like ``MantelStatistic``.
+    condensed like ``MantelStatistic``'s default layout.
 
     ``x``/``y``/``z`` may be square (n, n) matrices or condensed (m,)
     vectors. ``pre`` optionally carries the session-level hoist

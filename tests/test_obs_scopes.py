@@ -73,6 +73,22 @@ def test_null_distribution_hlo_carries_the_definition_scopes():
                    for op in ops), inner
 
 
+def test_row_layout_draws_carry_the_definition_scopes():
+    """The row layout keeps the scopes the metrics bind to: its row
+    gathers under ``perm.draws``/``gather``, its multiply-reduce under
+    ``reduce``, the inverse orders under ``index``."""
+    import dataclasses
+    _, _, stat = _mantel_call(29)
+    ops = set(re.findall(r'op_name="([^"]*)"', _compiled_text(
+        dataclasses.replace(stat, layout="rows"))))
+    for scope in ("perm.orders", "perm.hoist", "perm.draws"):
+        assert any(f"/{scope}/" in op for op in ops), scope
+    for inner in ("index", "gather", "reduce"):
+        assert any(re.search(
+            rf"/perm\.draws/.*permute_reduce_rows.*/{inner}/", op)
+            for op in ops), inner
+
+
 def test_scope_map_names_every_fusion_sort_and_gather():
     _, _, stat = _mantel_call(31)
     static = {"permutations": 40, "batch_size": 8}

@@ -2,7 +2,9 @@
 Pallas kernel and its lax.scan twin) against the eager square-roundtrip
 ``_ref`` oracle, across odd n, non-tile-multiple m and B, trailing
 chunks, and both interpret modes — plus the engine-facing properties
-(identity order, stacked invariant rows, int32 refusal)."""
+(identity order, stacked invariant rows, int32 refusal) — and the row
+layout ``permute_reduce_rows`` with its square build ``hollow_square``
+against the same oracle and the condensed kernel."""
 
 import jax
 import jax.numpy as jnp
@@ -10,9 +12,12 @@ import numpy as np
 import pytest
 
 from repro.core.distance_matrix import (condensed_index,
+                                        condensed_to_square,
                                         random_distance_matrix,
                                         triangle_coords)
 from repro.kernels import permute_reduce
+from repro.kernels.permute_reduce_ops import (hollow_square,
+                                              permute_reduce_rows)
 from repro.kernels.permute_reduce_ref import permute_reduce_ref
 
 KEY = jax.random.PRNGKey(7)
@@ -124,3 +129,45 @@ def test_permute_reduce_precomputed_coords_match():
     a = permute_reduce(xc, ys, orders, ii, jj, impl="xla")
     b = permute_reduce(xc, ys, orders, impl="xla")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------------------------------
+# the row layout: whole-row gathers of the two hollow squares
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("b_perms", [1, 8])
+@pytest.mark.parametrize("s", [1, 2])
+@pytest.mark.parametrize("n", [2, 3, 17, 64, 129])
+def test_permute_reduce_rows_matches_condensed(n, s, b_perms):
+    """The windowed square build is bitwise ``condensed_to_square``; the
+    row-gather draws match the oracle and the condensed kernel; and the
+    identity order reduces to the plain condensed dot. Both kernels sum
+    in fp32 in different orders, so a draw that cancels to near zero
+    carries an error of the largest draw's rounding: the tolerance is
+    1e-5 of the draws' scale as well as of each draw."""
+    xc, ys, orders = _case(n, b_perms, s, seed=n + s)
+    xs = hollow_square(xc, n)
+    np.testing.assert_array_equal(
+        np.asarray(xs).view(np.uint32),
+        np.asarray(condensed_to_square(xc, n)).view(np.uint32))
+    ys_sq = jnp.stack([hollow_square(y, n) for y in ys])
+    got = np.asarray(permute_reduce_rows(xs, ys_sq, orders))
+    assert got.shape == (s, b_perms)
+    for want in (permute_reduce_ref(xc, ys, orders),
+                 permute_reduce(xc, ys, orders, impl="xla")):
+        want = np.asarray(want)
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+    identity = jnp.arange(n, dtype=jnp.int32)[None, :]
+    plain = np.asarray(ys @ xc)
+    np.testing.assert_allclose(
+        np.asarray(permute_reduce_rows(xs, ys_sq, identity))[:, 0], plain,
+        rtol=1e-5, atol=1e-5 * np.abs(plain).max())
+
+
+def test_permute_reduce_rows_validates():
+    xc, ys, orders = _case(10, 2, 1, seed=3)
+    xs = hollow_square(xc, 10)
+    with pytest.raises(ValueError, match="xs must be"):
+        permute_reduce_rows(xs[:, :-1], xs[None], orders)
+    with pytest.raises(ValueError, match="ys must be"):
+        permute_reduce_rows(xs, xs, orders)

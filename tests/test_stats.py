@@ -11,12 +11,13 @@ import numpy as np
 import pytest
 
 from repro.core import mantel, mantel_ref, random_distance_matrix
-from repro.core.mantel import MantelStatistic
+from repro.core.mantel import MantelStatistic, draw_layout
 from repro.stats import (anosim, anosim_ref, partial_mantel,
                          partial_mantel_ref, permanova, permanova_ref,
                          permdisp, permdisp_ref, permutation_test,
                          permutation_test_distributed)
-from repro.stats.engine import encode_grouping, permutation_orders
+from repro.stats.engine import (_null_distribution, encode_grouping,
+                                permutation_orders)
 from repro.stats.permanova import PermanovaStatistic
 
 KEY = jax.random.PRNGKey(7)
@@ -117,6 +118,47 @@ def test_engine_results_invariant_to_batch_size():
     for r in rs[1:]:
         assert r.statistic == rs[0].statistic
         assert r.p_value == rs[0].p_value
+
+
+def test_mantel_rows_layout_matches_condensed():
+    """The row layout is the same test: same key, same p-value, every
+    draw within 1e-5 of the condensed loop's, through a padded tail tile
+    (K=999 over B=32)."""
+    x, y = _dm(0), _dm(1)
+    stats = {layout: MantelStatistic(x.data, y.data, len(x), layout=layout)
+             for layout in ("condensed", "rows")}
+    draws = {k: np.asarray(_null_distribution(st, KEY, 999, 32)[1])
+             for k, st in stats.items()}
+    np.testing.assert_allclose(draws["rows"], draws["condensed"], rtol=0,
+                               atol=1e-5)
+    r = {k: permutation_test(st, permutations=999, key=KEY, batch_size=32)
+         for k, st in stats.items()}
+    assert r["rows"].p_value == r["condensed"].p_value
+    assert abs(r["rows"].statistic - r["condensed"].statistic) < 1e-5
+    with pytest.raises(ValueError, match="layout"):
+        MantelStatistic(x.data, y.data, len(x), layout="square")
+
+
+@pytest.mark.parametrize("n,backend,want", [
+    (2898, "cpu", "condensed"),      # XLA:CPU vectorizes the element gather
+    (2898, "tpu", "rows"),           # HMP16SData: the squares fit
+    (46340, "tpu", "condensed"),     # two 8.6 GB squares do not
+])
+def test_draw_layout_rule(n, backend, want):
+    assert draw_layout(n, 32, backend, 16e9) == want
+
+
+def test_workspace_reports_draw_layout():
+    """A session's Mantel statistic carries the layout its report names
+    (the condensed one on this CPU backend)."""
+    from repro.api.workspace import Workspace
+    x, y = _dm(0), _dm(1)
+    ws = Workspace(x)
+    stat, _ = ws.statistic("mantel", other=y)
+    assert ws.resolved_tiles()["draw_layout"] == stat.layout == draw_layout(
+        len(x), 32, jax.default_backend(), None) == "condensed"
+    assert ws.report().to_dict()["meta"]["tiles"]["draw_layout"] == \
+        "condensed"
 
 
 def test_engine_rejects_bad_alternative():

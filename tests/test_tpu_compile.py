@@ -81,6 +81,20 @@ def test_permute_reduce_xla_compiles(topo, one_chip, s):
     _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
+@pytest.mark.parametrize("n", [N, 2898])       # chip_smoke; HMP16SData
+def test_mantel_rows_null_distribution_compiles(topo, one_chip, n):
+    """The row layout's whole test program (the TPU's Mantel path): the
+    hoist's two squares and one draw's gathered rows fit one chip."""
+    m = n * (n - 1) // 2
+    stat = MantelStatistic(one_chip((m,)), None, n,
+                           pre={"normxm": one_chip(()),
+                                "ynorm": one_chip((m,))}, layout="rows")
+    compiled = engine._null_distribution.lower(
+        stat, one_chip((2,), jnp.uint32), permutations=999,
+        batch_size=B).compile()
+    _fits_one_chip(compiled, topo.devices[0].device_kind)
+
+
 def test_panel_stats_xla_compiles(topo, one_chip):
     compiled = _panel_stats.lower(
         one_chip((256, D)), one_chip((N, D)),
@@ -166,7 +180,8 @@ def test_engine_mantel_four_chips_compiles(topo, meshes):
 
     def null(xc, normxm, ynorm, key):
         stat = MantelStatistic(xc, None, n,
-                               pre={"normxm": normxm, "ynorm": ynorm})
+                               pre={"normxm": normxm, "ynorm": ynorm},
+                               layout="condensed")
         return engine.null_distribution_distributed(stat, line, MULTI_K, key)
 
     compiled = jax.jit(null).lower(
