@@ -34,7 +34,7 @@ from repro.obs.config import ObsConfig
 # mirror of repro.dist.METRICS — kept literal here because this module
 # imports nothing from repro (pinned in sync by tests/test_dist.py)
 _KNOWN_METRICS = ("braycurtis", "canberra", "cityblock", "euclidean",
-                  "jaccard")
+                  "jaccard", "unweighted_unifrac")
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -112,7 +112,8 @@ class ExecConfig:
         Default beta-diversity metric for feature-table sessions
         (``Workspace.from_features`` with ``metric=None``) — any
         ``repro.dist`` registry name ("braycurtis", "euclidean",
-        "jaccard", "canberra", "cityblock").
+        "jaccard", "canberra", "cityblock", "unweighted_unifrac"; the
+        last needs the table's tree, ``tree=``).
     pairwise_impl:
         Backend for the ``repro.dist`` tiled distance production —
         ``"xla"`` (the ``lax.map`` row-panel fallback, the default) or

@@ -67,7 +67,8 @@ from repro.core.operators import (CenteredGramOperator,
 from repro.core.pcoa import pcoa as _pcoa
 from repro.core.pcoa import resolve_dimensions
 from repro.core.validation import ensure_finite
-from repro.dist import get_metric, pairwise_condensed
+from repro.dist import get_metric, pairwise_condensed, takes_tree
+from repro.dist.driver import check_tree
 from repro.kernels.dispatch import HIGHEST
 from repro.launch.mesh import chip_peaks
 from repro.obs.ledger import FEATURE_HOIST_PASSES, HOIST_PASSES
@@ -227,7 +228,7 @@ class Workspace:
     def __init__(self,
                  dm: Union[DistanceMatrix, jax.Array, np.ndarray, None] = None,
                  config: Optional[ExecConfig] = None, validate: bool = True,
-                 *, features=None, metric=None):
+                 *, features=None, metric=None, tree=None):
         self.config = config if config is not None else ExecConfig()
         # the as-requested config survives resolution so refresh() (a new
         # n) re-solves from the user's intent, not a previous solution
@@ -245,18 +246,22 @@ class Workspace:
             if dm is not None:
                 raise ValueError("pass a distance matrix OR a feature "
                                  "table, not both")
-            self._admit_features(features, metric)
+            self._admit_features(features, metric, tree)
         else:
             if dm is None:
                 raise ValueError("Workspace needs a distance matrix (or "
                                  "features= — see Workspace.from_features)")
+            if tree is not None:
+                raise ValueError("a tree goes with a feature table, not a "
+                                 "distance matrix")
             self._admit_dm(dm, validate)
         self._resolve_config()
         self._bind_cache()
 
     @classmethod
     def from_features(cls, features, metric=None,
-                      config: Optional[ExecConfig] = None) -> "Workspace":
+                      config: Optional[ExecConfig] = None, *,
+                      tree=None) -> "Workspace":
         """A session straight from an (n, d) feature table — the fused
         ``repro.dist`` path.
 
@@ -275,8 +280,16 @@ class Workspace:
         (default: ``config.metric``, Bray–Curtis). The table is validated
         finite on admission (shared ``ensure_finite`` path) and
         canonicalized to fp32 like a distance matrix would be.
+
+        A tree metric (``"unweighted_unifrac"``) takes the table's
+        ``tree``, a ``repro.dist.PhyloTree`` with one tip per column;
+        production then reads the table's branch embedding, made once
+        per table (``repro.dist.tree_hoist``). A tree metric without a
+        tree, a tree with any other metric, and a tree whose tip count is
+        not the table's width are refused on admission.
         """
-        return cls(features=features, metric=metric, config=config)
+        return cls(features=features, metric=metric, config=config,
+                   tree=tree)
 
     # -- admission (shared by __init__ and refresh) -------------------------
     def _admit_dm(self, dm, validate: bool) -> None:
@@ -310,9 +323,12 @@ class Workspace:
                                       _skip_validation=True)
         self._features = None
         self._metric = None
+        self._tree = None
         self.n = len(self._dm)
 
-    def _admit_features(self, features, metric) -> None:
+    def _admit_features(self, features, metric, tree) -> None:
+        metric = get_metric(metric if metric is not None
+                            else self.config.metric)
         with self._obs.span("ws.from_features") as span:
             with self._obs.span("ws.upload"):
                 x = jnp.asarray(features)
@@ -327,14 +343,16 @@ class Workspace:
                     x = x.astype(jnp.float32)
                 if self.config.device is not None:
                     x = jax.device_put(x, self.config.device)
+        check_tree(metric, tree, int(x.shape[1]))
         self._features = x
-        self._metric = get_metric(metric if metric is not None
-                                  else self.config.metric)
+        self._metric = metric
+        self._tree = tree
         self._dm = None
         self.n = int(x.shape[0])
 
     # -- cache lifecycle ----------------------------------------------------
-    def refresh(self, dm=None, *, features=None, metric=None) -> "Workspace":
+    def refresh(self, dm=None, *, features=None, metric=None,
+                tree=None) -> "Workspace":
         """Invalidate every cached hoist and bump ``generation``.
 
         The HoistCache assumes the session matrix never changes under it;
@@ -346,7 +364,9 @@ class Workspace:
         hoist exactly once. Pass ``dm=`` or ``features=`` to re-admit new
         data (same validation/canonicalization as construction); with no
         arguments the current matrix/table is kept and only the caches
-        drop. Returns ``self`` for chaining.
+        drop. New features keep the session's metric and, for a tree
+        metric, its tree, unless ``metric=`` or ``tree=`` replace them.
+        Returns ``self`` for chaining.
         """
         if dm is not None and features is not None:
             raise ValueError("pass a distance matrix OR a feature table, "
@@ -356,9 +376,11 @@ class Workspace:
         if dm is not None:
             self._admit_dm(dm, validate=True)
         elif features is not None:
-            self._admit_features(features,
-                                 metric if metric is not None
-                                 else self._metric)
+            metric = metric if metric is not None else self._metric
+            if (tree is None and metric is not None
+                    and takes_tree(get_metric(metric))):
+                tree = self._tree
+            self._admit_features(features, metric, tree)
         elif self._features is not None:
             # feature-backed: the lazily-materialized square (if any) was
             # derived from the dropped production — it goes too
@@ -508,7 +530,7 @@ class Workspace:
                 self._features, self._metric, block=self.config.block,
                 feature_block=self.config.feature_block,
                 impl=self.config.pairwise_impl,
-                interpret=self.config.interpret)
+                interpret=self.config.interpret, tree=self._tree)
         self.cache.get("condensed", lambda: prod["condensed"])
         self.cache.get("dist_means", lambda: {
             k: prod[k] for k in ("row_means", "global_mean")})

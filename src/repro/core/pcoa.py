@@ -53,7 +53,7 @@ from repro.core.distance_matrix import DistanceMatrix
 from repro.core.operators import (CenteredGramOperator,
                                   centered_gram_matvec_distributed)
 from repro.kernels.dispatch import HIGHEST
-from repro.obs.compile import note_trace
+from repro.obs.compile import note_run, note_trace
 from repro.obs.trace import current_obs
 
 # Legacy name for the unified ordination result (same class; the api
@@ -91,19 +91,21 @@ def _subspace_iteration(matvec, n: int, dtype, key, k: int, oversample: int,
     AᵀA = A²); project T = QᵀAQ (small, (k+p)²); exact eigh of T lifts
     back. Every O(n²k)-flop step is a single fused matvec — the operator
     decides whether that is a sharded matmul, a row-blocked XLA sweep or
-    the Pallas kernel.
+    the Pallas kernel. Profiler scope ``pcoa.solve``.
     """
-    p = min(k + oversample, n)
-    omega = jax.random.normal(key, (n, p), dtype=dtype)
-    q, _ = jnp.linalg.qr(matvec(omega))
-    for _ in range(power_iters):
-        q, _ = jnp.linalg.qr(matvec(q))
-    t = jnp.matmul(q.T, matvec(q), precision=HIGHEST)   # (p, p), tiny
-    t = 0.5 * (t + t.T)
-    evals, evecs = jnp.linalg.eigh(t)
-    # eigh returns ascending; take top-k by value (descending)
-    order = jnp.argsort(-evals)[:k]
-    return evals[order], jnp.matmul(q, evecs, precision=HIGHEST)[:, order]
+    with jax.named_scope("pcoa.solve"):
+        p = min(k + oversample, n)
+        omega = jax.random.normal(key, (n, p), dtype=dtype)
+        q, _ = jnp.linalg.qr(matvec(omega))
+        for _ in range(power_iters):
+            q, _ = jnp.linalg.qr(matvec(q))
+        t = jnp.matmul(q.T, matvec(q), precision=HIGHEST)   # (p, p), tiny
+        t = 0.5 * (t + t.T)
+        evals, evecs = jnp.linalg.eigh(t)
+        # eigh returns ascending; take top-k by value (descending)
+        order = jnp.argsort(-evals)[:k]
+        return evals[order], jnp.matmul(q, evecs,
+                                        precision=HIGHEST)[:, order]
 
 
 @partial(jax.jit, static_argnames=("k", "oversample", "power_iters"))
@@ -112,7 +114,9 @@ def _randomized_eigh_matfree(op: CenteredGramOperator, key, k: int,
     """Matrix-free fsvd: the operator pytree crosses the jit boundary with
     its tiling metadata static, so repeated solves of one shape reuse the
     executable."""
-    note_trace("pcoa.fsvd_matfree", (op.n, k, oversample, power_iters))
+    note_trace("pcoa.fsvd_matfree", (op.n, k, oversample, power_iters),
+               _randomized_eigh_matfree, (op, key),
+               {"k": k, "oversample": oversample, "power_iters": power_iters})
     return _subspace_iteration(op.matvec, op.n, op.dtype, key, k,
                                oversample, power_iters)
 
@@ -264,6 +268,8 @@ def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
                 CenteredGramOperator.from_distance(
                     dm.data, block=cfg.block, impl=cfg.matvec_impl,
                     interpret=cfg.interpret)
+            # the signature its trace note records, at the default sketch
+            note_run(_randomized_eigh_matfree, (op.n, k, 10, 2))
             evals, evecs = _randomized_eigh_matfree(op, key, k)
             total = op.trace()
 

@@ -7,9 +7,14 @@ distances — and fuses it straight into the hoists the analyses consume:
 
 * ``metrics``  — the ``Metric`` protocol (pytree dataclasses, the same
   design language as ``stats.Statistic``) with Euclidean, Bray–Curtis,
-  Jaccard, Canberra and Cityblock instances; each declares a
-  feature-chunk-additive ``accumulate`` and a ``finish``, which is what
-  lets the reduce fuse into a tile sweep.
+  Jaccard, Canberra, Cityblock and unweighted UniFrac instances; each
+  declares a feature-chunk-additive ``accumulate`` and a ``finish``,
+  which is what lets the reduce fuse into a tile sweep.
+* ``tree``     — ``PhyloTree`` (a rooted tree as arrays, validated and
+  ordered once on the host, read from Newick) and the device tree hoist
+  that turns a table into the branch embedding UniFrac reads.
+* ``unifrac_ref`` — the eager float64 UniFrac oracle the tests compare
+  against.
 * ``driver``   — the cache-blocked producer: row panels stream through
   the Pallas ``kernels.pairwise`` kernel (``impl="pallas"``) or the
   ``lax.map`` fallback (``impl="xla"``), emitting the condensed form
@@ -30,12 +35,15 @@ Session use (the fused path — see ``repro.api.Workspace``):
 """
 
 from repro.dist.metrics import (METRICS, BrayCurtis, Canberra, Cityblock,
-                                Euclidean, Jaccard, Metric, get_metric)
+                                Euclidean, Jaccard, Metric, UnweightedUniFrac,
+                                get_metric, takes_tree)
+from repro.dist.tree import PhyloTree, tree_hoist
 from repro.dist.driver import (condensed_size, pairwise_condensed,
                                pairwise_distances)
 
 __all__ = [
-    "METRICS", "Metric", "get_metric",
+    "METRICS", "Metric", "get_metric", "takes_tree",
     "Euclidean", "BrayCurtis", "Jaccard", "Canberra", "Cityblock",
+    "UnweightedUniFrac", "PhyloTree", "tree_hoist",
     "condensed_size", "pairwise_condensed", "pairwise_distances",
 ]

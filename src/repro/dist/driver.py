@@ -32,9 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist.metrics import Metric, get_metric, merge_acc
+from repro.dist.metrics import Metric, get_metric, merge_acc, takes_tree
+from repro.dist.tree import PhyloTree, tree_hoist
 from repro.kernels.dispatch import clamp_block
-from repro.obs.compile import note_trace
+from repro.obs.compile import note_run, note_trace
 from repro.obs.trace import current_obs
 
 _DEFAULT_BLOCK = 256
@@ -80,6 +81,22 @@ def _panel_xla(xi: jax.Array, x: jax.Array, metric: Metric,
     return jax.lax.map(one, sub).reshape(bm, x.shape[0])
 
 
+def _panel_signature(xi, x, metric: Metric, feature_block: int, impl: str,
+                     block: int) -> tuple:
+    """What keys a ``_panel_stats`` program, for its trace and run notes."""
+    return (tuple(xi.shape), tuple(x.shape), metric.name, feature_block,
+            impl, block)
+
+
+def _run_panel(xi, x, metric: Metric, feature_block: int, impl: str,
+               interpret: Optional[bool], block: int):
+    """``_panel_stats``, its execution counted (``note_run``)."""
+    note_run(_panel_stats, _panel_signature(xi, x, metric, feature_block,
+                                            impl, block))
+    return _panel_stats(xi, x, metric=metric, feature_block=feature_block,
+                        impl=impl, interpret=interpret, block=block)
+
+
 @partial(jax.jit, static_argnames=("metric", "feature_block", "impl",
                                    "interpret", "block"))
 def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
@@ -92,7 +109,7 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
     Profiler scopes: ``dist.accumulate`` (the strip) and
     ``dist.rowsums``."""
     note_trace("dist.panel_stats",
-               (xi.shape, x.shape, metric.name, feature_block, impl, block),
+               _panel_signature(xi, x, metric, feature_block, impl, block),
                _panel_stats, (xi, x),
                {"metric": metric, "feature_block": feature_block,
                 "impl": impl, "interpret": interpret, "block": block})
@@ -109,12 +126,43 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
         return strip, jnp.sum(strip * strip, axis=1)
 
 
+def check_tree(metric: Metric, tree, width: int) -> None:
+    """Raise ``ValueError`` unless ``tree`` suits ``metric`` on a table
+    of ``width`` feature columns: a tree metric needs a tree with one tip
+    per column, any other metric takes none."""
+    if not takes_tree(metric):
+        if tree is not None:
+            raise ValueError(f"metric {metric.name!r} takes no tree")
+        return
+    if tree is None:
+        raise ValueError(f"metric {metric.name!r} needs the table's "
+                         f"phylogenetic tree (tree=)")
+    if not isinstance(tree, PhyloTree):
+        raise TypeError(f"tree must be a repro.dist.PhyloTree, got "
+                        f"{type(tree).__name__}")
+    if tree.num_tips != width:
+        raise ValueError(f"the tree places {tree.num_tips} features; the "
+                         f"table has {width}")
+
+
+def _metric_input(x, metric: Metric, tree):
+    """What ``metric`` reads of the (n, d) float32 table ``x``: the table,
+    or for a tree metric its branch embedding on ``tree``."""
+    check_tree(metric, tree, x.shape[1])
+    return tree_hoist(x, tree) if takes_tree(metric) else x
+
+
 def pairwise_condensed(x, metric="braycurtis", *,
                        block: int = _DEFAULT_BLOCK,
                        feature_block: int = _DEFAULT_FEATURE_BLOCK,
                        impl: str = "xla",
-                       interpret: Optional[bool] = None) -> dict:
+                       interpret: Optional[bool] = None,
+                       tree=None) -> dict:
     """Condensed distances + fused hoists from an (n, d) feature table.
+
+    A tree metric (``unweighted_unifrac``) needs ``tree``, the table's
+    ``repro.dist.tree.PhyloTree``: production then runs on the table's
+    branch embedding, made once here (``tree.tree_hoist``).
 
     Returns a dict:
 
@@ -135,6 +183,7 @@ def pairwise_condensed(x, metric="braycurtis", *,
         raise ValueError(f"expected an (n, d) feature table, got {x.shape}")
     if x.dtype != jnp.float32:
         x = x.astype(jnp.float32)
+    x = _metric_input(x, metric, tree)
     n = x.shape[0]
     d = int(x.shape[1])
     b = clamp_block(n, block)
@@ -149,10 +198,8 @@ def pairwise_condensed(x, metric="braycurtis", *,
             xi = x[i0:i1]
             if i1 - i0 < b:                 # pad the short tail panel so
                 xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))  # one trace fits all
-            strip, rs2 = _panel_stats(xi, x, metric=metric,
-                                           feature_block=feature_block,
-                                           impl=impl, interpret=interpret,
-                                           block=b)
+            strip, rs2 = _run_panel(xi, x, metric, feature_block, impl,
+                                    interpret, b)
             rs2_parts.append(rs2[:i1 - i0])
             idx = _panel_condensed_indices(n, i0, i1)
             if idx.size:
@@ -172,7 +219,8 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
                        block: int = _DEFAULT_BLOCK,
                        feature_block: int = _DEFAULT_FEATURE_BLOCK,
                        impl: str = "xla",
-                       interpret: Optional[bool] = None) -> jax.Array:
+                       interpret: Optional[bool] = None,
+                       tree=None) -> jax.Array:
     """The ``scipy.spatial.distance.pdist``/``squareform`` replacement.
 
     ``out="square"`` assembles the full (n, n) matrix panel-by-panel
@@ -183,7 +231,7 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
     if out == "condensed":
         return pairwise_condensed(x, metric, block=block,
                                   feature_block=feature_block, impl=impl,
-                                  interpret=interpret)["condensed"]
+                                  interpret=interpret, tree=tree)["condensed"]
     if out != "square":
         raise ValueError(f"out must be 'square' or 'condensed', got {out!r}")
     metric = get_metric(metric)
@@ -194,6 +242,7 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
         raise ValueError(f"expected an (n, d) feature table, got {x.shape}")
     if x.dtype != jnp.float32:
         x = x.astype(jnp.float32)
+    x = _metric_input(x, metric, tree)
     n = x.shape[0]
     b = clamp_block(n, block)
     parts = []
@@ -202,8 +251,7 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
         xi = x[i0:i1]
         if i1 - i0 < b:
             xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))
-        strip, _ = _panel_stats(xi, x, metric=metric,
-                                   feature_block=feature_block, impl=impl,
-                                   interpret=interpret, block=b)
+        strip, _ = _run_panel(xi, x, metric, feature_block, impl,
+                              interpret, b)
         parts.append(strip[:i1 - i0])
     return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]

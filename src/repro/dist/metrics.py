@@ -38,9 +38,10 @@ Degenerate-pair conventions (pinned by ``tests/test_dist.py``):
 * **Canberra 0/0 terms** — per-feature 0/0 terms count as 0 (SciPy's
   convention).
 
-All five metrics match ``scipy.spatial.distance.pdist`` to ≤ 1e-5 on
-random fp32 tables (property-tested), modulo the Bray–Curtis NaN
-convention above.
+The five feature metrics match ``scipy.spatial.distance.pdist`` to
+≤ 1e-5 on random fp32 tables (property-tested), modulo the Bray–Curtis
+NaN convention above. Unweighted UniFrac, the one tree metric, matches
+the float64 reference ``dist.unifrac_ref`` instead; scipy has none.
 """
 
 from __future__ import annotations
@@ -168,6 +169,34 @@ class Jaccard:
         return _safe_div(acc["neq"], acc["nz"])
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=[], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class UnweightedUniFrac:
+    """Unweighted UniFrac (Lozupone & Knight 2005): the branch length
+    present in exactly one of two samples over the branch length present
+    in either. It reads the branch embedding E[x, b] = l_b·[b present in
+    x] that ``tree.tree_hoist`` makes from a table and its tree, on which
+    it is Σ|a−b| / Σmax(a, b) (l ≥ 0); ``takes_tree`` tells production
+    to make it. 0/0 (no branch present in either sample) → 0."""
+
+    name = "unweighted_unifrac"
+    takes_tree = True
+
+    def accumulate(self, xi, xj):
+        with jax.named_scope("dist.unifrac"):
+            a, b = _pairwise(xi, xj)
+            return {"num": jnp.sum(jnp.abs(a - b), axis=-1),
+                    "den": jnp.sum(jnp.maximum(a, b), axis=-1)}
+
+    def finish(self, acc):
+        return _safe_div(acc["num"], acc["den"])
+
+
+def takes_tree(metric: Metric) -> bool:
+    """Whether ``metric`` reads a table's tree embedding, not the table."""
+    return bool(getattr(metric, "takes_tree", False))
+
+
 def merge_acc(acc: Acc, part: Acc) -> Acc:
     """Sum two chunks' accumulators (all metrics are feature-additive)."""
     return {k: acc[k] + part[k] for k in acc}
@@ -175,7 +204,7 @@ def merge_acc(acc: Acc, part: Acc) -> Acc:
 
 METRICS: Dict[str, Metric] = {
     m.name: m for m in (Euclidean(), Cityblock(), Canberra(), BrayCurtis(),
-                        Jaccard())
+                        Jaccard(), UnweightedUniFrac())
 }
 
 

@@ -31,4 +31,7 @@ def pairwise_ref(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:
         dt = x.dtype
         return _guarded(jnp.sum((a != b).astype(dt), -1),
                         jnp.sum(((a != 0) | (b != 0)).astype(dt), -1))
+    if metric == "unweighted_unifrac":      # on the branch embedding
+        return _guarded(jnp.sum(jnp.abs(a - b), -1),
+                        jnp.sum(jnp.maximum(a, b), -1))
     raise ValueError(f"unknown metric {metric!r}")

@@ -28,12 +28,15 @@ records:
 
 A note that also passes the jitted function, its arguments and its
 static arguments keeps them as ``ShapeDtypeStruct``s, so the program can
-be lowered again later without any array: ``hlo_texts(module)`` does
-that, compiles and returns the compiled HLO text, whose ``op_name``
-metadata holds each instruction's ``jax.named_scope`` path. A profiler
-trace names device ops by instruction, so a reader can put each op of a
-trace under the scope that issued it. It runs only when asked, never on
-the hot path.
+be lowered again later without any array: ``compiled(module)`` does
+that and returns each signature's compiled HLO text (once: the texts
+are kept), whose ``op_name`` metadata holds each
+instruction's ``jax.named_scope`` path. A profiler trace names device
+ops by instruction, so a reader can put each op of a trace under the
+scope that issued it. It runs only when asked, never on the hot path.
+The call site of such an entry point counts its executions,
+``note_run(fn, signature)``, by the same signature, so a reader can
+weigh each program's device time by how often it ran (``runs``).
 
 The sentinel also listens to ``jax.monitoring`` (registered once, at
 import) and keeps **program preparation** counts and seconds: jaxpr
@@ -91,6 +94,11 @@ def _module_name(fun_name: str) -> str:
     return f"{m[1]}_{m[2]}" if m else f"jit_{fun_name}"
 
 
+#: a compiler option at its default: it keys a compile apart from the
+#: executables jax keeps, and changes nothing in what is compiled
+_FRESH = {"xla_embed_ir_in_executable": False}
+
+
 def _abstract(tree):
     """``tree`` with every array leaf as a ``ShapeDtypeStruct``."""
     def leaf(x):
@@ -120,6 +128,8 @@ class CompileSentinel:
         self._traces: Counter = Counter()
         self._signatures: dict = {}          # name -> set of signatures
         self._replays: dict = {}             # module -> {signature: call}
+        self._texts: dict = {}      # module -> {signature: (text, fresh)}
+        self._runs: dict = {}                # module -> Counter(signature)
         self._entry_of: dict = {}            # module -> entry point name
         self._prep: dict = {}                # kind -> [count, seconds]
         self._prep_by: dict = {}             # entry -> kind -> [n, s]
@@ -148,6 +158,15 @@ class CompileSentinel:
             self._entry_of[module] = name
             self._replays.setdefault(module, {})[signature] = (
                 fn, _abstract(tuple(args)), dict(static or {}))
+            self._texts.get(module, {}).pop(signature, None)
+
+    def note_run(self, fn, signature) -> None:
+        """Count one execution of the jitted ``fn`` (call from the host,
+        where it is dispatched) under the ``signature`` its trace note
+        records."""
+        module = f"jit_{fn.__name__}"
+        with self._lock:
+            self._runs.setdefault(module, Counter())[signature] += 1
 
     def listen(self) -> "CompileSentinel":
         """Register the prep counter's ``jax.monitoring`` listeners
@@ -261,29 +280,57 @@ class CompileSentinel:
                 out[n] = {"traces": dt, "programs": dp}
         return out
 
-    # -- scopes ------------------------------------------------------------
-    def hlo_texts(self, module: str) -> list:
-        """The compiled HLO text (``as_text()``) of every signature of
-        ``module`` traced so far, ``module`` as the profiler names it
-        (``jit__null_distribution``, with or without its
-        ``(fingerprint)``). Each instruction's ``op_name`` metadata holds
-        its ``jax.named_scope`` path. Lowers and compiles each signature
-        again, unnoted and uncounted: call it off the hot path.
+    def runs(self, module: str) -> dict:
+        """{signature: executions counted so far} of ``module``, named as
+        the profiler names it (with or without its ``(fingerprint)``)."""
+        with self._lock:
+            return dict(self._runs.get(module.split("(")[0], {}))
 
-        The persistent compile cache's key leaves metadata out, so a hit
-        may carry the scopes of whatever code compiled the entry first.
-        Here the key takes the metadata in: the text always holds this
-        code's scopes, at the price of one compile the first time."""
-        records = self._replays.get(module.split("(")[0], {})
+    # -- scopes ------------------------------------------------------------
+    def compiled(self, module: str, scope: Optional[str] = None) -> dict:
+        """{signature: compiled HLO text (``as_text()``)} of every
+        signature of ``module`` traced so far, ``module`` as the profiler
+        names it (``jit__null_distribution``, with or without its
+        ``(fingerprint)``). Each instruction's ``op_name`` metadata holds
+        its ``jax.named_scope`` path. Lowers a signature again, unnoted
+        and uncounted, the first time it is asked for: call it off the
+        hot path.
+
+        jax keeps the executables it ran: where the call's arguments were
+        not committed to a device (a ``Workspace`` without a ``device``),
+        the signature lowers again to the executable that ran, with no
+        compile; else it compiles, with the metadata in the persistent
+        compile cache's key. That key leaves metadata out by default, so
+        an executable that ran may have been loaded from an entry that
+        other code wrote, whose program differs in its metadata alone,
+        with that code's scopes. With ``scope``, a text that does not
+        name it is compiled once more, apart from jax's own executables
+        (``_FRESH``) and with the metadata in the key: this code's
+        scopes."""
+        module = module.split("(")[0]
+        records = dict(self._replays.get(module, {}))
+        texts = self._texts.setdefault(module, {})
         flag = "jax_compilation_cache_include_metadata_in_key"
         was = getattr(jax.config, flag)
         jax.config.update(flag, True)
         try:
             with self._quiet_window():
-                return [fn.lower(*args, **static).compile().as_text()
-                        for fn, args, static in list(records.values())]
+                for signature, (fn, args, static) in records.items():
+                    text, fresh = texts.get(signature, (None, False))
+                    if text is None:
+                        text = fn.lower(*args, **static).compile().as_text()
+                    if scope is not None and scope not in text and not fresh:
+                        text, fresh = fn.lower(*args, **static).compile(
+                            _FRESH).as_text(), True
+                    texts[signature] = text, fresh
         finally:
             jax.config.update(flag, was)
+        return {sig: texts[sig][0] for sig in records}
+
+    def hlo_texts(self, module: str) -> list:
+        """The texts of ``compiled(module)``, in the order the signatures
+        were first traced."""
+        return list(self.compiled(module).values())
 
     # -- guards ------------------------------------------------------------
     @contextlib.contextmanager
@@ -316,3 +363,8 @@ def note_trace(name: str, signature=None, fn=None, args=(),
                static=None) -> None:
     """Module-level shorthand the instrumented jit bodies call."""
     sentinel.note(name, signature, fn, args, static)
+
+
+def note_run(fn, signature) -> None:
+    """Module-level shorthand the instrumented call sites use."""
+    sentinel.note_run(fn, signature)

@@ -18,12 +18,15 @@ from repro.api import ExecConfig, Workspace
 from repro.api.config import _KNOWN_METRICS
 from repro.core import (CenteredGramOperator, CondensedCenteredGramOperator,
                         DistanceMatrix, condensed_moments_vec, pcoa)
-from repro.dist import (METRICS, condensed_size, get_metric,
+from repro.dist import (METRICS, condensed_size, get_metric, takes_tree,
                         pairwise_condensed, pairwise_distances)
 from repro.kernels.pairwise_ops import pairwise_panel_pallas
 from repro.kernels.pairwise_ref import pairwise_ref
 
 KEY = jax.random.PRNGKey(7)
+# the metrics scipy's pdist also computes; unweighted UniFrac reads a
+# tree and has its own oracle (tests/test_unifrac.py)
+PDIST_METRICS = sorted(m for m in METRICS if not takes_tree(METRICS[m]))
 
 
 def _table(seed, n, d, nonneg=True):
@@ -39,7 +42,7 @@ def _table(seed, n, d, nonneg=True):
 # --------------------------------------------------------------------------
 # metrics vs the scipy oracle
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("metric", sorted(METRICS))
+@pytest.mark.parametrize("metric", PDIST_METRICS)
 @pytest.mark.parametrize("n,d", [(23, 17), (64, 5), (7, 33), (16, 16)])
 def test_metric_matches_pdist(metric, n, d):
     """Acceptance: every metric ≤ 1e-5 off scipy's float64 pdist on
@@ -52,7 +55,7 @@ def test_metric_matches_pdist(metric, n, d):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("metric", sorted(METRICS))
+@pytest.mark.parametrize("metric", PDIST_METRICS)
 def test_zero_row_conventions(metric):
     """Pinned degenerate-pair conventions: two all-zero samples are at
     distance 0 for EVERY metric — including Bray–Curtis, where scipy

@@ -89,6 +89,102 @@ def test_row_layout_draws_carry_the_definition_scopes():
             for op in ops), inner
 
 
+def test_tree_hoist_unifrac_and_pcoa_carry_their_scopes():
+    """The tree hoist's program under ``dist.tree_hoist`` (a span of the
+    same name in the session, one sentinel program per (n, T, B)), the
+    UniFrac metric's accumulate under ``dist.unifrac`` in production,
+    and the fsvd solve under ``pcoa.solve``: the scopes ``tree_hoist_ms``,
+    ``unifrac_production_ms`` and ``pcoa_ms`` read."""
+    from repro.dist import PhyloTree
+    rng = np.random.default_rng(12)
+    tree = PhyloTree.from_newick("(((A:1,B:2):1,C:1):2,(D:1,E:3):1);")
+    before = sentinel.snapshot()
+    ws = Workspace.from_features(
+        rng.poisson(1.0, (14, 5)).astype(np.float32),
+        metric="unweighted_unifrac", tree=tree,
+        config=ExecConfig(obs=ObsConfig(enabled=True)))
+    ws.pcoa(dimensions=3)
+
+    def walk(spans):
+        for sp in spans:
+            yield sp
+            yield from walk(sp.children)
+
+    (prod,) = [sp for sp in walk(ws.obs.tracer.spans)
+               if sp.name == "ws.produce_distances"]
+    (hoist,) = [c for c in prod.children if c.name == "dist.tree_hoist"]
+    assert hoist.attrs == {"n": 14, "d": 5, "branches": 8}
+    assert sentinel.since(before)["dist.tree_hoist"]["programs"] == 1
+    for module, scope in (("jit__tree_hoist", "dist.tree_hoist"),
+                          ("jit__panel_stats", "dist.unifrac"),
+                          ("jit__randomized_eigh_matfree", "pcoa.solve")):
+        paths = _paths(sentinel.hlo_texts(module)).values()
+        assert any(scope in p.split("/") for p in paths), (module, scope)
+
+
+def test_runs_are_counted_by_the_signature_of_each_compiled_program():
+    """Production's panels, the tree hoist and the fsvd solve count their
+    executions under the signatures of the programs ``compiled`` hands
+    over; a second ``compiled`` call compiles nothing again."""
+    from repro.dist import PhyloTree
+    rng = np.random.default_rng(5)
+    tree = PhyloTree.from_newick("(((A:1,B:2):1,C:1):2,(D:1,E:3):1);")
+    table = rng.poisson(1.0, (21, 5)).astype(np.float32)
+    modules = ("jit__tree_hoist", "jit__panel_stats",
+               "jit__randomized_eigh_matfree")
+    before = {m: sentinel.runs(m) for m in modules}
+    ws = Workspace.from_features(table, metric="unweighted_unifrac",
+                                 tree=tree, config=ExecConfig(block=8))
+    ws.pcoa(dimensions=3, key=4)
+    moved = {m: {sig: n - before[m].get(sig, 0)
+                 for sig, n in sentinel.runs(m).items()
+                 if n > before[m].get(sig, 0)} for m in modules}
+    assert list(moved["jit__tree_hoist"].values()) == [1]
+    assert list(moved["jit__panel_stats"].values()) == [3]  # 21 rows by 8
+    assert list(moved["jit__randomized_eigh_matfree"].values()) == [1]
+    for m in modules:
+        assert set(moved[m]) <= set(sentinel.compiled(m)), m
+    prep = sentinel.prep()
+    texts = sentinel.compiled("jit__panel_stats")
+    again = sentinel.compiled("jit__panel_stats")
+    assert again == texts and sentinel.prep_since(prep) == {}
+    assert sentinel.hlo_texts("jit__panel_stats") == list(texts.values())
+
+
+def test_compiled_compiles_afresh_only_a_text_without_the_scope():
+    """Where the run's arguments were not committed, ``compiled`` hands
+    back the executable that ran, with no compile. A text that lacks the
+    scope asked for (as one loaded from a cache entry that other code
+    wrote would) is compiled once more, apart from jax's executables,
+    and then kept."""
+    from repro.dist import PhyloTree
+    rng = np.random.default_rng(8)
+    tree = PhyloTree.from_newick("((A:1,(B:2,C:1):1):2,(D:1,E:3):1);")
+    Workspace.from_features(rng.poisson(1.0, (19, 5)).astype(np.float32),
+                            metric="unweighted_unifrac",
+                            tree=tree).condensed()
+    compiles = []
+
+    def listen(event, seconds, **kwargs):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(event)
+
+    sentinel._texts.pop("jit__tree_hoist", None)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        ran = sentinel.compiled("jit__tree_hoist", "dist.tree_hoist")
+        assert compiles == [] and ((19, 5), 8) in ran
+        assert all("dist.tree_hoist" in t for t in ran.values())
+        fresh = sentinel.compiled("jit__tree_hoist", "pcoa.solve")
+        assert len(compiles) == len(ran)
+        assert sentinel.compiled("jit__tree_hoist", "pcoa.solve") == fresh
+        assert len(compiles) == len(ran)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    for signature, text in ran.items():
+        assert _paths([fresh[signature]]) == _paths([text])
+
+
 def test_scope_map_names_every_fusion_sort_and_gather():
     _, _, stat = _mantel_call(31)
     static = {"permutations": 40, "batch_size": 8}

@@ -22,6 +22,7 @@ from repro.core.mantel import MantelStatistic, mantel_null_distributed
 from repro.core.operators import CondensedCenteredGramOperator
 from repro.dist.driver import _panel_stats
 from repro.dist.metrics import get_metric
+from repro.dist.tree import _tree_hoist
 from repro.kernels.center_matvec_ops import center_matvec_pallas
 from repro.kernels.pairwise_ops import pairwise_panel_pallas
 from repro.kernels.permute_reduce_ops import DEFAULT_CHUNK, _permute_reduce_jit
@@ -100,6 +101,29 @@ def test_panel_stats_xla_compiles(topo, one_chip):
         one_chip((256, D)), one_chip((N, D)),
         metric=get_metric("braycurtis"), feature_block=128, impl="xla",
         interpret=None, block=256).compile()
+    _fits_one_chip(compiled, topo.devices[0].device_kind)
+
+
+TIPS = 2048                    # a binary tree: 2·TIPS − 2 branches
+
+
+def test_tree_hoist_compiles(topo, one_chip):
+    branches = 2 * TIPS - 2
+    compiled = _tree_hoist.lower(
+        one_chip((N, TIPS)), one_chip((TIPS,), jnp.int32),
+        one_chip((branches,), jnp.int32), one_chip((branches,), jnp.int32),
+        one_chip((branches,))).compile()
+    _fits_one_chip(compiled, topo.devices[0].device_kind)
+
+
+def test_panel_stats_unifrac_compiles(topo, one_chip):
+    """Production on the branch embedding: the ``unweighted_unifrac``
+    specialisation of ``_panel_stats``."""
+    branches = 2 * TIPS - 2
+    compiled = _panel_stats.lower(
+        one_chip((256, branches)), one_chip((N, branches)),
+        metric=get_metric("unweighted_unifrac"), feature_block=128,
+        impl="xla", interpret=None, block=256).compile()
     _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
