@@ -120,7 +120,10 @@ class ExecConfig:
         ``"pallas"`` (the VMEM-tiled ``kernels.pairwise`` kernel).
     feature_block:
         Feature-axis chunk of the pairwise metric reduce: bounds the
-        per-tile broadcast term at (rows, cols, feature_block).
+        per-tile broadcast term at (rows, cols, feature_block). A
+        product metric (unweighted UniFrac) on the XLA path ignores it:
+        each panel is one matrix product over the whole width
+        (``resolved_tiles()["feature_block_executed"]`` says so).
         ``"auto"``: the solver only ever *shrinks* this under budget
         pressure, never grows it — feature_block is value-affecting
         (the metric accumulators merge once per feature chunk and fp

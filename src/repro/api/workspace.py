@@ -68,7 +68,7 @@ from repro.core.pcoa import pcoa as _pcoa
 from repro.core.pcoa import resolve_dimensions
 from repro.core.validation import ensure_finite
 from repro.dist import get_metric, pairwise_condensed, takes_tree
-from repro.dist.driver import check_tree
+from repro.dist.driver import check_tree, panel_feature_block
 from repro.kernels.dispatch import HIGHEST
 from repro.launch.mesh import chip_peaks
 from repro.obs.ledger import FEATURE_HOIST_PASSES, HOIST_PASSES
@@ -433,11 +433,7 @@ class Workspace:
             "block_executed": pick_block(self.n, self.config.block, lane,
                                          floor=floor),
             "feature_block": self.config.feature_block,
-            "feature_block_executed": (
-                max(min(self.config.feature_block,
-                        int(self._features.shape[1])), 1)
-                if self._features is not None
-                else self.config.feature_block),
+            "feature_block_executed": self._feature_block_executed(),
             "batch_size": self.config.resolve_batch_size(None, 32),
             "chunk": chunk,
             "chunk_executed": snap_chunk(m, chunk)[0],
@@ -446,6 +442,20 @@ class Workspace:
             "draw_layout": self.draw_layout(),
         }
         return tiles
+
+    def _feature_block_executed(self) -> int:
+        """The feature chunk production reduces at once: the whole width
+        of what the metric reads (a tree metric's branch embedding) for
+        a product metric on the XLA path, else ``feature_block`` at most
+        that width."""
+        fb = self.config.feature_block
+        if self._features is None:
+            return fb
+        width = (self._tree.num_branches if takes_tree(self._metric)
+                 else int(self._features.shape[1]))
+        fb = panel_feature_block(self._metric, self.config.pairwise_impl,
+                                 width, fb)
+        return max(min(fb, width), 1)
 
     def draw_layout(self) -> str:
         """The Mantel draws' layout at this session's n, tile and

@@ -17,10 +17,17 @@ argument applied one level upstream of the analyses:
 Peak memory is one (block, n) strip plus the (m,) condensed output,
 m = n(n−1)/2 — the square matrix is never allocated. Panel compute
 dispatches per ``impl``: ``"pallas"`` routes through the VMEM-tiled
-``kernels.pairwise`` (backend-dispatched interpret, like ``mantel_corr``),
-``"xla"`` is the ``lax.map`` row-panel fallback — sub-panels of rows
-stream against the full table with the metric's reduce feature-chunked,
-so the broadcast term stays (rows, n, chunk)-bounded.
+``kernels.pairwise`` (backend-dispatched interpret, like ``mantel_corr``);
+``"xla"`` has two panel bodies, chosen by the metric's type:
+
+* an elementwise metric runs ``_panel_xla``, the ``lax.map`` row-panel
+  sweep — sub-panels of rows stream against the full table with the
+  metric's reduce feature-chunked, so the broadcast term stays
+  (rows, n, chunk)-bounded;
+* a product metric (``metrics.is_product``: unweighted UniFrac) runs
+  its ``accumulate`` once over the whole feature axis for the whole
+  (block, n) panel, one matrix product that XLA tiles itself;
+  ``feature_block`` does not apply to it.
 """
 
 from __future__ import annotations
@@ -32,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist.metrics import Metric, get_metric, merge_acc, takes_tree
+from repro.dist.metrics import (Metric, get_metric, is_product, merge_acc,
+                                takes_tree)
 from repro.dist.tree import PhyloTree, tree_hoist
 from repro.kernels.dispatch import clamp_block
 from repro.obs.compile import note_run, note_trace
@@ -81,6 +89,14 @@ def _panel_xla(xi: jax.Array, x: jax.Array, metric: Metric,
     return jax.lax.map(one, sub).reshape(bm, x.shape[0])
 
 
+def panel_feature_block(metric: Metric, impl: str, width: int,
+                        feature_block: int) -> int:
+    """The feature chunk that keys a panel program on a ``width``-wide
+    input: a product metric's XLA panel reduces the whole width at once;
+    every other panel takes ``feature_block`` as asked."""
+    return width if impl == "xla" and is_product(metric) else feature_block
+
+
 def _panel_signature(xi, x, metric: Metric, feature_block: int, impl: str,
                      block: int) -> tuple:
     """What keys a ``_panel_stats`` program, for its trace and run notes."""
@@ -91,6 +107,8 @@ def _panel_signature(xi, x, metric: Metric, feature_block: int, impl: str,
 def _run_panel(xi, x, metric: Metric, feature_block: int, impl: str,
                interpret: Optional[bool], block: int):
     """``_panel_stats``, its execution counted (``note_run``)."""
+    feature_block = panel_feature_block(metric, impl, x.shape[1],
+                                        feature_block)
     note_run(_panel_stats, _panel_signature(xi, x, metric, feature_block,
                                             impl, block))
     return _panel_stats(xi, x, metric=metric, feature_block=feature_block,
@@ -105,8 +123,10 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
     """One row strip + its fused row sums: (strip, Σ_j d²).
 
     The row sums ride the same jit region as the strip compute, so XLA
-    fuses them into the panel sweep — the hoist costs no extra pass.
-    Profiler scopes: ``dist.accumulate`` (the strip) and
+    fuses them into the panel sweep — the hoist costs no extra pass. On
+    ``impl="xla"`` a product metric's strip is one ``accumulate`` over
+    the whole panel and feature axis, an elementwise metric's
+    ``_panel_xla``. Profiler scopes: ``dist.accumulate`` (the strip) and
     ``dist.rowsums``."""
     note_trace("dist.panel_stats",
                _panel_signature(xi, x, metric, feature_block, impl, block),
@@ -120,6 +140,8 @@ def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
                                           block_n=block,
                                           feature_block=feature_block,
                                           interpret=interpret)
+        elif is_product(metric):
+            strip = metric.finish(metric.accumulate(xi, x))
         else:
             strip = _panel_xla(xi, x, metric, feature_block)
     with jax.named_scope("dist.rowsums"):
@@ -192,6 +214,7 @@ def pairwise_condensed(x, metric="braycurtis", *,
     cond_parts, rs2_parts = [], []
     with obs.span("dist.pairwise_condensed", phase="production", n=n, d=d,
                   block=b, impl=impl, metric=metric.name,
+                  reduce="product" if is_product(metric) else "elementwise",
                   panels=-(-n // b)):
         for i0 in range(0, n, b):
             i1 = min(i0 + b, n)
@@ -223,10 +246,13 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
                        tree=None) -> jax.Array:
     """The ``scipy.spatial.distance.pdist``/``squareform`` replacement.
 
-    ``out="square"`` assembles the full (n, n) matrix panel-by-panel
-    (exactly symmetric and hollow by construction — each (i, j) is the
-    same fp expression as (j, i)); ``out="condensed"`` is the pdist
-    layout via the streaming driver (no n×n allocated).
+    ``out="square"`` assembles the full (n, n) matrix panel-by-panel,
+    exactly symmetric and hollow: for an elementwise metric by
+    construction (each (i, j) is the same fp expression as (j, i)); a
+    product metric's (i, j) and (j, i) round apart and its diagonal
+    keeps about 1e-6, so its upper triangle is mirrored over a zero
+    diagonal. ``out="condensed"`` is the pdist layout via the streaming
+    driver (no n×n allocated).
     """
     if out == "condensed":
         return pairwise_condensed(x, metric, block=block,
@@ -254,4 +280,8 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
         strip, _ = _run_panel(xi, x, metric, feature_block, impl,
                               interpret, b)
         parts.append(strip[:i1 - i0])
-    return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+    square = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+    if is_product(metric):
+        upper = jnp.triu(square, 1)
+        square = upper + upper.T
+    return square

@@ -1,9 +1,10 @@
 """Beta-diversity distance metrics as pytree dataclasses.
 
 Every metric this subsystem ships reduces a pair of feature vectors to a
-distance through the same algebraic shape: a sum over features of an
-elementwise term (one or two running accumulators), followed by a cheap
-finishing transform. That shape is exactly what the tiled pairwise driver
+distance through the same algebraic shape: a sum over features of a
+per-feature term (one or two running accumulators; for unweighted
+UniFrac a matrix product, ``product``), followed by a cheap finishing
+transform. That shape is exactly what the tiled pairwise driver
 and the Pallas kernel need — the per-feature terms can be accumulated
 chunk-by-chunk while the (bm, d) × (bn, d) tiles are resident in
 VMEM/cache, and only the tiny (bm, bn) accumulators survive between
@@ -175,26 +176,52 @@ class UnweightedUniFrac:
     """Unweighted UniFrac (Lozupone & Knight 2005): the branch length
     present in exactly one of two samples over the branch length present
     in either. It reads the branch embedding E[x, b] = l_b·[b present in
-    x] that ``tree.tree_hoist`` makes from a table and its tree, on which
-    it is Σ|a−b| / Σmax(a, b) (l ≥ 0); ``takes_tree`` tells production
-    to make it. 0/0 (no branch present in either sample) → 0."""
+    x] that ``tree.tree_hoist`` makes from a table and its tree
+    (``takes_tree``). E = I·diag(l) with I exactly 0/1, so the metric is
+    bilinear: with the shared length S = [E > 0]·Eᵀ and the row totals
+    L = Σ_b E, the unshared length is L_x + L_y − 2S and the length in
+    either L_x + L_y − S. ``accumulate`` returns the chunk's shared
+    length and the pair's total, both additive over feature chunks, from
+    one matrix product (``product``: production runs a panel as one
+    product over the whole width): the presence of ``xi``'s rows, and a
+    row of ones, against ``xj``'s embedding, so the ones row gives
+    ``xj``'s row totals without a second read of it. The 0/1 side is
+    bfloat16, in which it is exact; the lengths are not, so the product
+    runs at ``Precision.HIGHEST``, where the default precision would
+    round them to bfloat16. ``finish`` clamps the unshared length at 0,
+    which it is in exact arithmetic: rounding leaves identical samples a
+    few ulps either side. 0/0 (no branch present in either sample) → 0."""
 
     name = "unweighted_unifrac"
     takes_tree = True
+    product = True
 
     def accumulate(self, xi, xj):
         with jax.named_scope("dist.unifrac"):
-            a, b = _pairwise(xi, xj)
-            return {"num": jnp.sum(jnp.abs(a - b), axis=-1),
-                    "den": jnp.sum(jnp.maximum(a, b), axis=-1)}
+            ones = jnp.ones((1, xi.shape[1]), bool)
+            lhs = jnp.concatenate([xi > 0, ones]).astype(jnp.bfloat16)
+            out = jax.lax.dot_general(
+                lhs, xj, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            total = jnp.sum(xi, axis=1)[:, None] + out[-1][None, :]
+            return {"shared": out[:-1], "total": total}
 
     def finish(self, acc):
-        return _safe_div(acc["num"], acc["den"])
+        shared, total = acc["shared"], acc["total"]
+        return _safe_div(jnp.maximum(total - 2.0 * shared, 0.0),
+                         total - shared)
 
 
 def takes_tree(metric: Metric) -> bool:
     """Whether ``metric`` reads a table's tree embedding, not the table."""
     return bool(getattr(metric, "takes_tree", False))
+
+
+def is_product(metric: Metric) -> bool:
+    """Whether ``metric``'s accumulate is one matrix product, which
+    production runs over a panel's whole feature axis at once."""
+    return bool(getattr(metric, "product", False))
 
 
 def merge_acc(acc: Acc, part: Acc) -> Acc:

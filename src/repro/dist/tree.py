@@ -15,8 +15,9 @@ when the running count of x's present tips rises across its interval,
 C_x[hi_b] − C_x[lo_b] > 0: one cumulative sum and two column gathers,
 whatever the tree's depth, exact because it counts tips, not reads. No
 (T, B) incidence matrix is built. Unweighted UniFrac is then
-Σ_b |E_xb − E_yb| / Σ_b max(E_xb, E_yb) (``metrics.UnweightedUniFrac``),
-which the tiled production runs like any other metric.
+Σ_b |E_xb − E_yb| / Σ_b max(E_xb, E_yb), which production computes from
+one matrix product per panel, the shared length [E > 0]·Eᵀ
+(``metrics.UnweightedUniFrac``).
 
 ``PhyloTree.from_newick`` reads the Newick text in which trees reach
 users (QIIME 2, HMP16SData).

@@ -108,8 +108,12 @@ def test_get_metric_coercion_and_config_registry_sync():
                                           (32, 12, 8, 5)])
 def test_pairwise_kernel_matches_ref(metric, n, d, block, fb):
     """Acceptance: the Pallas pairwise kernel agrees with the pure-jnp
-    _ref across non-multiple tile shapes (padding exactness)."""
+    _ref across non-multiple tile shapes (padding exactness). A tree
+    metric reads a branch embedding: each column one branch length where
+    the branch is present, else 0."""
     x = jnp.asarray(_table(3, n, d))
+    if takes_tree(get_metric(metric)):
+        x = jnp.where(x > 0, jnp.asarray(_table(9, 1, d)), 0.0)
     panel = x[:10]
     got = pairwise_panel_pallas(panel, x, metric=get_metric(metric),
                                 block_n=block, feature_block=fb)

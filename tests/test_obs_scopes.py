@@ -120,6 +120,41 @@ def test_tree_hoist_unifrac_and_pcoa_carry_their_scopes():
                           ("jit__randomized_eigh_matfree", "pcoa.solve")):
         paths = _paths(sentinel.hlo_texts(module)).values()
         assert any(scope in p.split("/") for p in paths), (module, scope)
+    # UniFrac production's shared-length product: every dot of its
+    # program lies under the metric's scope
+    texts = [t for t in sentinel.hlo_texts("jit__panel_stats")
+             if "dist.unifrac" in t]
+    dots = [(t, name) for t in texts
+            for name in _instructions(t, ("dot", "convolution"))]
+    assert dots
+    for t, name in dots:
+        assert "dist.unifrac" in hlo_scopes(t)[name][0].split("/"), name
+
+
+@pytest.mark.parametrize("metric, reduce", [
+    ("unweighted_unifrac", "product"), ("braycurtis", "elementwise")])
+def test_production_span_and_tiles_name_the_reduce(metric, reduce):
+    """The production span says which panel body ran; a product metric
+    reports the whole width it reads as its executed feature block."""
+    from repro.dist import PhyloTree
+    rng = np.random.default_rng(14)
+    tree = PhyloTree.from_newick("(((A:1,B:2):1,C:1):2,(D:1,E:3):1);")
+    ws = Workspace.from_features(
+        rng.poisson(1.0, (11, 5)).astype(np.float32), metric=metric,
+        tree=tree if metric == "unweighted_unifrac" else None,
+        config=ExecConfig(feature_block=3, obs=ObsConfig(enabled=True)))
+    ws.condensed()
+
+    def walk(spans):
+        for sp in spans:
+            yield sp
+            yield from walk(sp.children)
+
+    (prod,) = [sp for sp in walk(ws.obs.tracer.spans)
+               if sp.name == "dist.pairwise_condensed"]
+    assert prod.attrs["reduce"] == reduce
+    width = tree.num_branches if reduce == "product" else 3
+    assert ws.resolved_tiles()["feature_block_executed"] == width
 
 
 def test_runs_are_counted_by_the_signature_of_each_compiled_program():

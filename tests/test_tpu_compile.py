@@ -9,6 +9,8 @@ memory fits one chip. The topology is described inside a fixture — never
 while a module is imported — and the tests skip where it cannot be.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -118,13 +120,21 @@ def test_tree_hoist_compiles(topo, one_chip):
 
 def test_panel_stats_unifrac_compiles(topo, one_chip):
     """Production on the branch embedding: the ``unweighted_unifrac``
-    specialisation of ``_panel_stats``."""
-    branches = 2 * TIPS - 2
+    product panel of ``_panel_stats`` at the HMP cohort's size (2,898
+    samples, V3-V5's 90,764 branches), one product over the whole width.
+    Its dot keeps float32: a DEFAULT-precision dot would round the
+    branch lengths to bfloat16."""
+    n, branches = 2898, 90764
     compiled = _panel_stats.lower(
-        one_chip((256, branches)), one_chip((N, branches)),
-        metric=get_metric("unweighted_unifrac"), feature_block=128,
+        one_chip((256, branches)), one_chip((n, branches)),
+        metric=get_metric("unweighted_unifrac"), feature_block=branches,
         impl="xla", interpret=None, block=256).compile()
     _fits_one_chip(compiled, topo.devices[0].device_kind)
+    dots = [line for line in compiled.as_text().splitlines()
+            if re.search(r"= \S+ (dot|convolution)\(", line)]
+    assert dots
+    for line in dots:
+        assert "operand_precision={highest,highest}" in line, line
 
 
 def test_condensed_matvec_compiles(topo, one_chip):

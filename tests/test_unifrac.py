@@ -130,6 +130,55 @@ def test_a_four_tip_tree_by_hand():
         atol=1e-6)
 
 
+def test_duplicate_and_empty_rows_on_the_four_tip_tree():
+    """The product form on the condensed path: a sample against its
+    duplicate is at most a rounding above 0 (never below), two empty
+    samples are at 0 (0/0), an empty one against a present one at 1."""
+    tree = PhyloTree.from_newick("((A:1,B:2):3,(C:4,D:5):6);")
+    x = np.array([[1, 0, 3, 0], [2, 0, 1, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                  [1, 1, 1, 1], [5, 2, 7, 1]], np.float32)
+    got = np.asarray(pairwise_distances(x, "unweighted_unifrac", tree=tree,
+                                        out="condensed", block=4))
+    want = _condensed(unweighted_unifrac_ref(x, tree.parent, tree.length,
+                                             tree.tips))
+    pair = {(i, j): k for k, (i, j) in
+            enumerate(zip(*np.triu_indices(x.shape[0], k=1)))}
+    for dup in ((0, 1), (4, 5)):
+        assert 0.0 <= got[pair[dup]] <= 1e-6
+    assert got[pair[2, 3]] == 0.0
+    assert got[pair[0, 2]] == pytest.approx(1.0)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_square_is_exactly_symmetric_and_hollow(shape, impl):
+    """The product form rounds (i, j) and (j, i) apart and leaves about
+    1e-6 on the diagonal; the square form is symmetric and hollow all
+    the same, and agrees with the oracle."""
+    arrays, x = _tree_and_table(shape, 11, tips=20, n=21)
+    tree = PhyloTree(*arrays)
+    sq = np.asarray(pairwise_distances(x, "unweighted_unifrac", tree=tree,
+                                       block=8, impl=impl))
+    np.testing.assert_array_equal(sq, sq.T)
+    np.testing.assert_array_equal(np.diag(sq), 0.0)
+    np.testing.assert_allclose(sq, unweighted_unifrac_ref(x, *arrays),
+                               rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_pallas_panels_match_the_reference(shape):
+    """The Pallas panel kernel (interpreted here) calls the metric's
+    accumulate on feature chunks of its tiles: the shared length and
+    the totals add over chunks to the oracle's distances."""
+    arrays, x = _tree_and_table(shape, 13, tips=24, n=19)
+    want = _condensed(unweighted_unifrac_ref(x, *arrays))
+    got = np.asarray(pairwise_distances(
+        x, "unweighted_unifrac", tree=PhyloTree(*arrays), out="condensed",
+        block=8, feature_block=5, impl="pallas"))
+    assert np.max(np.abs(got - want)) <= 1e-5
+
+
 @pytest.mark.parametrize("method", ["mantel", "pcoa", "permanova"])
 def test_analyses_match_a_workspace_on_the_reference_square(method):
     arrays, x = _tree_and_table("zero_and_unary", 7, tips=24, n=30)
