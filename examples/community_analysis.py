@@ -91,8 +91,8 @@ def main(n: int = 2048, permutations: int = 999):
     # one session per measurement: the feature table is validated finite +
     # canonicalized once, distances are produced condensed with the
     # operator means fused into the sweep. ExecConfig is where execution
-    # knobs go (metric=..., pairwise_impl="pallas", matvec_impl="pallas",
-    # a mesh for the distributed paths, ...) — defaults suit one CPU/TPU.
+    # knobs go (metric=..., block=..., batch_size=..., a mesh for the
+    # distributed paths, ...) — defaults suit one CPU/TPU.
     # obs=ObsConfig(enabled=True) turns the primary session's telemetry
     # on: spans + analytic traffic ledger (off by default: zero overhead).
     ws = Workspace.from_features(table_a, metric="euclidean",

@@ -1,14 +1,12 @@
 """ExecConfig: one home for every execution knob in the analysis stack.
 
 Before the `repro.api` redesign the knobs that decide *how* an analysis
-executes — which matvec kernel, which centering implementation, whether to
-materialize the Gower matrix, Pallas tile sizes, the permutation batch,
-the device mesh — were scattered as inconsistent per-function kwargs
-(`pcoa(matvec_impl=..., block=...)`, `partial_mantel(kernel=...)`,
+executes — which centering implementation, whether to materialize the
+Gower matrix, tile sizes, the permutation batch, the device mesh — were
+scattered as inconsistent per-function kwargs (`pcoa(block=...)`,
 `permutation_test(batch_size=...)`, ...). ``ExecConfig`` collects them in
 a single frozen pytree dataclass that threads uniformly through
-``api.Workspace``, ``core.pcoa``, ``core.mantel``, ``stats.engine`` and
-the kernel dispatchers.
+``api.Workspace``, ``core.pcoa``, ``core.mantel`` and ``stats.engine``.
 
 It is registered as a *leaf-free* pytree (every field is static metadata),
 so it can sit inside jitted pytrees or static args: two configs compare
@@ -39,20 +37,16 @@ _KNOWN_METRICS = ("braycurtis", "canberra", "cityblock", "euclidean",
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=[],
-         meta_fields=["matvec_impl", "centering_impl", "materialize",
-                      "interpret", "block", "batch_size", "kernel", "mesh",
-                      "device", "metric", "pairwise_impl", "feature_block",
-                      "chunk", "auto", "tune_profile", "obs"])
+         meta_fields=["centering_impl", "materialize", "block",
+                      "batch_size", "mesh", "device", "metric",
+                      "feature_block", "chunk", "auto", "tune_profile",
+                      "obs"])
 @dataclasses.dataclass(frozen=True)
 class ExecConfig:
     """Execution configuration shared by every analysis entry point.
 
     Fields
     ------
-    matvec_impl:
-        Backend for ``CenteredGramOperator.matvec`` — ``"xla"`` (row-blocked
-        jnp matmuls, the default) or ``"pallas"`` (the VMEM-tiled
-        ``kernels.center_matvec`` kernel).
     centering_impl:
         Implementation used whenever a *materialized* Gower-centered matrix
         is required (PERMANOVA's hoist, ``pcoa(method="eigh")``, the
@@ -63,14 +57,9 @@ class ExecConfig:
         ``True`` restores the legacy materialize-then-solve ordination path
         (the benchmark baseline); ``False`` (default) runs PCoA matrix-free
         through the operator.
-    interpret:
-        Pallas dispatch mode — ``None`` (default) auto-resolves per backend
-        (native on TPU, interpreter elsewhere, e.g. this container's CPU);
-        ``True``/``False`` force it.
     block:
-        Row/column tile size for the operator matvec and the Pallas kernels
-        (lane-snapped per backend by the shared ``kernels.dispatch``
-        policy). ``"auto"``: solved by ``repro.tune`` as the largest
+        Row tile size of distance production and of the operator matvec.
+        ``"auto"``: solved by ``repro.tune`` as the largest
         lane-snapped block whose modeled resident set (one D tile + the
         x panels; plus the production strip when feature-backed) fits
         the backend budget, *capped at the default* (shrink-only, like
@@ -83,7 +72,7 @@ class ExecConfig:
         ordination/PERMANOVA agree to fp tolerance instead.
     batch_size:
         Permutations evaluated per engine tile — for the batch-fused
-        statistics (Mantel family, ANOSIM) this is exactly the B grid
+        statistics (Mantel family, ANOSIM) this is exactly the B
         dimension of ``kernels.permute_reduce``: each hoisted condensed
         invariant streams ONCE per tile and is reused by all B
         permutations, so bigger batches mean less traffic per
@@ -97,11 +86,6 @@ class ExecConfig:
         (capped at 128, where the 3m/B amortization is within 3% of its
         asymptote); batch choice is bitwise-neutral (pinned by the
         engine's batch-size-invariance test).
-    kernel:
-        Backend for the batched condensed permutation reductions of the
-        Mantel family and ANOSIM — ``"xla"`` (default; the ``lax.scan``
-        twin of the kernel) or ``"pallas"`` (``kernels.permute_reduce``
-        with explicit VMEM chunk streaming).
     mesh:
         Optional ``jax.sharding.Mesh`` for the distributed paths
         (``centering_impl="distributed"``, distributed matvec/engine).
@@ -114,15 +98,11 @@ class ExecConfig:
         ``repro.dist`` registry name ("braycurtis", "euclidean",
         "jaccard", "canberra", "cityblock", "unweighted_unifrac"; the
         last needs the table's tree, ``tree=``).
-    pairwise_impl:
-        Backend for the ``repro.dist`` tiled distance production —
-        ``"xla"`` (the ``lax.map`` row-panel fallback, the default) or
-        ``"pallas"`` (the VMEM-tiled ``kernels.pairwise`` kernel).
     feature_block:
         Feature-axis chunk of the pairwise metric reduce: bounds the
         per-tile broadcast term at (rows, cols, feature_block). A
-        product metric (unweighted UniFrac) on the XLA path ignores it:
-        each panel is one matrix product over the whole width
+        product metric (unweighted UniFrac) ignores it: each panel is
+        one matrix product over the whole width
         (``resolved_tiles()["feature_block_executed"]`` says so).
         ``"auto"``: the solver only ever *shrinks* this under budget
         pressure, never grows it — feature_block is value-affecting
@@ -163,17 +143,13 @@ class ExecConfig:
         ``None`` coerces to the disabled default.
     """
 
-    matvec_impl: str = "xla"
     centering_impl: str = "fused"
     materialize: bool = False
-    interpret: Optional[bool] = None
     block: Union[int, str] = 256
     batch_size: Union[int, str, None] = None
-    kernel: str = "xla"
     mesh: Optional[Any] = None
     device: Optional[Any] = None
     metric: str = "braycurtis"
-    pairwise_impl: str = "xla"
     feature_block: Union[int, str] = 128
     chunk: Union[int, str, None] = None
     auto: bool = False
@@ -186,13 +162,9 @@ class ExecConfig:
         if not isinstance(self.obs, ObsConfig):
             raise ValueError(f"obs must be an ObsConfig (or None), "
                              f"got {self.obs!r}")
-        if self.matvec_impl not in ("xla", "pallas"):
-            raise ValueError(f"unknown matvec_impl {self.matvec_impl!r}")
         if self.centering_impl not in ("ref", "fused", "distributed"):
             raise ValueError(f"unknown centering_impl "
                              f"{self.centering_impl!r}")
-        if self.kernel not in ("xla", "pallas"):
-            raise ValueError(f"unknown kernel {self.kernel!r}")
         if self.centering_impl == "distributed" and self.mesh is None:
             raise ValueError("centering_impl='distributed' requires a mesh")
         for knob in ("block", "feature_block"):
@@ -209,9 +181,6 @@ class ExecConfig:
         if self.metric not in _KNOWN_METRICS:
             raise ValueError(f"unknown metric {self.metric!r}; "
                              f"available: {list(_KNOWN_METRICS)}")
-        if self.pairwise_impl not in ("xla", "pallas"):
-            raise ValueError(f"unknown pairwise_impl "
-                             f"{self.pairwise_impl!r}")
 
     def replace(self, **changes) -> "ExecConfig":
         """A copy with ``changes`` applied (``dataclasses.replace``)."""

@@ -77,8 +77,7 @@ from repro.obs.trace import NULL_OBS
 from repro.stats import engine
 from repro.stats.anosim import AnosimStatistic, rank_transform_condensed
 from repro.stats.engine import PermutationTestResult, as_key
-from repro.stats.partial_mantel import (PartialMantelPallasStatistic,
-                                        PartialMantelStatistic)
+from repro.stats.partial_mantel import PartialMantelStatistic
 from repro.stats.permanova import (PermanovaOperatorStatistic,
                                    PermanovaStatistic)
 from repro.stats.permdisp import PermdispStatistic
@@ -424,7 +423,7 @@ class Workspace:
         from repro.kernels.dispatch import (lane_geometry, pick_block,
                                             snap_chunk)
         from repro.kernels.permute_reduce_ops import DEFAULT_CHUNK
-        lane, floor = lane_geometry(self.config.interpret)
+        lane, floor = lane_geometry(None)
         m = self.n * (self.n - 1) // 2
         chunk = (self.config.chunk if self.config.chunk is not None
                  else DEFAULT_CHUNK)
@@ -446,15 +445,13 @@ class Workspace:
     def _feature_block_executed(self) -> int:
         """The feature chunk production reduces at once: the whole width
         of what the metric reads (a tree metric's branch embedding) for
-        a product metric on the XLA path, else ``feature_block`` at most
-        that width."""
+        a product metric, else ``feature_block`` at most that width."""
         fb = self.config.feature_block
         if self._features is None:
             return fb
         width = (self._tree.num_branches if takes_tree(self._metric)
                  else int(self._features.shape[1]))
-        fb = panel_feature_block(self._metric, self.config.pairwise_impl,
-                                 width, fb)
+        fb = panel_feature_block(self._metric, width, fb)
         return max(min(fb, width), 1)
 
     def draw_layout(self) -> str:
@@ -534,13 +531,10 @@ class Workspace:
             return
         with self._obs.span("ws.produce_distances", phase="production",
                             n=self.n, d=int(self._features.shape[1]),
-                            metric=self._metric.name,
-                            impl=self.config.pairwise_impl):
+                            metric=self._metric.name):
             prod = pairwise_condensed(
                 self._features, self._metric, block=self.config.block,
-                feature_block=self.config.feature_block,
-                impl=self.config.pairwise_impl,
-                interpret=self.config.interpret, tree=self._tree)
+                feature_block=self.config.feature_block, tree=self._tree)
         self.cache.get("condensed", lambda: prod["condensed"])
         self.cache.get("dist_means", lambda: {
             k: prod[k] for k in ("row_means", "global_mean")})
@@ -571,9 +565,7 @@ class Workspace:
             return self.cache.get("operator", build)
         return self.cache.get("operator", lambda: (
             CenteredGramOperator.from_distance(
-                self.data, block=self.config.block,
-                impl=self.config.matvec_impl,
-                interpret=self.config.interpret)))
+                self.data, block=self.config.block)))
 
     def gram(self) -> jax.Array:
         """The materialized Gower-centered matrix (PERMANOVA's hoist; the
@@ -696,8 +688,6 @@ class Workspace:
             codes, num_groups = self._codes(grouping)
             return AnosimStatistic(None, codes, self.n, num_groups,
                                    pre=self.ranks(),
-                                   kernel=self.config.kernel,
-                                   interpret=self.config.interpret,
                                    chunk=self.config.chunk), "greater"
         if method == "permdisp":
             codes, num_groups = self._codes(grouping)
@@ -713,8 +703,6 @@ class Workspace:
             pre = {"normxm": self.moments()["norm"],
                    "ynorm": y.moments()["hat"]}
             return MantelStatistic(self.condensed(), None, self.n, pre=pre,
-                                   kernel=self.config.kernel,
-                                   interpret=self.config.interpret,
                                    chunk=self.config.chunk,
                                    layout=self.draw_layout()), "two-sided"
         if method == "partial_mantel":
@@ -744,13 +732,9 @@ class Workspace:
             # fixed sides ride in via pre only (their y/z fields are
             # consumed solely by the no-pre hoist) — nothing square for
             # any operand
-            cls = (PartialMantelPallasStatistic
-                   if self.config.kernel == "pallas"
-                   else PartialMantelStatistic)
-            return cls(self.condensed(), None, None, self.n, pre=pre,
-                       kernel=self.config.kernel,
-                       interpret=self.config.interpret,
-                       chunk=self.config.chunk), "two-sided"
+            return PartialMantelStatistic(
+                self.condensed(), None, None, self.n, pre=pre,
+                chunk=self.config.chunk), "two-sided"
         raise ValueError(
             f"unknown method {method!r}; expected one of ('permanova', "
             f"'anosim', 'permdisp', 'mantel', 'partial_mantel')")
@@ -780,8 +764,7 @@ class Workspace:
         the condensed within-indicator by closed-form triangle indexing,
         so neither backing ever materializes a square rank matrix."""
         with self._obs.span("ws.anosim", n=self.n,
-                            permutations=permutations,
-                            kernel=self.config.kernel):
+                            permutations=permutations):
             stat, alt = self.statistic("anosim", grouping=grouping)
             return engine.permutation_test(
                 stat, permutations, key, alternative=alt,
@@ -820,8 +803,7 @@ class Workspace:
         whose memory holds them: the test's hoist builds the centred x
         and ŷ as two hollow squares, and each draw gathers their rows)."""
         with self._obs.span("ws.mantel", n=self.n,
-                            permutations=permutations,
-                            kernel=self.config.kernel):
+                            permutations=permutations):
             stat, _ = self.statistic("mantel", other=other)
             return engine.permutation_test(
                 stat, permutations, key, alternative=alternative,
@@ -834,12 +816,9 @@ class Workspace:
                        ) -> PermutationTestResult:
         """Partial Mantel of this matrix against ``other``, controlling
         for ``control``; ŷ is residualized from cached moments — all
-        three operands stay condensed (``mantel``'s condensed layout).
-        Routes through the Pallas ``permute_reduce`` backend when
-        ``config.kernel == "pallas"``."""
+        three operands stay condensed (``mantel``'s condensed layout)."""
         with self._obs.span("ws.partial_mantel", n=self.n,
-                            permutations=permutations,
-                            kernel=self.config.kernel):
+                            permutations=permutations):
             stat, _ = self.statistic("partial_mantel", other=other,
                                      control=control)
             return engine.permutation_test(

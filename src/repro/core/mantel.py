@@ -167,7 +167,7 @@ def draw_layout(n: int, batch_size: int, backend: str,
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["x", "y", "pre"],
-         meta_fields=["n", "kernel", "interpret", "chunk", "layout"])
+         meta_fields=["n", "chunk", "layout"])
 @dataclasses.dataclass
 class MantelStatistic:
     """Pearson r between permuted x and fixed y, hoisting split per §4.2.
@@ -182,16 +182,12 @@ class MantelStatistic:
     centered-normalized y, assembled from two Workspaces' cached
     ``condensed_moments``) so repeated tests against one matrix skip the
     per-test normalization passes — and a fixed side never builds any
-    square form at all. ``kernel`` picks the batched reduction backend
-    (``"xla"``: the lax.scan twin; ``"pallas"``: the explicit-VMEM
-    kernel), both routed through ``kernels.permute_reduce``."""
+    square form at all."""
 
     x: jax.Array           # (n, n) square or (m,) condensed, permuted side
     y: Optional[jax.Array]  # same, held fixed; may be None when pre is given
     n: int
     pre: Optional[dict] = None
-    kernel: str = "xla"
-    interpret: Optional[bool] = None
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
     layout: str = "condensed"    # the draws' layout, one of LAYOUTS
 
@@ -238,8 +234,7 @@ class MantelStatistic:
             return (permute_reduce_rows(inv["xs"], inv["ys"], orders)[0]
                     / inv["normxm"])
         stats = permute_reduce(inv["xc"], inv["ynorm"][None, :], orders,
-                               inv["ii"], inv["jj"], impl=self.kernel,
-                               chunk=self.chunk, interpret=self.interpret)
+                               inv["ii"], inv["jj"], chunk=self.chunk)
         return stats[0] / inv["normxm"]
 
 

@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,15 +43,14 @@ from repro.kernels.dispatch import HIGHEST
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["d", "row_means", "global_mean"],
-         meta_fields=["n", "block", "impl", "interpret"])
+         meta_fields=["n", "block"])
 @dataclasses.dataclass
 class CenteredGramOperator:
     """The Gower-centered Gram matrix of a distance matrix, as an operator.
 
     A pytree (``register_dataclass``) so it can cross ``jax.jit`` boundaries:
-    ``d``/``row_means``/``global_mean`` are traced, the tiling metadata is
-    static. ``impl`` selects the matvec backend: ``"xla"`` (row-blocked jnp,
-    the default) or ``"pallas"`` (the VMEM-tiled ``kernels.center_matvec``).
+    ``d``/``row_means``/``global_mean`` are traced, the row-strip ``block``
+    is static.
     """
 
     d: jax.Array            # (n, n) distance matrix — the ONLY n² buffer
@@ -60,21 +58,15 @@ class CenteredGramOperator:
     global_mean: jax.Array  # ()    global mean of E
     n: int
     block: int = 256
-    impl: str = "xla"
-    interpret: Optional[bool] = None    # Pallas only; None = auto by backend
 
     @classmethod
-    def from_distance(cls, d: jax.Array, *, block: int = 256,
-                      impl: str = "xla",
-                      interpret: Optional[bool] = None) -> "CenteredGramOperator":
+    def from_distance(cls, d: jax.Array, *,
+                      block: int = 256) -> "CenteredGramOperator":
         """Hoist r and m in one read of D — no n² intermediate is written."""
-        if impl not in ("xla", "pallas"):
-            raise ValueError(f"unknown matvec impl {impl!r}")
         n = d.shape[0]
         # mean-of-square fuses the elementwise map into the row reduction
         row_means = -0.5 * jnp.mean(d * d, axis=1)
-        return cls(d, row_means, jnp.mean(row_means), n, block, impl,
-                   interpret)
+        return cls(d, row_means, jnp.mean(row_means), n, block)
 
     @property
     def dtype(self):
@@ -92,26 +84,18 @@ class CenteredGramOperator:
         squeeze = x.ndim == 1
         if squeeze:
             x = x[:, None]
-        if self.impl == "pallas":
-            # the kernel wrapper hoists its own correction vectors
-            from repro.kernels.center_matvec_ops import center_matvec_pallas
-            out = center_matvec_pallas(
-                self.d, x, self.row_means, self.global_mean,
-                block_m=self.block, block_n=self.block,
-                interpret=self.interpret)
-        else:
-            colsum = jnp.sum(x, axis=0)                  # 1ᵀX   (k,)
-            corr = self.global_mean * colsum - jnp.matmul(
-                self.row_means, x, precision=HIGHEST)    # m·1ᵀX − rᵀX
-            b = max(min(self.block, self.n), 1)
-            parts = []
-            for i0 in range(0, self.n, b):               # static row strips
-                rows = self.d[i0:i0 + b]
-                e_rows = -0.5 * rows * rows              # fused into the dot
-                parts.append(jnp.matmul(e_rows, x, precision=HIGHEST)
-                             - self.row_means[i0:i0 + b, None] * colsum[None, :]
-                             + corr[None, :])
-            out = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+        colsum = jnp.sum(x, axis=0)                  # 1ᵀX   (k,)
+        corr = self.global_mean * colsum - jnp.matmul(
+            self.row_means, x, precision=HIGHEST)    # m·1ᵀX − rᵀX
+        b = max(min(self.block, self.n), 1)
+        parts = []
+        for i0 in range(0, self.n, b):               # static row strips
+            rows = self.d[i0:i0 + b]
+            e_rows = -0.5 * rows * rows              # fused into the dot
+            parts.append(jnp.matmul(e_rows, x, precision=HIGHEST)
+                         - self.row_means[i0:i0 + b, None] * colsum[None, :]
+                         + corr[None, :])
+        out = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
         return out[:, 0] if squeeze else out
 
     def trace(self) -> jax.Array:

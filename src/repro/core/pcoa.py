@@ -9,12 +9,11 @@ by deleting the n² write (and the solver's k re-reads) entirely:
 * ``core.operators.CenteredGramOperator`` hoists the row/global means of
   ``E = −½D∘D`` in one read of D and applies
   ``F @ X = E@X − r(1ᵀX) − 1(rᵀX) + m·1(1ᵀX)`` to skinny (n, k+p) blocks,
-  with the E-formation fused into each row-blocked matmul (XLA) or
-  VMEM-tiled in-register (``kernels.center_matvec``, ``matvec_impl=
-  "pallas"``). Sfiligoi et al. 2021 ("Enabling microbiome research on
-  personal devices") make the same point from the footprint side: dropping
-  the materialized intermediate is what lets large-cohort ordination fit
-  on small machines.
+  with the E-formation fused into each row-blocked matmul. Sfiligoi et
+  al. 2021 ("Enabling microbiome research on personal devices") make the
+  same point from the footprint side: dropping the materialized
+  intermediate is what lets large-cohort ordination fit on small
+  machines.
 * ``method="fsvd"`` — randomized range-finder with power iterations
   (Halko et al. 2011, Algs. 4.3/5.3) driven entirely through
   ``operator.matvec``; ``materialize=True`` restores the old
@@ -90,8 +89,8 @@ def _subspace_iteration(matvec, n: int, dtype, key, k: int, oversample: int,
     Range finder: Y = A Ω, orthonormalize, power-iterate (A symmetric ⇒
     AᵀA = A²); project T = QᵀAQ (small, (k+p)²); exact eigh of T lifts
     back. Every O(n²k)-flop step is a single fused matvec — the operator
-    decides whether that is a sharded matmul, a row-blocked XLA sweep or
-    the Pallas kernel. Profiler scope ``pcoa.solve``.
+    decides whether that is a sharded matmul or a row-blocked XLA sweep.
+    Profiler scope ``pcoa.solve``.
     """
     with jax.named_scope("pcoa.solve"):
         p = min(k + oversample, n)
@@ -162,7 +161,7 @@ def materialized_gram(dm_data: jax.Array, centering_impl: str = "fused",
 def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
          method: str = "fsvd", key=None, mesh=None,
          centering_impl: str = "fused", materialize: bool = False,
-         matvec_impl: str = "xla", block: int = 256,
+         block: int = 256,
          config: Optional[ExecConfig] = None,
          operator: Optional[CenteredGramOperator] = None,
          gram: Optional[jax.Array] = None,
@@ -177,7 +176,7 @@ def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
 
     Execution knobs resolve from ``config`` (an ``api.ExecConfig``) when
     given; the legacy kwargs (``mesh``/``centering_impl``/``materialize``/
-    ``matvec_impl``/``block``) are kept for compatibility and are ignored
+    ``block``) are kept for compatibility and are ignored
     when ``config`` is present. ``key`` accepts a PRNG key or int seed
     (``stats.engine.as_key``; None -> the documented seed 42). A Workspace
     passes its cached ``operator`` (matrix-free paths) or ``gram`` (the
@@ -199,7 +198,7 @@ def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
     from repro.stats.engine import as_key
     cfg = config if config is not None else ExecConfig(
         mesh=mesh, centering_impl=centering_impl, materialize=materialize,
-        matvec_impl=matvec_impl, block=block)
+        block=block)
     key = as_key(key, default=42)
 
     if dm is None:
@@ -243,8 +242,7 @@ def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
     if method not in ("eigh", "fsvd"):
         raise ValueError(f"unknown method {method!r}")
     with current_obs().span(f"pcoa.{method}", phase="solve", n=n, k=k,
-                            materialize=cfg.materialize,
-                            impl=cfg.matvec_impl):
+                            materialize=cfg.materialize):
         if method == "eigh":
             centered = _gram(dm.data)
             evals, evecs = _exact_eigh(centered, k)
@@ -265,9 +263,7 @@ def pcoa(dm: Optional[DistanceMatrix], dimensions: int = 10,
                      CenteredGramOperator.from_distance(dm.data)).trace()
         else:
             op = operator if operator is not None else \
-                CenteredGramOperator.from_distance(
-                    dm.data, block=cfg.block, impl=cfg.matvec_impl,
-                    interpret=cfg.interpret)
+                CenteredGramOperator.from_distance(dm.data, block=cfg.block)
             # the signature its trace note records, at the default sketch
             note_run(_randomized_eigh_matfree, (op.n, k, 10, 2))
             evals, evecs = _randomized_eigh_matfree(op, key, k)

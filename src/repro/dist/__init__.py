@@ -13,11 +13,11 @@ distances — and fuses it straight into the hoists the analyses consume:
 * ``tree``     — ``PhyloTree`` (a rooted tree as arrays, validated and
   ordered once on the host, read from Newick) and the device tree hoist
   that turns a table into the branch embedding UniFrac reads.
-* ``unifrac_ref`` — the eager float64 UniFrac oracle the tests compare
-  against.
+* ``pairwise_ref`` and ``unifrac_ref`` — the eager oracles the tests
+  compare production against (``unifrac_ref`` in float64).
 * ``driver``   — the cache-blocked producer: row panels stream through
-  the Pallas ``kernels.pairwise`` kernel (``impl="pallas"``) or the
-  ``lax.map`` fallback (``impl="xla"``), emitting the condensed form
+  XLA (a ``lax.map`` sweep for an elementwise metric, one matrix product
+  for a product metric), emitting the condensed form
   while the operator means (row/global means of E = −½ D∘D) and the
   Mantel moments accumulate tile-by-tile — so
   ``Workspace.from_features(...)`` runs a feature-table→PCoA→PERMANOVA

@@ -15,10 +15,8 @@ argument applied one level upstream of the analyses:
   run matrix-free PCoA/PERMANOVA without a square n×n ever existing.
 
 Peak memory is one (block, n) strip plus the (m,) condensed output,
-m = n(n−1)/2 — the square matrix is never allocated. Panel compute
-dispatches per ``impl``: ``"pallas"`` routes through the VMEM-tiled
-``kernels.pairwise`` (backend-dispatched interpret, like ``mantel_corr``);
-``"xla"`` has two panel bodies, chosen by the metric's type:
+m = n(n−1)/2 — the square matrix is never allocated. A panel has two
+bodies, chosen by the metric's type:
 
 * an elementwise metric runs ``_panel_xla``, the ``lax.map`` row-panel
   sweep — sub-panels of rows stream against the full table with the
@@ -33,8 +31,6 @@ dispatches per ``impl``: ``"pallas"`` routes through the VMEM-tiled
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -89,58 +85,42 @@ def _panel_xla(xi: jax.Array, x: jax.Array, metric: Metric,
     return jax.lax.map(one, sub).reshape(bm, x.shape[0])
 
 
-def panel_feature_block(metric: Metric, impl: str, width: int,
+def panel_feature_block(metric: Metric, width: int,
                         feature_block: int) -> int:
     """The feature chunk that keys a panel program on a ``width``-wide
-    input: a product metric's XLA panel reduces the whole width at once;
+    input: a product metric's panel reduces the whole width at once;
     every other panel takes ``feature_block`` as asked."""
-    return width if impl == "xla" and is_product(metric) else feature_block
+    return width if is_product(metric) else feature_block
 
 
-def _panel_signature(xi, x, metric: Metric, feature_block: int, impl: str,
-                     block: int) -> tuple:
+def _panel_signature(xi, x, metric: Metric, feature_block: int) -> tuple:
     """What keys a ``_panel_stats`` program, for its trace and run notes."""
-    return (tuple(xi.shape), tuple(x.shape), metric.name, feature_block,
-            impl, block)
+    return (tuple(xi.shape), tuple(x.shape), metric.name, feature_block)
 
 
-def _run_panel(xi, x, metric: Metric, feature_block: int, impl: str,
-               interpret: Optional[bool], block: int):
+def _run_panel(xi, x, metric: Metric, feature_block: int):
     """``_panel_stats``, its execution counted (``note_run``)."""
-    feature_block = panel_feature_block(metric, impl, x.shape[1],
-                                        feature_block)
-    note_run(_panel_stats, _panel_signature(xi, x, metric, feature_block,
-                                            impl, block))
-    return _panel_stats(xi, x, metric=metric, feature_block=feature_block,
-                        impl=impl, interpret=interpret, block=block)
+    feature_block = panel_feature_block(metric, x.shape[1], feature_block)
+    note_run(_panel_stats, _panel_signature(xi, x, metric, feature_block))
+    return _panel_stats(xi, x, metric=metric, feature_block=feature_block)
 
 
-@partial(jax.jit, static_argnames=("metric", "feature_block", "impl",
-                                   "interpret", "block"))
+@partial(jax.jit, static_argnames=("metric", "feature_block"))
 def _panel_stats(xi: jax.Array, x: jax.Array, *, metric: Metric,
-                 feature_block: int, impl: str, interpret: Optional[bool],
-                 block: int):
+                 feature_block: int):
     """One row strip + its fused row sums: (strip, Σ_j d²).
 
     The row sums ride the same jit region as the strip compute, so XLA
-    fuses them into the panel sweep — the hoist costs no extra pass. On
-    ``impl="xla"`` a product metric's strip is one ``accumulate`` over
-    the whole panel and feature axis, an elementwise metric's
-    ``_panel_xla``. Profiler scopes: ``dist.accumulate`` (the strip) and
-    ``dist.rowsums``."""
+    fuses them into the panel sweep — the hoist costs no extra pass. A
+    product metric's strip is one ``accumulate`` over the whole panel
+    and feature axis, an elementwise metric's ``_panel_xla``. Profiler
+    scopes: ``dist.accumulate`` (the strip) and ``dist.rowsums``."""
     note_trace("dist.panel_stats",
-               _panel_signature(xi, x, metric, feature_block, impl, block),
+               _panel_signature(xi, x, metric, feature_block),
                _panel_stats, (xi, x),
-               {"metric": metric, "feature_block": feature_block,
-                "impl": impl, "interpret": interpret, "block": block})
+               {"metric": metric, "feature_block": feature_block})
     with jax.named_scope("dist.accumulate"):
-        if impl == "pallas":
-            from repro.kernels.pairwise_ops import pairwise_panel_pallas
-            strip = pairwise_panel_pallas(xi, x, metric=metric,
-                                          block_n=block,
-                                          feature_block=feature_block,
-                                          interpret=interpret)
-        elif is_product(metric):
+        if is_product(metric):
             strip = metric.finish(metric.accumulate(xi, x))
         else:
             strip = _panel_xla(xi, x, metric, feature_block)
@@ -177,8 +157,6 @@ def _metric_input(x, metric: Metric, tree):
 def pairwise_condensed(x, metric="braycurtis", *,
                        block: int = _DEFAULT_BLOCK,
                        feature_block: int = _DEFAULT_FEATURE_BLOCK,
-                       impl: str = "xla",
-                       interpret: Optional[bool] = None,
                        tree=None) -> dict:
     """Condensed distances + fused hoists from an (n, d) feature table.
 
@@ -198,8 +176,6 @@ def pairwise_condensed(x, metric="braycurtis", *,
     (block, n) strip plus the condensed output.
     """
     metric = get_metric(metric)
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"unknown pairwise impl {impl!r}")
     x = jnp.asarray(x)
     if x.ndim != 2:
         raise ValueError(f"expected an (n, d) feature table, got {x.shape}")
@@ -213,7 +189,7 @@ def pairwise_condensed(x, metric="braycurtis", *,
 
     cond_parts, rs2_parts = [], []
     with obs.span("dist.pairwise_condensed", phase="production", n=n, d=d,
-                  block=b, impl=impl, metric=metric.name,
+                  block=b, metric=metric.name,
                   reduce="product" if is_product(metric) else "elementwise",
                   panels=-(-n // b)):
         for i0 in range(0, n, b):
@@ -221,13 +197,12 @@ def pairwise_condensed(x, metric="braycurtis", *,
             xi = x[i0:i1]
             if i1 - i0 < b:                 # pad the short tail panel so
                 xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))  # one trace fits all
-            strip, rs2 = _run_panel(xi, x, metric, feature_block, impl,
-                                    interpret, b)
+            strip, rs2 = _run_panel(xi, x, metric, feature_block)
             rs2_parts.append(rs2[:i1 - i0])
             idx = _panel_condensed_indices(n, i0, i1)
             if idx.size:
                 cond_parts.append(strip.reshape(-1)[jnp.asarray(idx)])
-    obs.charge_production(n, d, b, metric=metric.name, impl=impl)
+    obs.charge_production(n, d, b, metric=metric.name)
 
     rowsum_d2 = jnp.concatenate(rs2_parts)
     condensed = (jnp.concatenate(cond_parts) if cond_parts
@@ -241,8 +216,6 @@ def pairwise_condensed(x, metric="braycurtis", *,
 def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
                        block: int = _DEFAULT_BLOCK,
                        feature_block: int = _DEFAULT_FEATURE_BLOCK,
-                       impl: str = "xla",
-                       interpret: Optional[bool] = None,
                        tree=None) -> jax.Array:
     """The ``scipy.spatial.distance.pdist``/``squareform`` replacement.
 
@@ -256,13 +229,11 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
     """
     if out == "condensed":
         return pairwise_condensed(x, metric, block=block,
-                                  feature_block=feature_block, impl=impl,
-                                  interpret=interpret, tree=tree)["condensed"]
+                                  feature_block=feature_block,
+                                  tree=tree)["condensed"]
     if out != "square":
         raise ValueError(f"out must be 'square' or 'condensed', got {out!r}")
     metric = get_metric(metric)
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"unknown pairwise impl {impl!r}")
     x = jnp.asarray(x)
     if x.ndim != 2:
         raise ValueError(f"expected an (n, d) feature table, got {x.shape}")
@@ -277,8 +248,7 @@ def pairwise_distances(x, metric="braycurtis", *, out: str = "square",
         xi = x[i0:i1]
         if i1 - i0 < b:
             xi = jnp.pad(xi, ((0, b - (i1 - i0)), (0, 0)))
-        strip, _ = _run_panel(xi, x, metric, feature_block, impl,
-                              interpret, b)
+        strip, _ = _run_panel(xi, x, metric, feature_block)
         parts.append(strip[:i1 - i0])
     square = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
     if is_product(metric):

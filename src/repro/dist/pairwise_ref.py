@@ -1,7 +1,7 @@
-"""Pure-jnp oracle for the pairwise kernel: naive full-broadcast pairwise
+"""Pure-jnp oracle for distance production: naive full-broadcast pairwise
 distances, each metric written out longhand the way scipy.spatial.distance
-documents it (the memory behaviour the tiled kernel exists to avoid — the
-(n, m, d) broadcast intermediate is materialized whole)."""
+documents it (the memory behaviour the tiled ``driver`` exists to avoid —
+the (n, m, d) broadcast intermediate is materialized whole)."""
 
 from __future__ import annotations
 

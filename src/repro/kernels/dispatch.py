@@ -1,26 +1,20 @@
-"""Shared backend-dispatch and tile-snapping policy for every kernel.
+"""Shared backend-dispatch and tile-snapping policy for the kernels.
 
-Before this module the four kernel dispatchers (``center_matvec_ops``,
-``mantel_corr_ops``, ``pairwise_ops``, ``permute_reduce_ops``) each
-carried their own copy of the same three decisions:
+* **interpret resolution** and **lane geometry** — ``None`` means
+  "TPU-native on a TPU backend, the Pallas interpreter everywhere else";
+  TPU-native tiles need lane-aligned (multiple of 128) trailing dims
+  while the interpreter takes the fp32 sublane multiple of 8. Only the
+  Pallas kernels no analysis path calls (``center``, ``symhollow``,
+  ``mantel_corr``, ``rmsnorm``) dispatch on them;
+* **tile snapping** — ``pick_block``: the largest multiple-of-lane block
+  ``<=`` requested, clamped to the problem size, with a floor for tiny
+  inputs; ``clamp_block`` is the un-laned clamp the XLA row panels use;
+* **chunk geometry** — ``snap_chunk`` for the condensed streams of
+  ``permute_reduce``.
 
-* **interpret resolution** — ``None`` means "TPU-native on a TPU
-  backend, the Pallas interpreter everywhere else" (this container's
-  CPU);
-* **lane geometry** — TPU-native tiles need lane-aligned (multiple of
-  128) trailing dims while the interpreter is happy with the fp32
-  sublane multiple of 8, so every tile knob is snapped down to the
-  backend's lane before use;
-* **tile snapping** — the largest multiple-of-lane block ``<=``
-  requested, clamped to the problem size, with a floor for tiny inputs.
-
-``repro.tune`` (the cost-model autotuner) consumes the SAME helpers, so
-the tile sizes the solver models are exactly the tile sizes the kernels
-execute — a lane-width change lands in the model and the dispatchers
-simultaneously, and the two can never drift.
-
-``center_matvec_ops`` re-exports ``pick_block``/``resolve_interpret``
-for backward compatibility; new code should import from here.
+``repro.tune`` and ``Workspace.resolved_tiles`` snap with
+``lane_geometry(None)`` as well: the lane of those Pallas kernels, which
+the XLA bodies of the analysis path (``clamp_block``) do not use.
 """
 
 from __future__ import annotations

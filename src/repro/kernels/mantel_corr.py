@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.center_matvec_ops import resolve_interpret
+from repro.kernels.dispatch import resolve_interpret
 
 
 def _mantel_kernel(xp_ref, y_ref, out_ref):

@@ -1,18 +1,11 @@
-"""jit'd public wrapper for the batched permuted-gather-reduce.
+"""jit'd batched permuted-gather-reduce.
 
-One entry point, two implementations with identical semantics and the
-same analytic traffic profile (the tests pin them against each other and
-against ``permute_reduce_ref``):
-
-* ``impl="pallas"`` — the explicit-VMEM kernel in ``permute_reduce.py``
-  (TPU-native when ``jax.default_backend() == "tpu"``, the interpreter
-  elsewhere, like every kernel in this package);
-* ``impl="xla"``   — a ``lax.scan`` over the same condensed chunks: the
-  streamed invariants enter one (S, chunk) tile at a time, the permuted
-  gather is a single vectorized (B, chunk) take, and the multiply-reduce
-  is one small matmul. Peak extra memory is one (B, chunk) gather tile —
-  never (B, m), and never any n² buffer. This is the production CPU
-  path (XLA:CPU vectorizes the gather; the Pallas interpreter does not).
+``permute_reduce`` is a ``lax.scan`` over condensed chunks: the
+streamed invariants enter one (S, chunk) tile at a time, the permuted
+gather is a single vectorized (B, chunk) take, and the multiply-reduce
+is one small matmul. Peak extra memory is one (B, chunk) gather tile —
+never (B, m), and never any n² buffer. The tests pin it against
+``permute_reduce_ref``.
 
 The wrapper owns the hoistable geometry: the triangle coordinate map
 (ii, jj) via ``triangle_coords`` — callers may pass a precomputed pair to
@@ -45,16 +38,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.dispatch import HIGHEST, snap_chunk
-from repro.kernels.permute_reduce import permute_reduce_kernel
 from repro.obs.compile import note_trace
 
-# condensed chunk streamed per grid step. 64k floats = 256 KiB per ys row:
+# condensed chunk streamed per scan step. 64k floats = 256 KiB per ys row:
 # big enough that the (B, chunk) gather tile amortizes loop overhead,
-# small enough to stay cache/VMEM-resident alongside the xc block.
+# small enough to stay cache-resident alongside the xc block.
 # ``repro.tune`` solves this knob from the measured budget instead when
 # ``ExecConfig(auto=True)``; callers pass chunk=None to keep the default.
 DEFAULT_CHUNK = 65536
-_DEFAULT_CHUNK = DEFAULT_CHUNK            # backward-compat alias
 
 # the chunk/padding geometry is the shared ``kernels.dispatch.snap_chunk``
 # policy (also consumed by the tuner's resident-set model)
@@ -62,10 +53,9 @@ _chunk_geometry = snap_chunk
 
 
 def _reduce_xla(xc, ys, ii, jj, orders, n: int, chunk: int) -> jax.Array:
-    """The lax.scan twin: same chunking, same math, pure XLA. Its steps
-    carry the profiler scopes ``index`` (order gathers and triangle
-    arithmetic), ``gather`` (the take from ``xc``) and ``reduce`` (the
-    matmul)."""
+    """The chunked ``lax.scan``. Its steps carry the profiler scopes
+    ``index`` (order gathers and triangle arithmetic), ``gather`` (the
+    take from ``xc``) and ``reduce`` (the matmul)."""
     s, m_pad = ys.shape
     num_chunks = m_pad // chunk
     ii_c = ii.reshape(num_chunks, chunk)
@@ -90,11 +80,10 @@ def _reduce_xla(xc, ys, ii, jj, orders, n: int, chunk: int) -> jax.Array:
     return out
 
 
-@partial(jax.jit, static_argnames=("impl", "chunk", "interpret"))
+@partial(jax.jit, static_argnames=("chunk",))
 def _permute_reduce_jit(xc: jax.Array, ys: jax.Array, orders: jax.Array,
                         ii: Optional[jax.Array], jj: Optional[jax.Array], *,
-                        impl: str, chunk: int,
-                        interpret: Optional[bool]) -> jax.Array:
+                        chunk: int) -> jax.Array:
     """All B permuted condensed multiply-reduces of one invariant stack.
 
     out[s, b] = sum_k ys[s, k] * xc[tri(orders[b, i_k], orders[b, j_k])]
@@ -115,8 +104,6 @@ def _permute_reduce_jit(xc: jax.Array, ys: jax.Array, orders: jax.Array,
     # the package inits (core → mantel → stats → kernels)
     from repro.core.distance_matrix import MAX_TRIANGLE_N, triangle_coords
 
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"unknown permute_reduce impl {impl!r}")
     b_perms, n = orders.shape
     if n > MAX_TRIANGLE_N:
         raise ValueError(
@@ -130,10 +117,9 @@ def _permute_reduce_jit(xc: jax.Array, ys: jax.Array, orders: jax.Array,
     if ys.ndim != 2 or ys.shape[1] != m:
         raise ValueError(f"ys must be (S, {m}), got {ys.shape}")
     # trace-time only: THE padded per_batch kernel entry — one program
-    # per (n, B, S, impl, chunk) whatever K the engine runs (nested-jit
-    # bodies trace once per distinct avals even across outer retraces)
-    note_trace("kernels.permute_reduce",
-               (n, b_perms, ys.shape[0], impl, chunk, interpret))
+    # per (n, B, S, chunk) whatever K the engine runs (nested-jit bodies
+    # trace once per distinct avals even across outer retraces)
+    note_trace("kernels.permute_reduce", (n, b_perms, ys.shape[0], chunk))
     if m == 0:                                     # n < 2: empty triangle
         return jnp.zeros((ys.shape[0], b_perms), dtype=xc.dtype)
 
@@ -152,21 +138,13 @@ def _permute_reduce_jit(xc: jax.Array, ys: jax.Array, orders: jax.Array,
         ys = jnp.pad(ys, ((0, 0), (0, pad)))
         ii = jnp.pad(ii, (0, pad))
         jj = jnp.pad(jj, (0, pad), constant_values=1)
-
-    if impl == "pallas":
-        # one kernel does the index arithmetic, gather and reduce; the
-        # gather is what it is for
-        with jax.named_scope("gather"):
-            return permute_reduce_kernel(xc, ys, ii, jj, orders,
-                                         chunk=chunk, interpret=interpret)
     return _reduce_xla(xc, ys, ii, jj, orders, n, chunk)
 
 
 def permute_reduce(xc: jax.Array, ys: jax.Array, orders: jax.Array,
                    ii: Optional[jax.Array] = None,
-                   jj: Optional[jax.Array] = None, *, impl: str = "xla",
-                   chunk: Optional[int] = None,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   jj: Optional[jax.Array] = None, *,
+                   chunk: Optional[int] = None) -> jax.Array:
     """All B permuted condensed multiply-reduces of one invariant stack
     (see ``_permute_reduce_jit`` for the exact semantics and shapes).
 
@@ -176,9 +154,8 @@ def permute_reduce(xc: jax.Array, ys: jax.Array, orders: jax.Array,
     entry and one sentinel program.
     """
     return _permute_reduce_jit(
-        xc, ys, orders, ii, jj, impl=impl,
-        chunk=DEFAULT_CHUNK if chunk is None else int(chunk),
-        interpret=interpret)
+        xc, ys, orders, ii, jj,
+        chunk=DEFAULT_CHUNK if chunk is None else int(chunk))
 
 
 def hollow_square(xc: jax.Array, n: int) -> jax.Array:

@@ -1,10 +1,10 @@
-"""Eager oracle for the batched permuted-gather-reduce kernel.
+"""Eager oracle for the batched permuted-gather-reduce.
 
 Deliberately takes the long way round (the PR-4 square-gather loop shape):
 per permutation, rebuild the full permuted square ``X[o][:, o]``, extract
 its condensed triangle, and dot it against every streamed invariant row.
-``permute_reduce`` and its Pallas kernel must agree with this to fp
-tolerance — it is the ground truth that the closed-form triangle indexing
+``permute_reduce`` and ``permute_reduce_rows`` must agree with this to
+fp tolerance — it is the ground truth that the closed-form triangle indexing
 ``xc[k(order[i], order[j])]`` really is the condensed form of the permuted
 matrix.
 """

@@ -190,34 +190,6 @@ class DriftSentinel:
                            "lax.map", "args + 1..5 accumulator buffers")
         return [bv, pv]
 
-    # -- fused center-matvec (Pallas) --------------------------------------
-    def check_center_matvec(self, rec) -> List[DriftVerdict]:
-        p = rec.params
-        n, k = int(p["n"]), int(p["k"])
-        floor = 4.0 * (n * n + 2 * n * k + 2 * n)   # D + x + out + vecs
-        args = floor
-        interp = p.get("interpret")
-        emulated = interp is None and self.backend != "tpu" or bool(interp)
-        if emulated:
-            # the Pallas interpreter lowers grid steps to while+slice
-            # copies; HLO traffic is emulation overhead, not the
-            # kernel's DMA plan — bracket wide and say so
-            bv = self._verdict("kernels.center_matvec", "bytes",
-                               rec.bytes_corrected, floor, floor,
-                               30.0 * floor, "interpret",
-                               "envelope: Pallas interpreter emulation")
-            pv = self._verdict("kernels.center_matvec", "peak",
-                               rec.peak_bytes, args, args, 4.0 * args,
-                               "interpret", "padded block copies")
-        else:
-            bv = self._verdict("kernels.center_matvec", "bytes",
-                               rec.bytes_corrected, floor, floor,
-                               2.0 * floor, "native", "tight: one D pass")
-            pv = self._verdict("kernels.center_matvec", "peak",
-                               rec.peak_bytes, args, args, 1.5 * args,
-                               "native", "args + block scratch")
-        return [bv, pv]
-
     # -- calibration stream pass -------------------------------------------
     def check_stream(self, rec) -> List[DriftVerdict]:
         nbytes = 8.0 * int(rec.params["n"])         # read + write fp32
@@ -229,7 +201,6 @@ class DriftSentinel:
     _CHECKS = {
         "kernels.permute_reduce": "check_permute_reduce",
         "dist.panel_stats": "check_panel",
-        "kernels.center_matvec": "check_center_matvec",
         "tune.stream_pass": "check_stream",
     }
 

@@ -50,8 +50,8 @@ from typing import Dict, List, Optional, Tuple
 __all__ = [
     "ProbeRecord", "probe_lowered", "scan_corrected_bytes",
     "computation_multipliers",
-    "probe_permute_reduce", "probe_panel_stats", "probe_center_matvec",
-    "probe_pcoa_matfree", "probe_statistic", "probe_stream_pass",
+    "probe_permute_reduce", "probe_panel_stats", "probe_pcoa_matfree",
+    "probe_statistic", "probe_stream_pass",
     "probe_session", "probe_table", "clear_probe_cache",
 ]
 
@@ -397,8 +397,7 @@ def _memo_key(name: str, params: dict) -> tuple:
 # Entry-point probes (aval-only: nothing executes)
 # --------------------------------------------------------------------------
 def probe_permute_reduce(n: int, batch: int = 32, s: int = 1,
-                         chunk: Optional[int] = None, impl: str = "xla",
-                         interpret: Optional[bool] = None) -> ProbeRecord:
+                         chunk: Optional[int] = None) -> ProbeRecord:
     """Measure ONE (B, n) tile of the batched condensed reduce — the
     program ``stats.engine``'s ``per_batch`` path runs per tile."""
     import jax
@@ -408,8 +407,7 @@ def probe_permute_reduce(n: int, batch: int = 32, s: int = 1,
                                                   _permute_reduce_jit)
 
     chunk = DEFAULT_CHUNK if chunk is None else int(chunk)
-    params = {"n": n, "batch": batch, "s": s, "chunk": chunk,
-              "impl": impl, "interpret": interpret}
+    params = {"n": n, "batch": batch, "s": s, "chunk": chunk}
     key = _memo_key("kernels.permute_reduce", params)
     if key not in _MEMO:
         m = n * (n - 1) // 2
@@ -419,8 +417,7 @@ def probe_permute_reduce(n: int, batch: int = 32, s: int = 1,
             jax.ShapeDtypeStruct((s, m), f32),
             jax.ShapeDtypeStruct((batch, n), i32),
             jax.ShapeDtypeStruct((m,), i32),
-            jax.ShapeDtypeStruct((m,), i32),
-            impl=impl, chunk=chunk, interpret=interpret)
+            jax.ShapeDtypeStruct((m,), i32), chunk=chunk)
         _MEMO[key] = probe_lowered("kernels.permute_reduce", lowered,
                                    params)
     return _MEMO[key]
@@ -428,8 +425,7 @@ def probe_permute_reduce(n: int, batch: int = 32, s: int = 1,
 
 def probe_panel_stats(n: int, d: int, block: int = 256,
                       feature_block: int = 128,
-                      metric: str = "braycurtis", impl: str = "xla",
-                      interpret: Optional[bool] = None) -> ProbeRecord:
+                      metric: str = "braycurtis") -> ProbeRecord:
     """Measure ONE row panel of the distance production sweep (strip +
     fused running sums) — ``dist.driver`` runs ⌈n/block⌉ of these."""
     import jax
@@ -442,41 +438,14 @@ def probe_panel_stats(n: int, d: int, block: int = 256,
     b = clamp_block(n, block)
     fb = max(min(feature_block, d), 1)
     params = {"n": n, "d": d, "block": b, "feature_block": fb,
-              "metric": metric, "impl": impl, "interpret": interpret}
+              "metric": metric}
     key = _memo_key("dist.panel_stats", params)
     if key not in _MEMO:
         lowered = _panel_stats.lower(
             jax.ShapeDtypeStruct((b, d), jnp.float32),
             jax.ShapeDtypeStruct((n, d), jnp.float32),
-            metric=get_metric(metric), feature_block=fb, impl=impl,
-            interpret=interpret, block=b)
+            metric=get_metric(metric), feature_block=fb)
         _MEMO[key] = probe_lowered("dist.panel_stats", lowered, params)
-    return _MEMO[key]
-
-
-def probe_center_matvec(n: int, k: int = 10, block_m: int = 512,
-                        block_n: int = 512,
-                        interpret: Optional[bool] = None) -> ProbeRecord:
-    """Measure one fused center-matvec pass over the square (n, n) D —
-    the ``matvec_impl="pallas"`` operator kernel."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.center_matvec_ops import center_matvec_pallas
-
-    params = {"n": n, "k": k, "block_m": block_m, "block_n": block_n,
-              "interpret": interpret}
-    key = _memo_key("kernels.center_matvec", params)
-    if key not in _MEMO:
-        f32 = jnp.float32
-        lowered = center_matvec_pallas.lower(
-            jax.ShapeDtypeStruct((n, n), f32),
-            jax.ShapeDtypeStruct((n, k), f32),
-            jax.ShapeDtypeStruct((n,), f32),
-            jax.ShapeDtypeStruct((), f32),
-            block_m=block_m, block_n=block_n, interpret=interpret)
-        _MEMO[key] = probe_lowered("kernels.center_matvec", lowered,
-                                   params)
     return _MEMO[key]
 
 
@@ -548,7 +517,6 @@ def probe_session(ws, dimensions: int = 10) -> Dict[str, ProbeRecord]:
 
     * ``kernels.permute_reduce`` — always (every permutation test);
     * ``dist.panel_stats``       — feature-backed sessions (production);
-    * ``kernels.center_matvec``  — square-backed Pallas-matvec sessions;
     * ``pcoa.fsvd_matfree``      — when the operator hoist is already
       cached (probing must not trigger builds mid-report).
     """
@@ -557,8 +525,7 @@ def probe_session(ws, dimensions: int = 10) -> Dict[str, ProbeRecord]:
     n = ws.n
     records: Dict[str, ProbeRecord] = {}
     records["kernels.permute_reduce"] = probe_permute_reduce(
-        n, batch=tiles["batch_size"], s=1, chunk=tiles["chunk"],
-        impl=cfg.kernel, interpret=cfg.interpret)
+        n, batch=tiles["batch_size"], s=1, chunk=tiles["chunk"])
     if ws._features is not None:
         records["dist.panel_stats"] = probe_panel_stats(
             n, int(ws._features.shape[1]),
@@ -566,11 +533,7 @@ def probe_session(ws, dimensions: int = 10) -> Dict[str, ProbeRecord]:
             else tiles["block_executed"],
             feature_block=tiles["feature_block_executed"]
             if isinstance(tiles["feature_block_executed"], int) else 128,
-            metric=cfg.metric or "braycurtis",
-            impl=cfg.pairwise_impl, interpret=cfg.interpret)
-    elif cfg.matvec_impl == "pallas":
-        records["kernels.center_matvec"] = probe_center_matvec(
-            n, k=dimensions, interpret=cfg.interpret)
+            metric=cfg.metric or "braycurtis")
     if "operator" in ws.cache:
         op = ws.cache._store["operator"]      # peek — no counter perturbed
         records["pcoa.fsvd_matfree"] = probe_pcoa_matfree(op, k=dimensions)

@@ -45,7 +45,6 @@ from repro.stats.engine import (
 from repro.stats.anosim import AnosimStatistic, anosim, anosim_ref, \
     rank_transform, rank_transform_condensed
 from repro.stats.partial_mantel import (
-    PartialMantelPallasStatistic,
     PartialMantelStatistic,
     partial_mantel,
     partial_mantel_ref,
@@ -63,7 +62,7 @@ __all__ = [
     "permutation_test", "permutation_test_distributed",
     "AnosimStatistic", "anosim", "anosim_ref", "rank_transform",
     "rank_transform_condensed",
-    "PartialMantelPallasStatistic", "PartialMantelStatistic",
+    "PartialMantelStatistic",
     "partial_mantel", "partial_mantel_ref",
     "PermanovaOperatorStatistic", "PermanovaStatistic", "permanova",
     "permanova_ref",

@@ -78,7 +78,7 @@ def rank_transform(dm_data: jax.Array, n: int) -> dict:
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["dm", "grouping", "pre"],
-         meta_fields=["n", "num_groups", "kernel", "interpret", "chunk"])
+         meta_fields=["n", "num_groups", "chunk"])
 @dataclasses.dataclass
 class AnosimStatistic:
     """Clarke's R with ranks hoisted out of the Monte-Carlo loop, on the
@@ -87,17 +87,13 @@ class AnosimStatistic:
     ``dm`` may be a square (n, n) matrix, a condensed (m,) vector, or
     ``None`` when ``pre`` carries the session-level rank hoist (the
     ``rank_transform`` dict from a Workspace's ``HoistCache``) so
-    back-to-back tests on one matrix sort the condensed distances once.
-    ``kernel`` picks the ``permute_reduce`` backend for the batched
-    path."""
+    back-to-back tests on one matrix sort the condensed distances once."""
 
     dm: Optional[jax.Array]   # (n, n) square / (m,) condensed / None w/ pre
     grouping: jax.Array       # (n,) int group codes in [0, num_groups)
     n: int
     num_groups: int
     pre: Optional[dict] = None   # optional pre-hoisted rank_transform dict
-    kernel: str = "xla"
-    interpret: Optional[bool] = None
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
 
     def hoist(self):
@@ -136,8 +132,7 @@ class AnosimStatistic:
     def per_batch(self, inv, orders):
         w_sums = permute_reduce(inv["within"], inv["ranks"][None, :],
                                 orders, inv["ii"], inv["jj"],
-                                impl=self.kernel, chunk=self.chunk,
-                                interpret=self.interpret)
+                                chunk=self.chunk)
         return self._finish_r(inv, w_sums[0])
 
 

@@ -47,7 +47,7 @@ from repro.stats.engine import PermutationTestResult
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["x", "y", "z", "pre"],
-         meta_fields=["n", "kernel", "interpret", "chunk"])
+         meta_fields=["n", "chunk"])
 @dataclasses.dataclass
 class PartialMantelStatistic:
     """r_xy·z with ŷ residualized against ẑ once, outside the loop —
@@ -59,16 +59,13 @@ class PartialMantelStatistic:
     from three Workspaces' cached ``condensed_moments`` by
     ``Workspace.partial_mantel``) so repeated tests reuse the
     normalization and residualization passes and the fixed sides never
-    build a square form. ``kernel`` picks the ``permute_reduce`` backend
-    for the batched path (``"xla"`` / ``"pallas"``)."""
+    build a square form."""
 
     x: jax.Array           # permuted side
     y: Optional[jax.Array]  # held fixed; may be None when pre is given
     z: Optional[jax.Array]  # held fixed (the control); ditto
     n: int
     pre: Optional[dict] = None
-    kernel: str = "xla"
-    interpret: Optional[bool] = None
     chunk: Optional[int] = None  # condensed stream chunk (None: kernel default)
 
     def hoist(self):
@@ -105,43 +102,25 @@ class PartialMantelStatistic:
         # reductions, and each invariant streams once per B permutations
         ys = jnp.stack([inv["y_res"], inv["z"]])
         stats = permute_reduce(inv["xc"], ys, orders, inv["ii"], inv["jj"],
-                               impl=self.kernel, chunk=self.chunk,
-                               interpret=self.interpret)
+                               chunk=self.chunk)
         num = stats[0] / inv["normxm"]
         r_xz = stats[1] / inv["normxm"]
         return num / jnp.sqrt(1.0 - r_xz * r_xz)
-
-
-@partial(jax.tree_util.register_dataclass,
-         data_fields=["x", "y", "z", "pre"],
-         meta_fields=["n", "kernel", "interpret", "chunk"])
-@dataclasses.dataclass
-class PartialMantelPallasStatistic(PartialMantelStatistic):
-    """Same statistic with the Pallas ``permute_reduce`` backend pinned —
-    kept as a named class for the ``kernel="pallas"`` dispatch and
-    backward compatibility."""
-
-    kernel: str = "pallas"
 
 
 def partial_mantel(x: DistanceMatrix, y: DistanceMatrix, z: DistanceMatrix,
                    permutations: int = 999,
                    key=None,
                    alternative: str = "two-sided",
-                   batch_size: int = 32,
-                   kernel: str = "xla") -> PermutationTestResult:
-    """Hoisted+fused partial Mantel on the condensed batch loop.
-    ``kernel="pallas"`` routes the stacked inner products through the
-    explicit-VMEM ``permute_reduce`` kernel (interpret mode on CPU; the
-    TPU-native path at scale) instead of its XLA twin. Thin wrapper over
-    a one-shot ``api.Workspace`` — identical p-values per key; sessions
-    hold their own Workspace to share the normalization hoists."""
-    from repro.api.config import ExecConfig
+                   batch_size: int = 32) -> PermutationTestResult:
+    """Hoisted+fused partial Mantel on the condensed batch loop. Thin
+    wrapper over a one-shot ``api.Workspace`` — identical p-values per
+    key; sessions hold their own Workspace to share the normalization
+    hoists."""
     from repro.api.workspace import Workspace
-    cfg = ExecConfig(kernel=kernel)      # validates the kernel name too
     # validate=False: trust the DistanceMatrix as constructed, exactly like
     # the pre-session implementation that read x.data directly
-    return Workspace(x, config=cfg, validate=False).partial_mantel(
+    return Workspace(x, validate=False).partial_mantel(
         y, z, permutations=permutations, key=key, alternative=alternative,
         batch_size=batch_size)
 

@@ -142,7 +142,7 @@ def perm_batch_cost(n: int, batch: int, chunk: int, s: int = 1,
 
 
 # --------------------------------------------------------------------------
-# the tiled distance production sweep (dist.driver / kernels.pairwise)
+# the tiled distance production sweep (dist.driver)
 # --------------------------------------------------------------------------
 def production_cost(n: int, d: int, block: int,
                     feature_block: int = 128) -> CostTerms:
@@ -163,7 +163,7 @@ def production_cost(n: int, d: int, block: int,
 
 
 # --------------------------------------------------------------------------
-# the centered-operator matvec (kernels.center_matvec) / fsvd coords
+# the centered-operator matvec (core.operators) / fsvd coords
 # --------------------------------------------------------------------------
 def matvec_cost(n: int, k: int, block: int, passes: float = 1.0,
                 lane: int = 8) -> CostTerms:

@@ -142,8 +142,7 @@ def _solve_batch_chunk(n: int, s: int, budget_floats: float
 
 def solve_tiles(n: int, d: Optional[int] = None, *,
                 budget: Optional[BackendBudget] = None,
-                profile: Optional[str] = None,
-                interpret: Optional[bool] = None, s: int = 2) -> TunedTiles:
+                profile: Optional[str] = None, s: int = 2) -> TunedTiles:
     """Solve every tile knob for a problem of ``n`` observations (and
     ``d`` features when feature-backed).
 
@@ -163,7 +162,7 @@ def solve_tiles(n: int, d: Optional[int] = None, *,
     if budget is None:
         budget = load_profile(profile) if profile else detect_budget()
     bf = budget.working_floats
-    lane, floor = lane_geometry(interpret)
+    lane, floor = lane_geometry(None)
 
     # feature_block: start at the default (clamped to d) and SHRINK only
     # while even the smallest block cannot fit the production step
@@ -233,8 +232,7 @@ def resolve_exec_config(config, n: int, d: Optional[int] = None):
     if not (want_block or want_fb or want_batch or want_chunk):
         return config, None
 
-    tuned = solve_tiles(n, d, profile=getattr(config, "tune_profile", None),
-                        interpret=config.interpret)
+    tuned = solve_tiles(n, d, profile=getattr(config, "tune_profile", None))
     updates = {"auto": False}
     if want_block:
         updates["block"] = tuned.block

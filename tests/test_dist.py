@@ -1,8 +1,9 @@
-"""repro.dist tests: every metric against the scipy pdist oracle
+"""repro.dist tests: every metric against a float64 oracle — scipy's
+pdist, or for Jaccard its documented definition written out here —
 (property-style sweeps over odd/non-tile-multiple shapes, zero rows with
-the pinned 0/0 conventions), the Pallas pairwise kernel against its _ref
-across awkward tile shapes, the fused hoist accumulators against
-square-matrix recomputation, the condensed-backed operator against the
+the pinned 0/0 conventions), the production panel against
+``pairwise_ref`` across awkward tile shapes, the fused hoist
+accumulators against square-matrix recomputation, the condensed-backed operator against the
 square operator, and the Workspace.from_features acceptance battery —
 including the "no n×n square on the matrix-free path" guarantee, cache
 refresh()/generation semantics, the eigh lower-k coords serving, and the
@@ -18,10 +19,11 @@ from repro.api import ExecConfig, Workspace
 from repro.api.config import _KNOWN_METRICS
 from repro.core import (CenteredGramOperator, CondensedCenteredGramOperator,
                         DistanceMatrix, condensed_moments_vec, pcoa)
-from repro.dist import (METRICS, condensed_size, get_metric, takes_tree,
-                        pairwise_condensed, pairwise_distances)
-from repro.kernels.pairwise_ops import pairwise_panel_pallas
-from repro.kernels.pairwise_ref import pairwise_ref
+from repro.dist import (METRICS, PhyloTree, condensed_size, get_metric,
+                        takes_tree, pairwise_condensed, pairwise_distances)
+from repro.dist.driver import _panel_stats
+from repro.dist.pairwise_ref import pairwise_ref
+from repro.dist.unifrac_ref import unweighted_unifrac_ref
 
 KEY = jax.random.PRNGKey(7)
 # the metrics scipy's pdist also computes; unweighted UniFrac reads a
@@ -39,18 +41,37 @@ def _table(seed, n, d, nonneg=True):
     return x.astype(np.float32)
 
 
+def _jaccard64(x):
+    """Condensed float64 Jaccard as ``repro.dist.metrics`` documents it:
+    #(a≠b) / #(a≠0 ∨ b≠0), with 0/0 → 0."""
+    x = np.asarray(x, dtype=np.float64)
+    i, j = np.triu_indices(x.shape[0], k=1)
+    a, b = x[i], x[j]
+    num = np.sum(a != b, axis=1).astype(np.float64)
+    den = np.sum((a != 0) | (b != 0), axis=1).astype(np.float64)
+    return np.where(den > 0, num / np.where(den > 0, den, 1.0), 0.0)
+
+
+def _pdist64(x, metric):
+    """The float64 condensed oracle of an untreed metric."""
+    # SciPy >= 1.17 reads non-boolean input to jaccard as presence/absence
+    if metric == "jaccard":
+        return _jaccard64(x)
+    return pdist(np.asarray(x, dtype=np.float64), metric)
+
+
 # --------------------------------------------------------------------------
 # metrics vs the scipy oracle
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("metric", PDIST_METRICS)
 @pytest.mark.parametrize("n,d", [(23, 17), (64, 5), (7, 33), (16, 16)])
 def test_metric_matches_pdist(metric, n, d):
-    """Acceptance: every metric ≤ 1e-5 off scipy's float64 pdist on
+    """Acceptance: every metric ≤ 1e-5 off its float64 oracle on
     random fp32 tables, including odd / non-tile-multiple n and d."""
     x = _table(0, n, d)
     got = np.asarray(pairwise_distances(x, metric, out="condensed",
                                         block=16, feature_block=8))
-    want = pdist(x.astype(np.float64), metric)
+    want = _pdist64(x, metric)
     assert got.shape == (condensed_size(n),)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -67,8 +88,8 @@ def test_zero_row_conventions(metric):
     sq = np.asarray(pairwise_distances(x, metric, block=8, feature_block=4))
     assert sq[0, 5] == 0.0 and sq[5, 0] == 0.0
     assert np.all(np.isfinite(sq))
-    # non-degenerate pairs still match scipy
-    want = squareform(pdist(x.astype(np.float64), metric))
+    # non-degenerate pairs still match the oracle
+    want = squareform(_pdist64(x, metric))
     mask = np.ones_like(sq, dtype=bool)
     mask[0, 5] = mask[5, 0] = False        # the 0/0 pair (scipy: NaN)
     np.testing.assert_allclose(sq[mask], want[mask], rtol=1e-5, atol=1e-5)
@@ -95,40 +116,57 @@ def test_get_metric_coercion_and_config_registry_sync():
     with pytest.raises(ValueError, match="unknown metric"):
         ExecConfig(metric="chebyshev")
     with pytest.raises(ValueError):
-        ExecConfig(pairwise_impl="cuda")
-    with pytest.raises(ValueError):
         ExecConfig(feature_block=0)
 
 
 # --------------------------------------------------------------------------
-# the Pallas kernel vs its oracle / the xla fallback
+# the production panel and driver vs their oracles
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("metric", sorted(METRICS))
 @pytest.mark.parametrize("n,d,block,fb", [(30, 11, 8, 4), (17, 7, 16, 16),
                                           (32, 12, 8, 5)])
 def test_pairwise_kernel_matches_ref(metric, n, d, block, fb):
-    """Acceptance: the Pallas pairwise kernel agrees with the pure-jnp
-    _ref across non-multiple tile shapes (padding exactness). A tree
-    metric reads a branch embedding: each column one branch length where
-    the branch is present, else 0."""
+    """Acceptance: one (block, n) production panel and its fused row
+    sums agree with the pure-jnp ``pairwise_ref`` across feature chunks
+    that do not divide d. A tree metric reads a branch embedding: each
+    column one branch length where the branch is present, else 0."""
     x = jnp.asarray(_table(3, n, d))
     if takes_tree(get_metric(metric)):
         x = jnp.where(x > 0, jnp.asarray(_table(9, 1, d)), 0.0)
-    panel = x[:10]
-    got = pairwise_panel_pallas(panel, x, metric=get_metric(metric),
-                                block_n=block, feature_block=fb)
-    want = pairwise_ref(panel, x, metric)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+    panel = x[:block]
+    got, rs2 = _panel_stats(panel, x, metric=get_metric(metric),
+                            feature_block=fb)
+    want = np.asarray(pairwise_ref(panel, x, metric))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(rs2), np.sum(want * want, axis=1),
+                               rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_driver_impls_agree(impl):
+def _balanced_tree(tips):
+    """A complete binary tree over ``tips`` leaves, as PhyloTree arrays:
+    node v's parent is (v − 1) // 2, the leaves are the last nodes."""
+    nodes = 2 * tips - 1
+    parent = (np.arange(nodes) - 1) // 2
+    parent[0] = -1
+    length = np.random.default_rng(6).exponential(0.1, nodes)
+    return parent, length, np.arange(tips - 1, nodes)
+
+
+@pytest.mark.parametrize("metric", sorted(METRICS))
+def test_driver_matches_reference(metric):
+    """The whole driver, panels that do not divide n and feature chunks
+    that do not divide d, against each metric's float64 oracle: pdist,
+    the Jaccard definition, or ``unifrac_ref`` on a tree."""
     x = _table(4, 27, 13)
-    got = np.asarray(pairwise_distances(x, "canberra", out="condensed",
-                                        block=8, feature_block=4,
-                                        impl=impl))
-    want = pdist(x.astype(np.float64), "canberra")
+    if takes_tree(get_metric(metric)):
+        arrays = _balanced_tree(13)
+        tree = PhyloTree(*arrays)
+        want = squareform(unweighted_unifrac_ref(x, *arrays), checks=False)
+    else:
+        tree = None
+        want = _pdist64(x, metric)
+    got = np.asarray(pairwise_distances(x, metric, out="condensed",
+                                        block=8, feature_block=4, tree=tree))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -254,18 +292,6 @@ def test_condensed_operator_rejects_overflow_n():
     with pytest.raises(ValueError, match="int32"):
         CondensedCenteredGramOperator(
             jnp.zeros((3,)), jnp.zeros((50000,)), jnp.float32(0.0), 50000)
-
-
-def test_from_features_pallas_production_parity():
-    x = _table(10, 20, 7)
-    g = np.arange(20) % 2
-    cfg = ExecConfig(pairwise_impl="pallas", block=8, feature_block=4)
-    ws = Workspace.from_features(x, metric="cityblock", config=cfg)
-    r = ws.permanova(g, permutations=49, key=KEY)
-    r2 = Workspace.from_features(x, metric="cityblock").permanova(
-        g, permutations=49, key=KEY)
-    np.testing.assert_allclose(r.statistic, r2.statistic, rtol=1e-5)
-    assert r.p_value == r2.p_value
 
 
 def test_from_features_respects_config_metric_default():

@@ -8,10 +8,8 @@ import pytest
 
 from repro.core.distance_matrix import random_distance_matrix
 from repro.kernels import (center_distance_matrix_pallas,
-                           center_matvec_pallas,
                            is_symmetric_and_hollow_pallas,
                            mantel_corr_pallas, rmsnorm_pallas)
-from repro.kernels.center_matvec_ref import center_matvec_ref
 from repro.kernels.center_ref import center_distance_matrix_ref
 from repro.kernels.mantel_corr_ref import mantel_corr_ref
 from repro.kernels.rmsnorm_ref import rmsnorm_ref
@@ -48,48 +46,6 @@ def test_center_bf16():
     assert np.abs(got - want).max() < 0.05 * scale
     corr = np.corrcoef(got.ravel(), want.ravel())[0, 1]
     assert corr > 0.999
-
-
-# --------------------------------------------------------------------------
-# center_matvec
-# --------------------------------------------------------------------------
-def _matvec_inputs(n, k, seed):
-    d = random_distance_matrix(jax.random.PRNGKey(seed), n).data
-    x = jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(seed), 7),
-                          (n, k))
-    row_means = -0.5 * jnp.mean(d * d, axis=1)
-    return d, x, row_means, jnp.mean(row_means)
-
-
-@pytest.mark.parametrize("n,k", [(16, 4), (64, 10), (77, 7), (128, 20),
-                                 (200, 3)])
-def test_center_matvec_matches_ref(n, k):
-    d, x, rm, gm = _matvec_inputs(n, k, seed=n)
-    got = center_matvec_pallas(d, x, rm, gm, block_m=32, block_n=32,
-                               interpret=True)
-    want = center_matvec_ref(d, x)
-    scale = np.abs(np.asarray(want)).max()
-    np.testing.assert_allclose(got, want, rtol=1e-5,
-                               atol=1e-5 * max(scale, 1.0))
-
-
-@pytest.mark.parametrize("bm,bn", [(8, 8), (16, 32), (64, 16)])
-def test_center_matvec_block_shapes(bm, bn):
-    d, x, rm, gm = _matvec_inputs(64, 6, seed=1)
-    got = center_matvec_pallas(d, x, rm, gm, block_m=bm, block_n=bn,
-                               interpret=True)
-    np.testing.assert_allclose(got, center_matvec_ref(d, x),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_center_matvec_identity_recovers_centered_matrix():
-    """F @ I == F: the kernel against the materialized matrix itself."""
-    n = 48
-    d, _, rm, gm = _matvec_inputs(n, 1, seed=2)
-    got = center_matvec_pallas(d, jnp.eye(n), rm, gm, block_m=16,
-                               block_n=16, interpret=True)
-    np.testing.assert_allclose(got, center_distance_matrix_ref(d),
-                               rtol=2e-4, atol=2e-4)
 
 
 # --------------------------------------------------------------------------

@@ -11,6 +11,8 @@ from repro.core import (CenteredGramOperator,
                         centered_gram_matvec_distributed, pcoa,
                         random_distance_matrix)
 from repro.core.centering import center_distance_matrix
+from repro.kernels.center_matvec_ref import center_matvec_ref
+from repro.kernels.center_ref import center_distance_matrix_ref
 
 
 def _matvec_case(n, seed, k=7):
@@ -35,12 +37,50 @@ def test_matvec_matches_materialized_odd_n(n):
 
 @pytest.mark.parametrize("n", [16, 77, 96])
 def test_matvec_pallas_impl_matches(n):
+    """F@X at block=32, n below, off and on a block multiple, against the
+    materialised matrix."""
     d, x, want = _matvec_case(n, seed=n + 1)
-    got = CenteredGramOperator.from_distance(d, block=32,
-                                             impl="pallas").matvec(x)
+    got = CenteredGramOperator.from_distance(d, block=32).matvec(x)
     scale = np.abs(np.asarray(want)).max()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5 * max(scale, 1.0))
+
+
+def _matvec_inputs(n, k, seed):
+    d = random_distance_matrix(jax.random.PRNGKey(seed), n).data
+    x = jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(seed), 7),
+                          (n, k))
+    return d, x
+
+
+@pytest.mark.parametrize("n,k", [(16, 4), (64, 10), (77, 7), (128, 20),
+                                 (200, 3)])
+def test_center_matvec_matches_ref(n, k):
+    """The row-strip matvec against ``center_matvec_ref``, which
+    materialises the centred matrix the eager way."""
+    d, x = _matvec_inputs(n, k, seed=n)
+    got = CenteredGramOperator.from_distance(d, block=32).matvec(x)
+    want = center_matvec_ref(d, x)
+    scale = np.abs(np.asarray(want)).max()
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * max(scale, 1.0))
+
+
+@pytest.mark.parametrize("block", [8, 16, 64])
+def test_center_matvec_block_shapes(block):
+    d, x = _matvec_inputs(64, 6, seed=1)
+    got = CenteredGramOperator.from_distance(d, block=block).matvec(x)
+    np.testing.assert_allclose(got, center_matvec_ref(d, x),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_center_matvec_identity_recovers_centered_matrix():
+    """F @ I == F: the matvec against the materialized matrix itself."""
+    n = 48
+    d, _ = _matvec_inputs(n, 1, seed=2)
+    got = CenteredGramOperator.from_distance(d, block=16).matvec(jnp.eye(n))
+    np.testing.assert_allclose(got, center_distance_matrix_ref(d),
+                               rtol=2e-4, atol=2e-4)
 
 
 def test_matvec_1d_vector_roundtrip():
@@ -76,12 +116,6 @@ def test_operator_crosses_jit_boundary():
                                np.asarray(want) +
                                float(jnp.trace(center_distance_matrix(d))),
                                rtol=1e-4, atol=1e-3)
-
-
-def test_operator_rejects_unknown_impl():
-    d = random_distance_matrix(jax.random.PRNGKey(0), 8).data
-    with pytest.raises(ValueError):
-        CenteredGramOperator.from_distance(d, impl="cuda")
 
 
 def test_materialize_is_the_fused_centering():
@@ -133,15 +167,6 @@ def test_matrix_free_matches_materialized_fsvd():
     np.testing.assert_allclose(np.asarray(r_mf.eigenvalues),
                                np.asarray(r_mat.eigenvalues),
                                rtol=1e-3, atol=1e-3)
-
-
-def test_pcoa_pallas_matvec_impl():
-    dm = random_distance_matrix(jax.random.PRNGKey(21), 64, dim=4)
-    r_xla = pcoa(dm, dimensions=4, block=32)
-    r_pal = pcoa(dm, dimensions=4, block=32, matvec_impl="pallas")
-    np.testing.assert_allclose(np.asarray(r_pal.eigenvalues),
-                               np.asarray(r_xla.eigenvalues),
-                               rtol=1e-3, atol=1e-4)
 
 
 def test_proportion_explained_uses_exact_total():

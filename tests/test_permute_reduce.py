@@ -1,10 +1,9 @@
-"""``kernels.permute_reduce`` validation: both implementations (the
-Pallas kernel and its lax.scan twin) against the eager square-roundtrip
-``_ref`` oracle, across odd n, non-tile-multiple m and B, trailing
-chunks, and both interpret modes — plus the engine-facing properties
-(identity order, stacked invariant rows, int32 refusal) — and the row
-layout ``permute_reduce_rows`` with its square build ``hollow_square``
-against the same oracle and the condensed kernel."""
+"""``kernels.permute_reduce`` validation: both layouts (the condensed
+``lax.scan``, and the row gathers of ``permute_reduce_rows`` with its
+square build ``hollow_square``) against the eager square-roundtrip
+``_ref`` oracle and each other, across odd n, non-tile-multiple m and B
+and trailing chunks — plus the engine-facing properties (identity order,
+stacked invariant rows, int32 refusal)."""
 
 import jax
 import jax.numpy as jnp
@@ -54,32 +53,26 @@ def test_triangle_coords_roundtrip(n):
 # --------------------------------------------------------------------------
 # parity vs the _ref oracle
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("layout", ["xla", "rows"])
 @pytest.mark.parametrize("n,b_perms,s,chunk", [
     (33, 5, 1, 64),     # odd n, m=528 → trailing chunk (528 % 64 != 0)
     (17, 7, 2, 32),     # odd n AND non-multiple B, stacked rows
     (40, 3, 3, 1024),   # chunk > m: single padded chunk
     (24, 8, 2, 100),    # chunk not a multiple of 8 (geometry snaps it)
 ])
-def test_permute_reduce_matches_ref(impl, n, b_perms, s, chunk):
+def test_permute_reduce_matches_ref(layout, n, b_perms, s, chunk):
+    """The condensed scan at each chunk, or the row gathers (which take
+    no chunk) of the same operands as hollow squares."""
     xc, ys, orders = _case(n, b_perms, s, seed=n)
-    got = permute_reduce(xc, ys, orders, impl=impl, chunk=chunk,
-                         interpret=True if impl == "pallas" else None)
+    if layout == "rows":
+        got = permute_reduce_rows(
+            hollow_square(xc, n),
+            jnp.stack([hollow_square(y, n) for y in ys]), orders)
+    else:
+        got = permute_reduce(xc, ys, orders, chunk=chunk)
     want = permute_reduce_ref(xc, ys, orders)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
-
-
-def test_permute_reduce_impls_agree_and_auto_interpret():
-    """interpret=None auto-resolves per backend (the interpreter on this
-    container's CPU) and the two impls agree on identical inputs."""
-    xc, ys, orders = _case(26, 6, 2, seed=1)
-    a = permute_reduce(xc, ys, orders, impl="xla")
-    b = permute_reduce(xc, ys, orders, impl="pallas")   # interpret=None
-    c = permute_reduce(xc, ys, orders, impl="pallas", interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(b), np.asarray(c))
 
 
 def test_permute_reduce_identity_order_is_plain_dot():
@@ -88,7 +81,7 @@ def test_permute_reduce_identity_order_is_plain_dot():
     n = 30
     xc, ys, _ = _case(n, 1, 2, seed=2)
     orders = jnp.arange(n, dtype=jnp.int32)[None, :]
-    got = permute_reduce(xc, ys, orders, impl="xla")
+    got = permute_reduce(xc, ys, orders)
     want = ys @ xc
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -97,19 +90,16 @@ def test_permute_reduce_identity_order_is_plain_dot():
 def test_permute_reduce_tiny_n_edges():
     """n=2 (m=1) and n=1 (m=0, empty triangle) don't crash or mis-shape."""
     out = permute_reduce(jnp.ones((1,)), jnp.full((1, 1), 2.0),
-                         jnp.asarray([[0, 1], [1, 0]]), impl="xla")
+                         jnp.asarray([[0, 1], [1, 0]]))
     np.testing.assert_allclose(np.asarray(out), [[2.0, 2.0]])
     empty = permute_reduce(jnp.zeros((0,)), jnp.zeros((2, 0)),
-                           jnp.zeros((3, 1), jnp.int32), impl="pallas",
-                           interpret=True)
+                           jnp.zeros((3, 1), jnp.int32))
     assert empty.shape == (2, 3)
     np.testing.assert_array_equal(np.asarray(empty), 0.0)
 
 
 def test_permute_reduce_validates():
     xc, ys, orders = _case(10, 2, 1, seed=3)
-    with pytest.raises(ValueError, match="impl"):
-        permute_reduce(xc, ys, orders, impl="cuda")
     with pytest.raises(ValueError, match="condensed length"):
         permute_reduce(xc[:-1], ys, orders)
     with pytest.raises(ValueError, match="ys must be"):
@@ -126,8 +116,8 @@ def test_permute_reduce_precomputed_coords_match():
     bitwise the recomputed path."""
     xc, ys, orders = _case(21, 4, 1, seed=4)
     ii, jj = triangle_coords(21)
-    a = permute_reduce(xc, ys, orders, ii, jj, impl="xla")
-    b = permute_reduce(xc, ys, orders, impl="xla")
+    a = permute_reduce(xc, ys, orders, ii, jj)
+    b = permute_reduce(xc, ys, orders)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -153,7 +143,7 @@ def test_permute_reduce_rows_matches_condensed(n, s, b_perms):
     got = np.asarray(permute_reduce_rows(xs, ys_sq, orders))
     assert got.shape == (s, b_perms)
     for want in (permute_reduce_ref(xc, ys, orders),
-                 permute_reduce(xc, ys, orders, impl="xla")):
+                 permute_reduce(xc, ys, orders)):
         want = np.asarray(want)
         np.testing.assert_allclose(got, want, rtol=1e-5,
                                    atol=1e-5 * np.abs(want).max())

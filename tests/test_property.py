@@ -59,17 +59,14 @@ def test_centering_scales_quadratically(n, seed, scale):
 
 
 @given(n=st.integers(4, 97), seed=st.integers(0, 2**30),
-       k=st.integers(1, 12), block=st.sampled_from([8, 16, 32]),
-       impl=st.sampled_from(["xla", "pallas"]))
+       k=st.integers(1, 12), block=st.sampled_from([8, 16, 32]))
 @settings(**_settings)
-def test_operator_matvec_equals_materialized_any_shape(n, seed, k, block,
-                                                       impl):
+def test_operator_matvec_equals_materialized_any_shape(n, seed, k, block):
     """CenteredGramOperator.matvec == center_distance_matrix(D) @ X to
-    ≤1e-5 relative, across odd n (non-multiples of the block) and both
-    matvec backends."""
+    ≤1e-5 relative, across odd n (non-multiples of the block)."""
     dm = random_distance_matrix(jax.random.PRNGKey(seed), n).data
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (n, k))
-    op = CenteredGramOperator.from_distance(dm, block=block, impl=impl)
+    op = CenteredGramOperator.from_distance(dm, block=block)
     want = np.asarray(center_distance_matrix(dm) @ x)
     got = np.asarray(op.matvec(x))
     scale = max(np.abs(want).max(), 1.0)

@@ -315,20 +315,6 @@ def test_partial_mantel_fused_matches_ref():
     assert abs(got.p_value - want.p_value) < 1e-9
 
 
-def test_partial_mantel_pallas_kernel_path():
-    """The per-batch route through kernels.mantel_corr gives the same test.
-
-    K=35 with the default batch of 8 leaves a remainder block of 3: the
-    engine must still route every permutation through per_batch."""
-    x, y, z = _dm(13, 24), _dm(14, 24), _dm(15, 24)
-    xla = partial_mantel(x, y, z, permutations=35, key=KEY, kernel="xla")
-    pal = partial_mantel(x, y, z, permutations=35, key=KEY, kernel="pallas")
-    assert abs(xla.statistic - pal.statistic) < 1e-5
-    assert abs(xla.p_value - pal.p_value) < 1e-9
-    with pytest.raises(ValueError):
-        partial_mantel(x, y, z, permutations=8, kernel="cuda")
-
-
 def test_partial_mantel_rejects_collinear_control():
     """z == y makes the residualization 0/0 — must raise, not report the
     most significant p-value via NaN comparisons."""

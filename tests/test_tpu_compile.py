@@ -1,6 +1,6 @@
 """Ahead-of-time compiles for a described TPU v5e, at the sizes the chip
-runs (``chip_smoke.py``): the default XLA entry points of the main path,
-every Pallas kernel ``ExecConfig`` can select, and the four-chip paths.
+runs (``chip_smoke.py``, the HMP cohort): the XLA entry points of the
+main path and the four-chip paths.
 
 No chip is needed: the TPU compiler compiles for a v5e:2x2 topology that
 is described, not attached. A compile that passes is not a chip run; it
@@ -21,12 +21,11 @@ from jax.sharding import PartitionSpec as P
 from repro.core.centering import center_distance_matrix_distributed
 from repro.core.distance_matrix import DistanceMatrix
 from repro.core.mantel import MantelStatistic, mantel_null_distributed
-from repro.core.operators import CondensedCenteredGramOperator
+from repro.core.operators import (CenteredGramOperator,
+                                  CondensedCenteredGramOperator)
 from repro.dist.driver import _panel_stats
 from repro.dist.metrics import get_metric
 from repro.dist.tree import _tree_hoist
-from repro.kernels.center_matvec_ops import center_matvec_pallas
-from repro.kernels.pairwise_ops import pairwise_panel_pallas
 from repro.kernels.permute_reduce_ops import DEFAULT_CHUNK, _permute_reduce_jit
 from repro.launch.mesh import chip_peaks
 from repro.stats import engine
@@ -80,7 +79,7 @@ def test_permute_reduce_xla_compiles(topo, one_chip, s):
     compiled = _permute_reduce_jit.lower(
         one_chip((M,)), one_chip((s, M)), one_chip((B, N), jnp.int32),
         one_chip((M,), jnp.int32), one_chip((M,), jnp.int32),
-        impl="xla", chunk=DEFAULT_CHUNK, interpret=None).compile()
+        chunk=DEFAULT_CHUNK).compile()
     _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
@@ -101,8 +100,7 @@ def test_mantel_rows_null_distribution_compiles(topo, one_chip, n):
 def test_panel_stats_xla_compiles(topo, one_chip):
     compiled = _panel_stats.lower(
         one_chip((256, D)), one_chip((N, D)),
-        metric=get_metric("braycurtis"), feature_block=128, impl="xla",
-        interpret=None, block=256).compile()
+        metric=get_metric("braycurtis"), feature_block=128).compile()
     _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
@@ -127,8 +125,8 @@ def test_panel_stats_unifrac_compiles(topo, one_chip):
     n, branches = 2898, 90764
     compiled = _panel_stats.lower(
         one_chip((256, branches)), one_chip((n, branches)),
-        metric=get_metric("unweighted_unifrac"), feature_block=branches,
-        impl="xla", interpret=None, block=256).compile()
+        metric=get_metric("unweighted_unifrac"),
+        feature_block=branches).compile()
     _fits_one_chip(compiled, topo.devices[0].device_kind)
     dots = [line for line in compiled.as_text().splitlines()
             if re.search(r"= \S+ (dot|convolution)\(", line)]
@@ -145,30 +143,15 @@ def test_condensed_matvec_compiles(topo, one_chip):
     _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
-# --------------------------------------------------------------------------
-# the Pallas kernels ExecConfig can select, native (interpret=False)
-# --------------------------------------------------------------------------
 def test_center_matvec_pallas_compiles(topo, one_chip):
-    compiled = center_matvec_pallas.lower(
-        one_chip((N, N)), one_chip((N, K)), one_chip((N,)), one_chip(()),
-        block_m=512, block_n=512, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pairwise_pallas_compiles(topo, one_chip):
-    compiled = pairwise_panel_pallas.lower(
-        one_chip((256, D)), one_chip((N, D)),
-        metric=get_metric("braycurtis"), block_n=256, feature_block=128,
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_permute_reduce_pallas_refused_on_tpu(topo, one_chip):
-    with pytest.raises(NotImplementedError, match="kernel='xla'"):
-        _permute_reduce_jit.lower(
-            one_chip((M,)), one_chip((1, M)), one_chip((B, N), jnp.int32),
-            one_chip((M,), jnp.int32), one_chip((M,), jnp.int32),
-            impl="pallas", chunk=DEFAULT_CHUNK, interpret=False)
+    """The square operator's row-strip matvec at the HMP cohort's n (a
+    ``Workspace`` over a distance matrix runs it for PCoA)."""
+    n = 2898
+    matvec = jax.jit(lambda d, rm, gm, x: CenteredGramOperator(
+        d, rm, gm, n, 256).matvec(x))
+    compiled = matvec.lower(one_chip((n, n)), one_chip((n,)), one_chip(()),
+                            one_chip((n, K))).compile()
+    _fits_one_chip(compiled, topo.devices[0].device_kind)
 
 
 # --------------------------------------------------------------------------

@@ -150,16 +150,17 @@ def test_duplicate_and_empty_rows_on_the_four_tip_tree():
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("block", [8, 256])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_square_is_exactly_symmetric_and_hollow(shape, impl):
+def test_square_is_exactly_symmetric_and_hollow(shape, block):
     """The product form rounds (i, j) and (j, i) apart and leaves about
     1e-6 on the diagonal; the square form is symmetric and hollow all
-    the same, and agrees with the oracle."""
+    the same, and agrees with the oracle, in panels of 8 rows or in one
+    panel (the default block)."""
     arrays, x = _tree_and_table(shape, 11, tips=20, n=21)
     tree = PhyloTree(*arrays)
     sq = np.asarray(pairwise_distances(x, "unweighted_unifrac", tree=tree,
-                                       block=8, impl=impl))
+                                       block=block))
     np.testing.assert_array_equal(sq, sq.T)
     np.testing.assert_array_equal(np.diag(sq), 0.0)
     np.testing.assert_allclose(sq, unweighted_unifrac_ref(x, *arrays),
@@ -168,14 +169,15 @@ def test_square_is_exactly_symmetric_and_hollow(shape, impl):
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 def test_pallas_panels_match_the_reference(shape):
-    """The Pallas panel kernel (interpreted here) calls the metric's
-    accumulate on feature chunks of its tiles: the shared length and
-    the totals add over chunks to the oracle's distances."""
+    """The product path in panels of 8 rows, the last one padded (n=19),
+    asked for feature chunks of 5 that a product metric does not take:
+    each panel's one product over the whole embedding gives the
+    oracle's distances."""
     arrays, x = _tree_and_table(shape, 13, tips=24, n=19)
     want = _condensed(unweighted_unifrac_ref(x, *arrays))
     got = np.asarray(pairwise_distances(
         x, "unweighted_unifrac", tree=PhyloTree(*arrays), out="condensed",
-        block=8, feature_block=5, impl="pallas"))
+        block=8, feature_block=5))
     assert np.max(np.abs(got - want)) <= 1e-5
 
 
